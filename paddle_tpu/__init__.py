@@ -31,8 +31,6 @@ if int(_os.environ.get("PADDLE_TRAINERS_NUM", "1")) > 1 \
     # worker spawns — pipe-command data generators, PS servers) keeps
     # those children from re-joining the coordination service with a
     # duplicate process_id on import.
-    from ._jax_compat import enable_cpu_multiprocess_collectives
-    enable_cpu_multiprocess_collectives()
     _jax.distributed.initialize(
         coordinator_address=_os.environ["PADDLE_TRAINER_ENDPOINTS"]
         .split(",")[0],

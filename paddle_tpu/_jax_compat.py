@@ -1,85 +1,15 @@
-"""Version-compat shims over the jax surface.
-
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the top-level
-``jax`` namespace (jax >= 0.6); the repo supports both so the pinned
-container toolchain (0.4.x) and newer runtimes load the same source.
-"""
+"""The handful of jax names the package reaches through one module, as
+the installed JAX (0.9.0) spells them: ``jax.shard_map`` (with
+``check_vma``), ``jax.lax.pcast(..., to="varying")``,
+``jax.lax.axis_size``, ``jax.distributed.is_initialized``."""
 from __future__ import annotations
 
-import inspect as _inspect
-
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SM_PARAMS = _inspect.signature(_shard_map).parameters
-
-
-def shard_map(*args, **kwargs):
-    """``shard_map`` accepting both spellings of the replication-check
-    flag (``check_rep`` in jax 0.4.x, renamed ``check_vma`` later)."""
-    if "check_vma" in kwargs and "check_vma" not in _SM_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _SM_PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(*args, **kwargs)
-
-
-try:  # jax >= 0.6 top-level context manager
-    from jax import enable_x64  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x
-    from jax.experimental import enable_x64  # noqa: F401
+import jax
+from jax import shard_map  # noqa: F401
+from jax.distributed import is_initialized as distributed_is_initialized  # noqa: F401
+from jax.lax import axis_size  # noqa: F401
 
 
 def pvary(x, axes):
-    """Mark ``x`` device-varying over ``axes`` (jax >= 0.6 ``lax.pcast`` /
-    ``lax.pvary``). jax 0.4.x has no varying-axis type system, so the
-    identity is the correct lowering there."""
-    import jax
-
-    fn = getattr(jax.lax, "pcast", None)
-    if fn is not None:
-        try:
-            return fn(x, tuple(axes), to="varying")
-        except TypeError:
-            pass
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is not None:
-        return fn(x, tuple(axes))
-    return x
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` with a fallback for jax versions that predate
-    it (``jax.core.axis_frame(name)`` returns the bound size there)."""
-    import jax
-
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    import jax.core as _core
-    return _core.axis_frame(axis_name)
-
-
-def enable_cpu_multiprocess_collectives():
-    """Multi-process collectives on the CPU backend need the gloo
-    implementation selected before backend init on jax 0.4.x (newer
-    releases default to it; the knob may not exist there — best effort)."""
-    import jax
-
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
-
-
-def distributed_is_initialized():
-    """``jax.distributed.is_initialized()`` with a fallback for jax
-    versions that predate it (the coordination client lives in
-    ``jax._src.distributed.global_state``)."""
-    import jax
-
-    if hasattr(jax.distributed, "is_initialized"):
-        return jax.distributed.is_initialized()
-    from jax._src.distributed import global_state
-    return global_state.client is not None
+    """Mark ``x`` device-varying over ``axes`` inside a ``shard_map``."""
+    return jax.lax.pcast(x, tuple(axes), to="varying")

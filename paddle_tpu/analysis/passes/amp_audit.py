@@ -19,6 +19,7 @@ from __future__ import annotations
 from collections import defaultdict
 
 import jax
+import jax.extend.core as jex_core
 
 from ..core import Diagnostic, register_pass
 from ..tracing import eqn_site
@@ -64,10 +65,10 @@ def _redundant_casts(ctx, out):
     convert_eqns = []
     for jx in _iter_jaxprs(ctx.jaxpr):
         out_ids.update(id(v) for v in jx.outvars
-                       if not isinstance(v, jax.core.Literal))
+                       if not isinstance(v, jex_core.Literal))
         for eqn in jx.eqns:
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, jex_core.Literal):
                     uses[id(v)] += 1
             if eqn.primitive.name == "convert_element_type":
                 convert_eqns.append(eqn)
@@ -75,7 +76,7 @@ def _redundant_casts(ctx, out):
     seen = set()
     for eqn in convert_eqns:
         src = eqn.invars[0]
-        if isinstance(src, jax.core.Literal):
+        if isinstance(src, jex_core.Literal):
             continue
         up = producer.get(id(src))
         if up is None or uses[id(src)] != 1 or id(src) in out_ids:
@@ -111,7 +112,7 @@ def _redundant_casts(ctx, out):
 
 def _iter_jaxprs(jaxpr):
     """Every (sub)Jaxpr reachable from a ClosedJaxpr, top first."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jex_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     stack = [jaxpr]
     while stack:
@@ -123,9 +124,9 @@ def _iter_jaxprs(jaxpr):
 
 
 def _sub_jaxprs_of(v):
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jex_core.ClosedJaxpr):
         return [v.jaxpr]
-    if isinstance(v, jax.core.Jaxpr):
+    if isinstance(v, jex_core.Jaxpr):
         return [v]
     if isinstance(v, (list, tuple)):
         out = []

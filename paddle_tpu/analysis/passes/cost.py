@@ -60,6 +60,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 import jax
+import jax.extend.core as jex_core
 
 from ..core import Diagnostic, register_pass
 from ..tracing import eqn_site
@@ -70,6 +71,7 @@ _FREE = {
     "reshape", "squeeze", "expand_dims", "broadcast_in_dim", "iota",
     "stop_gradient", "copy", "device_put", "sharding_constraint",
     "transpose", "rev", "bitcast_convert_type", "split", "symbolic_zeros",
+    "tile",  # what jnp.tile binds: a broadcast, like broadcast_in_dim
 }
 
 # elementwise / cheap ops XLA fuses into their consumers: their outputs
@@ -94,7 +96,7 @@ _FUSABLE = _FREE | {
 # primitives whose params carry sub-jaxprs the walker recurses into
 # transparently (cost of the call = cost of the body)
 _TRANSPARENT = {
-    "pjit", "closed_call", "core_call", "xla_call", "remat", "remat2",
+    "jit", "closed_call", "core_call", "xla_call", "remat", "remat2",
     "checkpoint", "custom_jvp_call", "custom_vjp_call",
     "custom_vjp_call_jaxpr", "custom_jvp_call_jaxpr", "name",
 }
@@ -353,9 +355,9 @@ def _sub_jaxprs(params):
         stack = [v]
         while stack:
             x = stack.pop()
-            if isinstance(x, jax.core.ClosedJaxpr):
+            if isinstance(x, jex_core.ClosedJaxpr):
                 yield x.jaxpr
-            elif isinstance(x, jax.core.Jaxpr):
+            elif isinstance(x, jex_core.Jaxpr):
                 yield x
             elif isinstance(x, (list, tuple)):
                 stack.extend(x)
@@ -423,7 +425,7 @@ class _JaxprCoster:
             div[id(v)] = 1
 
         def dof(v):
-            if isinstance(v, jax.core.Literal):
+            if isinstance(v, jex_core.Literal):
                 return 1
             return div.get(id(v), 1)
 
@@ -436,7 +438,7 @@ class _JaxprCoster:
         frame_in = {id(v) for v in jaxpr.invars}
         frame_in |= {id(v) for v in jaxpr.constvars}
         frame_out = {id(v) for v in jaxpr.outvars
-                     if not isinstance(v, jax.core.Literal)}
+                     if not isinstance(v, jex_core.Literal)}
 
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
@@ -448,7 +450,7 @@ class _JaxprCoster:
             # width they stream from; free view ops pass it through
             if name in ("convert_element_type",) or name in _FREE:
                 ins = [v for v in eqn.invars
-                       if not isinstance(v, jax.core.Literal)]
+                       if not isinstance(v, jex_core.Literal)]
                 if ins and eqn.outvars:
                     sb = min(self._sbytes(ins[0]),
                              _nbytes(eqn.outvars[0].aval))
@@ -546,7 +548,7 @@ class _JaxprCoster:
                 # operand compresses, or not, at its own width
                 wire_payload = payload_i8 = 0.0
                 for v in eqn.invars:
-                    if isinstance(v, jax.core.Literal):
+                    if isinstance(v, jex_core.Literal):
                         continue
                     b = _nbytes(v.aval)
                     dt = getattr(v.aval, "dtype", None)
@@ -593,7 +595,7 @@ class _JaxprCoster:
             elif name in _FUSABLE:
                 flops = _default_flops(eqn)
                 nbytes = sum(_nbytes(v.aval) for v in eqn.invars
-                             if not isinstance(v, jax.core.Literal)
+                             if not isinstance(v, jex_core.Literal)
                              and id(v) in frame_in)
                 nbytes += sum(_nbytes(v.aval) for v in eqn.outvars
                               if id(v) in frame_out)
@@ -613,7 +615,7 @@ class _JaxprCoster:
         their STORED width — fused converts read the narrow buffer) +
         outputs."""
         nbytes = sum(self._sbytes(v) for v in eqn.invars
-                     if not isinstance(v, jax.core.Literal))
+                     if not isinstance(v, jex_core.Literal))
         nbytes += sum(_nbytes(v.aval) for v in eqn.outvars)
         return float(nbytes)
 
@@ -630,7 +632,7 @@ def estimate_jaxpr_cost(closed_jaxpr, in_divisors=None, axis_sizes=None,
     ``summary.int8_wire_reduction``)."""
     from ...observability.instrument import chip_specs
     jaxpr = (closed_jaxpr.jaxpr
-             if isinstance(closed_jaxpr, jax.core.ClosedJaxpr)
+             if isinstance(closed_jaxpr, jex_core.ClosedJaxpr)
              else closed_jaxpr)
     s = CostSummary()
     s.wire_dtype = wire_dtype
@@ -745,7 +747,7 @@ def _moe_fusion_opportunities(jaxpr, _found=None, recurse=True):
             for sub in _sub_jaxprs(eqn.params):
                 _moe_fusion_opportunities(sub, found)
         ins = [v for v in eqn.invars
-               if not isinstance(v, jax.core.Literal)]
+               if not isinstance(v, jex_core.Literal)]
         hit = any(id(v) in tainted for v in ins)
         if name == "top_k":
             saw_topk = True
@@ -802,7 +804,7 @@ def _paged_gather_opportunities(jaxpr, _found=None, recurse=True):
         if name != "gather":
             continue
         ins = [v for v in eqn.invars
-               if not isinstance(v, jax.core.Literal)]
+               if not isinstance(v, jex_core.Literal)]
         if len(ins) != 2:
             continue
         op, idx = eqn.invars[0], eqn.invars[1]
@@ -849,7 +851,7 @@ def _dequant_matmul_opportunities(jaxpr, _found=None, recurse=True):
             for sub in _sub_jaxprs(eqn.params):
                 _dequant_matmul_opportunities(sub, found)
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jex_core.Literal):
                 cons.setdefault(id(v), []).append(eqn)
     glue_bytes = 0.0
     big_out = 0.0
@@ -859,7 +861,7 @@ def _dequant_matmul_opportunities(jaxpr, _found=None, recurse=True):
         if eqn.primitive.name != "convert_element_type":
             continue
         src = eqn.invars[0]
-        if isinstance(src, jax.core.Literal) \
+        if isinstance(src, jex_core.Literal) \
                 or str(getattr(src.aval, "dtype", "")) != "int8":
             continue
         outv = eqn.outvars[0]

@@ -24,6 +24,7 @@ static diagnostics:
 from __future__ import annotations
 
 import jax
+import jax.extend.core as jex_core
 
 from ..core import Diagnostic, register_pass
 from ..tracing import eqn_site
@@ -31,7 +32,7 @@ from .cost import _nbytes, _sub_jaxprs
 
 
 def _iter_jaxprs(jaxpr):
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jex_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     stack = [jaxpr]
     while stack:
@@ -51,13 +52,14 @@ def donation_pass(ctx):
 
 
 def _pjit_donation_audit(ctx, out):
-    """Walk every (sub)jaxpr for pjit eqns that donate, and check each
+    """Walk every (sub)jaxpr for ``jit`` eqns (the primitive JAX 0.9
+    binds for a jitted call) that donate, and check each
     donated operand's fate in the ENCLOSING frame."""
     for jx in _iter_jaxprs(ctx.jaxpr):
         out_ids = {id(v) for v in jx.outvars
-                   if not isinstance(v, jax.core.Literal)}
+                   if not isinstance(v, jex_core.Literal)}
         for i, eqn in enumerate(jx.eqns):
-            if eqn.primitive.name != "pjit":
+            if eqn.primitive.name != "jit":
                 continue
             donated = eqn.params.get("donated_invars") or ()
             if not any(donated):
@@ -68,11 +70,11 @@ def _pjit_donation_audit(ctx, out):
             free_outs = [v.aval for v in eqn.outvars
                          if not isinstance(v, jax.core.DropVar)]
             for pos, (v, don) in enumerate(zip(eqn.invars, donated)):
-                if not don or isinstance(v, jax.core.Literal):
+                if not don or isinstance(v, jex_core.Literal):
                     continue
                 used_later = any(
                     any(id(u) == id(v) for u in later.invars
-                        if not isinstance(u, jax.core.Literal))
+                        if not isinstance(u, jex_core.Literal))
                     for later in jx.eqns[i + 1:])
                 escapes = id(v) in out_ids
                 if used_later or escapes:

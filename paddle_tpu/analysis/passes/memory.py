@@ -41,6 +41,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 
 import jax
+import jax.extend.core as jex_core
 
 from ..core import Diagnostic, register_pass
 from .cost import _FUSABLE, _nbytes, _sub_jaxprs
@@ -82,7 +83,7 @@ _SCAN_YS_CORESIDENT = 0.25
 
 # higher-order call prims whose operands become computation parameters
 # (real buffers) even when their producers would fuse
-_HO_CALLS = {"cond", "remat", "remat2", "checkpoint", "pjit",
+_HO_CALLS = {"cond", "remat", "remat2", "checkpoint", "jit",
              "closed_call", "core_call", "xla_call",
              "custom_jvp_call", "custom_vjp_call",
              "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr"}
@@ -129,7 +130,7 @@ class _MemWalker:
             div[id(v)] = 1
 
         def dof(v):
-            if isinstance(v, jax.core.Literal):
+            if isinstance(v, jex_core.Literal):
                 return 1
             return div.get(id(v), 1)
 
@@ -138,14 +139,14 @@ class _MemWalker:
         for i, eqn in enumerate(jaxpr.eqns):
             is_anchor = eqn.primitive.name not in _FUSABLE
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, jex_core.Literal):
                     last_use[id(v)] = i
                     if is_anchor:
                         anchor_consumers[id(v)] = \
                             anchor_consumers.get(id(v), 0) + 1
         n_eqns = len(jaxpr.eqns)
         for v in jaxpr.outvars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jex_core.Literal):
                 last_use[id(v)] = n_eqns  # never freed in this frame
 
         live = 0.0
@@ -166,7 +167,7 @@ class _MemWalker:
             # retro-materialize any fusable-produced operand here
             if name in _LOOPS or (_HO_OPERANDS and name in _HO_CALLS):
                 for v in eqn.invars:
-                    if (not isinstance(v, jax.core.Literal)
+                    if (not isinstance(v, jex_core.Literal)
                             and freeable.get(id(v)) == 0.0):
                         b = _nbytes(v.aval) / max(dof(v), 1)
                         freeable[id(v)] = b
@@ -206,7 +207,7 @@ class _MemWalker:
             bump(live)
 
             for v in eqn.invars:
-                if isinstance(v, jax.core.Literal):
+                if isinstance(v, jex_core.Literal):
                     continue
                 if last_use.get(id(v)) == i and id(v) in freeable:
                     live -= freeable.pop(id(v))
@@ -227,7 +228,7 @@ class _MemWalker:
                   + int(params.get("body_nconsts", 0) or 0))
             carry = eqn.invars[nc:]
         return sum(_nbytes(v.aval) / max(dof(v), 1) for v in carry
-                   if not isinstance(v, jax.core.Literal))
+                   if not isinstance(v, jex_core.Literal))
 
     def _call_transient(self, eqn, dof, live_base) -> float:
         """Transient bytes a higher-order eqn's body needs on top of the
@@ -251,7 +252,7 @@ class _MemWalker:
                 ys = body.outvars[ncar:]
                 peak = max(0.0, peak - sum(
                     _nbytes(v.aval) for v in ys
-                    if not isinstance(v, jax.core.Literal)))
+                    if not isinstance(v, jex_core.Literal)))
             return peak
         if name == "while":
             nc = int(params.get("cond_nconsts", 0) or 0)
@@ -291,7 +292,7 @@ def estimate_jaxpr_peak(closed_jaxpr, in_divisors=None, donated=None,
     donated arg's bytes free at its last use instead of pinning HBM for
     the whole step."""
     jaxpr = (closed_jaxpr.jaxpr
-             if isinstance(closed_jaxpr, jax.core.ClosedJaxpr)
+             if isinstance(closed_jaxpr, jex_core.ClosedJaxpr)
              else closed_jaxpr)
     divs = list(in_divisors or [])
     divs += [1] * (len(jaxpr.invars) - len(divs))

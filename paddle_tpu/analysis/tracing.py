@@ -28,6 +28,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 
 from ..framework import tape as tape_mod
@@ -479,7 +480,7 @@ def trace_abstract(fn, example_inputs, recorder: TraceRecorder,
 def iter_eqns(jaxpr):
     """Every eqn in a (Closed)Jaxpr including nested sub-jaxprs (pjit,
     scan, cond, remat...)."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jex_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
@@ -489,9 +490,9 @@ def iter_eqns(jaxpr):
 
 
 def _sub_jaxprs(v):
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jex_core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jex_core.Jaxpr):
         yield v
     elif isinstance(v, (list, tuple)):
         for x in v:

@@ -84,9 +84,8 @@ class virtual_hcg:
         from .. import mesh as mesh_mod
         d = self.degrees
         self._saved = (mesh_mod._global_mesh, mesh_mod._hcg)
-        am = AbstractMesh((("pp", d["pp"]), ("dp", d["dp"]),
-                           ("sharding", d["sharding"]), ("sep", 1),
-                           ("mp", d["mp"])))
+        am = AbstractMesh((d["pp"], d["dp"], d["sharding"], 1, d["mp"]),
+                          ("pp", "dp", "sharding", "sep", "mp"))
         return mesh_mod.HybridCommunicateGroup(
             dp_degree=d["dp"], mp_degree=d["mp"], pp_degree=d["pp"],
             sharding_degree=d["sharding"], mesh=am)

@@ -49,8 +49,6 @@ def init_parallel_env():
             and not distributed_is_initialized():
         # normally already done at paddle_tpu import (the bootstrap must
         # precede any XLA backend touch); kept for direct callers
-        from .._jax_compat import enable_cpu_multiprocess_collectives
-        enable_cpu_multiprocess_collectives()
         eps = env_mod.get_endpoints()
         jax.distributed.initialize(
             coordinator_address=eps[0],

@@ -18,4 +18,7 @@ Pallas TPU kernels instead of hand-written CUDA.
   for the weighted combine (scalar-prefetch row gather); gather-based
   reference + recompute VJPs, so fused training is trajectory-
   equivalent to the unfused path.
+- :mod:`.int8_matmul` — weight-only-int8 dequant-matmul.
+- :mod:`._mosaic` — what the kernel files share (x64 off around a
+  ``pallas_call`` traced for the chip).
 """

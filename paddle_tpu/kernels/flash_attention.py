@@ -39,6 +39,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._mosaic import x64_off
+
 MIN_BLOCK = 128
 MAX_BLOCK = 512
 _LANE = 128
@@ -105,21 +107,6 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _no_x64(fn):
-    from .._jax_compat import enable_x64
-
-    @functools.wraps(fn)
-    def inner(*a, **kw):
-        if _interpret():
-            # interpret mode has no Mosaic 64-bit restriction, and toggling
-            # x64 inside an outer trace splits cached sub-jaxprs across
-            # dtype regimes (i32/i64 func.call mismatch at lowering)
-            return fn(*a, **kw)
-        with enable_x64(False):
-            return fn(*a, **kw)
-    return inner
-
-
 def _causal_mask(s, qi, ki, bq, bk, offset=0):
     """offset: absolute position of query row 0 (cached decode / chunked
     prefill — row i attends keys <= offset + i); 0 = classic causal."""
@@ -138,9 +125,8 @@ def _kv_bounds_mask(s, ki, bk, kv_len):
     return jnp.where(col < np.int32(kv_len), s, jnp.float32(_NEG_INF))
 
 
-# CompilerParams is the jax>=0.6 name; 0.4.x calls it TPUCompilerParams
-_ARB = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-_ARB = _ARB(dimension_semantics=("parallel", "parallel", "arbitrary"))
+_ARB = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 # ---------------------------------------------------------------------------
@@ -194,41 +180,41 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lse_ref[0] = m_scr[:] + jnp.log(l_scr[:])
 
 
-@_no_x64
 def _fwd(q, k, v, causal, scale, g=1, kv_len=None, q_offset=0):
     """g: query heads per KV head (MQA/GQA) — q is [bn, sq, d], k/v are
     [bn // g, sk, d]; the KV block index maps divide the head index.
     kv_len: true (pre-padding) key length for ragged shapes. q_offset:
     absolute position of query row 0 (causal cached decode)."""
-    bn, sq, d = q.shape
-    sk = k.shape[1]
-    bq, bk = _pick_block(sq), _pick_block(sk)
-    nq, nk = sq // bq, sk // bk
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                          kv_len=kv_len, q_offset=q_offset),
-        grid=(bn, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b // g, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b // g, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bn, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bn, sq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
-        compiler_params=_ARB,
-        interpret=_interpret(),
-    )(q, k, v)
+    with x64_off(_interpret()):
+        bn, sq, d = q.shape
+        sk = k.shape[1]
+        bq, bk = _pick_block(sq), _pick_block(sk)
+        nq, nk = sq // bq, sk // bk
+        return pl.pallas_call(
+            functools.partial(_fwd_kernel, causal=causal, scale=scale,
+                              kv_len=kv_len, q_offset=q_offset),
+            grid=(bn, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j: (b // g, j, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j: (b // g, j, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bn, sq, d), q.dtype),
+                jax.ShapeDtypeStruct((bn, sq, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, d), jnp.float32),
+            ],
+            compiler_params=_ARB,
+            interpret=_interpret(),
+        )(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -331,69 +317,69 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-@_no_x64
 def _bwd(causal, scale, g, kv_len, q_offset, residuals, do):
-    q, k, v, o, lse = residuals
-    bn, sq, d = q.shape
-    bnk, sk, _ = k.shape
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    bq, bk = _pick_block(sq), _pick_block(sk)
-    nq, nk = sq // bq, sk // bk
+    with x64_off(_interpret()):
+        q, k, v, o, lse = residuals
+        bn, sq, d = q.shape
+        bnk, sk, _ = k.shape
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1, keepdims=True)
+        bq, bk = _pick_block(sq), _pick_block(sk)
+        nq, nk = sq // bq, sk // bk
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
-                          kv_len=kv_len, q_offset=q_offset),
-        grid=(bn, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b // g, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b // g, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bn, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_ARB,
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
+                              kv_len=kv_len, q_offset=q_offset),
+            grid=(bn, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j: (b // g, j, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j: (b // g, j, 0)),
+                pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((bn, sq, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            compiler_params=_ARB,
+            interpret=_interpret(),
+        )(q, k, v, do, lse, delta)
 
-    # dk/dv: one program per KV head; the innermost dim walks the g*nq
-    # query blocks of the whole GQA group so grouped heads accumulate
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
-                          nq=nq, kv_len=kv_len, q_offset=q_offset),
-        grid=(bnk, nk, g * nq),
-        in_specs=[
-            pl.BlockSpec((1, bq, d),
-                         lambda b, i, j: (b * g + j // nq, j % nq, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, d),
-                         lambda b, i, j: (b * g + j // nq, j % nq, 0)),
-            pl.BlockSpec((1, bq, 1),
-                         lambda b, i, j: (b * g + j // nq, j % nq, 0)),
-            pl.BlockSpec((1, bq, 1),
-                         lambda b, i, j: (b * g + j // nq, j % nq, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bnk, sk, d), q.dtype),
-            jax.ShapeDtypeStruct((bnk, sk, d), q.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        compiler_params=_ARB,
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+        # dk/dv: one program per KV head; the innermost dim walks the g*nq
+        # query blocks of the whole GQA group so grouped heads accumulate
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
+                              nq=nq, kv_len=kv_len, q_offset=q_offset),
+            grid=(bnk, nk, g * nq),
+            in_specs=[
+                pl.BlockSpec((1, bq, d),
+                             lambda b, i, j: (b * g + j // nq, j % nq, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bq, d),
+                             lambda b, i, j: (b * g + j // nq, j % nq, 0)),
+                pl.BlockSpec((1, bq, 1),
+                             lambda b, i, j: (b * g + j // nq, j % nq, 0)),
+                pl.BlockSpec((1, bq, 1),
+                             lambda b, i, j: (b * g + j // nq, j % nq, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bnk, sk, d), q.dtype),
+                jax.ShapeDtypeStruct((bnk, sk, d), q.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, d), jnp.float32),
+            ],
+            compiler_params=_ARB,
+            interpret=_interpret(),
+        )(q, k, v, do, lse, delta)
+        return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------

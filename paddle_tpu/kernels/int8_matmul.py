@@ -36,13 +36,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._mosaic import x64_off
+
 __all__ = ["int8_matmul"]
 
 _LANE = 128
 
-# CompilerParams is the jax>=0.6 name; 0.4.x calls it TPUCompilerParams
-_CP = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-_ARB3 = _CP(dimension_semantics=("parallel", "parallel", "arbitrary"))
+_ARB3 = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _interpret() -> bool:
@@ -98,19 +99,20 @@ def int8_matmul(x, w, scale, interpret=None):
         if Np != N:
             scale = jnp.pad(scale, [(0, Np - N)])
     nk = Kp // bk
-    out = pl.pallas_call(
-        functools.partial(_mm_kernel, nk=nk),
-        grid=(Mp // bm, Np // bn, nk),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_ARB3,
-        interpret=interpret,
-        name="autofuse_int8_matmul",
-    )(x, w, scale.reshape(1, -1))
+    with x64_off(interpret):
+        out = pl.pallas_call(
+            functools.partial(_mm_kernel, nk=nk),
+            grid=(Mp // bm, Np // bn, nk),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+                pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+                pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+            compiler_params=_ARB3,
+            interpret=interpret,
+            name="autofuse_int8_matmul",
+        )(x, w, scale.reshape(1, -1))
     return out[:M, :N]

@@ -21,18 +21,24 @@ Layout contract:
   A zero length marks an idle batch slot: every key is masked and the
   (finite, garbage) output row is discarded by the caller.
 
-Grid: one step per ``(sequence, kv_head, kv_page_block)`` — the page
-table rides :class:`pltpu.PrefetchScalarGridSpec` scalar prefetch so the
-``k_pages`` BlockSpec index_map can gather the right HBM page into VMEM
-while the online-softmax state (m/l/acc) lives in VMEM scratch, exactly
-the flash-attention streaming scheme but with an indirection per block.
-Fully-padded page blocks (``j*page_size >= seq_len``) early-out.
+Grid: one step per ``(sequence, kv_page)`` — the page table rides
+:class:`pltpu.PrefetchScalarGridSpec` scalar prefetch so the ``k_pages``
+BlockSpec index_map can gather the right HBM page into VMEM while the
+online-softmax state (m/l/acc) lives in VMEM scratch, exactly the
+flash-attention streaming scheme but with an indirection per block.
+Fully-padded pages (``j*page_size >= seq_len``) early-out.
 
-On CPU the kernel runs in interpreter mode so tier-1 asserts
+A block holds ALL KV heads of one page, ``(1, page_size, nkv, d)``: the
+TPU lowering wants a block's last two dims to be multiples of (8, 128)
+or the array's own, and one head out of ``nkv`` is neither. The heads
+are walked inside the kernel body. (A flat ``[pages, page, nkv*d]`` view
+of the pool would give lane-aligned head slices, but on the chip that
+reshape is a relayout copy of the whole pool per call, not a bitcast.)
+
+On CPU the kernels run in interpreter mode so tier-1 asserts
 paged-decode == XLA reference attention without a TPU; the same
-``pallas_call`` compiles on TPU (x64 disabled around the trace, head_dim
-padded to the 128-lane width — prefer d_head=128 models so the pool
-needs no per-step pad copy).
+``pallas_call`` compiles for the chip (x64 off around the trace;
+``tests/test_chip_compile.py`` asks the v5e compiler at 345M widths).
 
 **Shared (prefix-cache) pages**: all reads here are page-table gathers,
 so a page mapped into many sequences' tables (refcounted sharing in
@@ -53,108 +59,65 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_LANE = 128
+from ._mosaic import x64_off
+
 _NEG_INF = -1e30
 
-# CompilerParams is the jax>=0.6 name; 0.4.x calls it TPUCompilerParams
-_CP = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-_ARB3 = _CP(dimension_semantics=("parallel", "parallel", "arbitrary"))
+_ARB2 = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _no_x64(fn):
-    from .._jax_compat import enable_x64
-
-    @functools.wraps(fn)
-    def inner(*a, **kw):
-        if _interpret():
-            return fn(*a, **kw)
-        with enable_x64(False):
-            return fn(*a, **kw)
-    return inner
-
-
 def _decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, page_size, scale):
-    """One (sequence b, kv head h, page block j) step of the online
-    softmax; scratch carries the running (max, denom, weighted-V) state
-    across the innermost page walk."""
+                   m_scr, l_scr, acc_scr, *, page_size, g, scale):
+    """One (sequence b, page j) step of the online softmax over every
+    head at once; scratch carries the running (max, denom, weighted-V)
+    state across the page walk. Decode is bound by the bytes of K/V,
+    not by the MXU: scores are a multiply and a lane reduce over ``d``
+    with the reduced axis kept — tokens stay on the major axis, heads on
+    sublanes, ``d`` on lanes throughout, so there is no one-row dot, no
+    transpose and no relayout."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    npg = pl.num_programs(2)
+    j = pl.program_id(1)
+    npg = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     sl = sl_ref[b]
-    # ragged early-out: page blocks wholly beyond this sequence's length
-    # (incl. every block of an idle slot, sl == 0) are skipped
+    # ragged early-out: pages wholly beyond this sequence's length
+    # (incl. every page of an idle slot, sl == 0) are skipped
     run = j * np.int32(page_size) < sl
 
     @pl.when(run)
     def _():
-        q = q_ref[0, 0]            # [g, d] — this kv head's query group
-        k = k_ref[0][:, 0, :]      # [page_size, d]
-        v = v_ref[0][:, 0, :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) \
-            * jnp.float32(scale)   # [g, page_size]
-        col = j * np.int32(page_size) + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(col < sl, s, jnp.float32(_NEG_INF))
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = corr * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = corr * acc_scr[:] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        k = k_ref[0].astype(jnp.float32)               # [page, nkv, d]
+        v = v_ref[0].astype(jnp.float32)
+        live = j * np.int32(page_size) + jax.lax.broadcasted_iota(
+            jnp.int32, (page_size, 1, 1), 0) < sl
+        for r in range(g):          # query r of each kv head's group
+            q = q_ref[0, r].astype(jnp.float32)        # [nkv, d]
+            s = jnp.sum(k * q[None], axis=2, keepdims=True) \
+                * jnp.float32(scale)                   # [page, nkv, 1]
+            s = jnp.where(live, s, jnp.float32(_NEG_INF))
+            m_prev = m_scr[r]                          # [nkv, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            p = jnp.exp(s - m_new[None])               # [page, nkv, 1]
+            corr = jnp.exp(m_prev - m_new)
+            m_scr[r] = m_new
+            l_scr[r] = corr * l_scr[r] + jnp.sum(p, axis=0)
+            acc_scr[r] = corr * acc_scr[r] + jnp.sum(p * v, axis=0)
 
     @pl.when(j == npg - 1)
     def _():
         # idle slots never ran: l == 0 → emit finite garbage, not NaN
-        l = jnp.maximum(l_scr[:], jnp.float32(1e-30))
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
-
-
-@_no_x64
-def _paged_call(q4, k_pages, v_pages, page_table, seq_lens, scale):
-    B, nkv, g, d = q4.shape
-    page_size = k_pages.shape[1]
-    p_max = page_table.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, nkv, p_max),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda b, h, j, pt, sl: (b, h, 0, 0)),
-            # the paged gather: the page table picks which HBM page this
-            # grid step DMAs into VMEM
-            pl.BlockSpec((1, page_size, 1, d),
-                         lambda b, h, j, pt, sl: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, d),
-                         lambda b, h, j, pt, sl: (pt[b, j], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda b, h, j, pt, sl: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, page_size=page_size, scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nkv, g, d), q4.dtype),
-        compiler_params=_ARB3,
-        interpret=_interpret(),
-    )(page_table, seq_lens, q4, k_pages, v_pages)
+        l = jnp.maximum(l_scr[...], jnp.float32(1e-30))
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens,
@@ -162,82 +125,107 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens,
     """Single-token decode attention over a paged KV cache.
 
     ``q`` ``[B, num_heads, d]``; pages ``[num_pages, page_size,
-    num_kv_heads, d]`` (num_kv_heads may divide num_heads — MQA/GQA);
-    ``page_table`` ``[B, pages_per_seq]`` int32; ``seq_lens`` ``[B]``
-    int32 true lengths (0 = idle slot). Returns ``[B, num_heads, d]``.
+    num_kv_heads, d]`` (num_kv_heads may divide num_heads — MQA/GQA:
+    query heads ``[h*g, (h+1)*g)`` read kv head ``h``); ``page_table``
+    ``[B, pages_per_seq]`` int32; ``seq_lens`` ``[B]`` int32 true
+    lengths (0 = idle slot). Returns ``[B, num_heads, d]``.
     """
     B, nh, d = q.shape
-    nkv = k_pages.shape[2]
+    _, page_size, nkv, _ = k_pages.shape
     if nh % nkv:
         raise ValueError(f"num_heads {nh} must be a multiple of "
                          f"num_kv_heads {nkv}")
     g = nh // nkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if not _interpret() and d < _LANE:
-        # Mosaic wants full 128 lanes; interpret mode skips the pad (it
-        # would copy the whole pool per step for nothing on CPU)
-        pad = _LANE - d
-        q = jnp.pad(q, [(0, 0), (0, 0), (0, pad)])
-        k_pages = jnp.pad(k_pages, [(0, 0), (0, 0), (0, 0), (0, pad)])
-        v_pages = jnp.pad(v_pages, [(0, 0), (0, 0), (0, 0), (0, pad)])
-    q4 = q.reshape(B, nkv, g, q.shape[-1])
-    out = _paged_call(q4, k_pages, v_pages,
-                      page_table.astype(jnp.int32),
-                      seq_lens.astype(jnp.int32), float(scale))
-    return out.reshape(B, nh, -1)[..., :d]
+    interpret = _interpret()
+    with x64_off(interpret):
+        # q rides as [B, g, nkv, d]: row r of a block holds the r-th
+        # query of every kv head's group, aligned with a page's heads
+        q_block = pl.BlockSpec((1, g, nkv, d),
+                               lambda b, j, pt, sl: (b, 0, 0, 0))
+        # the paged gather: the page table picks which HBM page this
+        # grid step DMAs into VMEM
+        kv_block = pl.BlockSpec((1, page_size, nkv, d),
+                                lambda b, j, pt, sl: (pt[b, j], 0, 0, 0))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, page_table.shape[1]),
+            in_specs=[q_block, kv_block, kv_block],
+            out_specs=q_block,
+            scratch_shapes=[
+                pltpu.VMEM((g, nkv, 1), jnp.float32),
+                pltpu.VMEM((g, nkv, 1), jnp.float32),
+                pltpu.VMEM((g, nkv, d), jnp.float32),
+            ],
+        )
+        out = pl.pallas_call(
+            functools.partial(_decode_kernel, page_size=page_size, g=g,
+                              scale=float(scale)),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, g, nkv, d), q.dtype),
+            compiler_params=_ARB2,
+            interpret=interpret,
+            name="paged_attention_decode",
+        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+          q.reshape(B, nkv, g, d).swapaxes(1, 2), k_pages, v_pages)
+    return out.swapaxes(1, 2).reshape(B, nh, d)
 
 
 def _prefill_kernel(pt_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
                     m_scr, l_scr, acc_scr, *, page_size, scale):
-    """One (sequence b, head h, page block j) step of the ragged chunk
-    prefill: a whole C-row chunk attends one paged KV block per step,
+    """One (sequence b, page j) step of the ragged chunk prefill: a whole
+    C-row chunk attends one paged KV block per step, head by head,
     online-softmax state in VMEM scratch, the causal rule applied with
     the TRACED chunk offset (row ``off + i`` sees cols ``<= off + i``)."""
-    j = pl.program_id(2)
-    npg = pl.num_programs(2)
+    j = pl.program_id(1)
+    npg = pl.num_programs(1)
     off = off_ref[0]
-    C = q_ref.shape[1]
+    _, C, nh, _ = q_ref.shape
 
     @pl.when(j == 0)
     def _():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # ragged early-out: page blocks wholly past the last chunk row's
-    # position (col_start > off + C - 1) are fully masked — skip them
+    # ragged early-out: pages wholly past the last chunk row's position
+    # (col_start > off + C - 1) are fully masked — skip them
     run = j * np.int32(page_size) <= off + np.int32(C - 1)
 
     @pl.when(run)
     def _():
-        q = q_ref[0, :, 0, :]          # [C, d]
-        k = k_ref[0][:, 0, :]          # [page_size, d]
-        v = v_ref[0][:, 0, :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) \
-            * jnp.float32(scale)       # [C, page_size]
-        row = off + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        row = off + jax.lax.broadcasted_iota(jnp.int32, (C, page_size), 0)
         col = j * np.int32(page_size) + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(col <= row, s, jnp.float32(_NEG_INF))
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = corr * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = corr * acc_scr[:] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            jnp.int32, (C, page_size), 1)
+        seen = col <= row
+        for h in range(nh):
+            q = q_ref[0, :, h, :]          # [C, d]
+            k = k_ref[0, :, h, :]          # [page_size, d]
+            v = v_ref[0, :, h, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) \
+                * jnp.float32(scale)       # [C, page_size]
+            s = jnp.where(seen, s, jnp.float32(_NEG_INF))
+            m_prev = m_scr[h]              # [C, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            m_scr[h] = m_new
+            l_scr[h] = corr * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = corr * acc_scr[h] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
     @pl.when(j == npg - 1)
     def _():
         # col 0 is always <= every row (off >= 0), so l > 0 for real
         # rows; padded chunk rows still produce finite garbage
-        l = jnp.maximum(l_scr[:], jnp.float32(1e-30))
-        o_ref[0, :, 0, :] = (acc_scr[:] / l).astype(o_ref.dtype)
+        for h in range(nh):
+            l = jnp.maximum(l_scr[h], jnp.float32(1e-30))
+            o_ref[0, h] = (acc_scr[h] / l).astype(o_ref.dtype)
 
 
-@_no_x64
 def ragged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
                              scale=None, interpret=None):
     """True ragged Pallas chunk-prefill attention over a paged KV cache.
@@ -266,50 +254,42 @@ def ragged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
         interpret = _interpret()
-    Cp, dp = C, d
-    if not interpret:
-        # Mosaic tiling: chunk rows to the 8-sublane multiple, head dim
-        # to the 128-lane width; interpret mode skips both pads
-        Cp = -(-C // 8) * 8
-        dp = max(d, _LANE)
-        if dp != d:
-            k_pages = jnp.pad(k_pages, [(0, 0), (0, 0), (0, 0),
-                                        (0, dp - d)])
-            v_pages = jnp.pad(v_pages, [(0, 0), (0, 0), (0, 0),
-                                        (0, dp - d)])
-        if (Cp, dp) != (C, d):
-            q = jnp.pad(q, [(0, 0), (0, Cp - C), (0, 0), (0, dp - d)])
-    npt = page_table.shape[1]
-    off = jnp.reshape(jnp.asarray(q_offset, jnp.int32), (1,))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, nh, npt),
-        in_specs=[
-            pl.BlockSpec((1, Cp, 1, dp),
-                         lambda b, h, j, pt, off: (b, 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, dp),
-                         lambda b, h, j, pt, off: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, dp),
-                         lambda b, h, j, pt, off: (pt[b, j], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Cp, 1, dp),
-                               lambda b, h, j, pt, off: (b, 0, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Cp, 1), jnp.float32),
-            pltpu.VMEM((Cp, 1), jnp.float32),
-            pltpu.VMEM((Cp, dp), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_prefill_kernel, page_size=ps,
-                          scale=float(scale)),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Cp, nh, dp), q.dtype),
-        compiler_params=_ARB3,
-        interpret=interpret,
-        name="autofuse_ragged_prefill",
-    )(page_table.astype(jnp.int32), off, q, k_pages, v_pages)
-    return out[:, :C, :, :d]
+    # Mosaic tiling: chunk rows to the 8-sublane multiple (interpret
+    # mode skips the pad)
+    Cp = C if interpret else -(-C // 8) * 8
+    with x64_off(interpret):
+        if Cp != C:
+            q = jnp.pad(q, [(0, 0), (0, Cp - C), (0, 0), (0, 0)])
+        q_block = pl.BlockSpec((1, Cp, nh, d),
+                               lambda b, j, pt, off: (b, 0, 0, 0))
+        kv_block = pl.BlockSpec((1, ps, nh, d),
+                                lambda b, j, pt, off: (pt[b, j], 0, 0, 0))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, page_table.shape[1]),
+            in_specs=[q_block, kv_block, kv_block],
+            # head-major out: a whole [Cp, d] store per head (Mosaic
+            # refuses the strided bf16 store into [Cp, nh, d] at d < 128)
+            out_specs=pl.BlockSpec((1, nh, Cp, d),
+                                   lambda b, j, pt, off: (b, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((nh, Cp, 1), jnp.float32),
+                pltpu.VMEM((nh, Cp, 1), jnp.float32),
+                pltpu.VMEM((nh, Cp, d), jnp.float32),
+            ],
+        )
+        out = pl.pallas_call(
+            functools.partial(_prefill_kernel, page_size=ps,
+                              scale=float(scale)),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, nh, Cp, d), q.dtype),
+            compiler_params=_ARB2,
+            interpret=interpret,
+            name="autofuse_ragged_prefill",
+        )(page_table.astype(jnp.int32),
+          jnp.reshape(jnp.asarray(q_offset, jnp.int32), (1,)),
+          q, k_pages, v_pages)
+    return out.swapaxes(1, 2)[:, :C]
 
 
 def paged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,

@@ -69,8 +69,7 @@ def ring_attention_local(q, k, v, axis_name: str, causal: bool = False,
         return (o, m_new, l, kb, vb), None
 
     # mark the accumulators device-varying over the ring axis so the scan
-    # carry type matches across iterations (they mix with the varying kv);
-    # identity on jax versions without the varying-axis type system
+    # carry type matches across iterations (they mix with the varying kv)
     def _vary(x):
         from .._jax_compat import pvary
         return pvary(x, (axis_name,))

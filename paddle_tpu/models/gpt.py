@@ -1303,16 +1303,18 @@ class GPTGenerator:
         S_max = S_prompt + max_new
         assert S_max <= cfg.max_position_embeddings, \
             f"{S_max} > max_position_embeddings"
-        blocks, wte, wpe = self.blocks, self.wte, self.wpe
-        lnf_w, lnf_b = self.lnf_w, self.lnf_b
-
         # prefill rides the Pallas flash kernel through the SAME gate as
         # the training schedules; the decode loop stays XLA (a 1-row q
         # has nothing to tile)
         use_flash = flash_attention_gate(S_prompt, cfg.head_dim,
                                          self.use_flash)
 
-        def run(ids, key):
+        def run(weights, ids, key):
+            # the weights are ARGUMENTS: closed over, jit would bake them
+            # into the program as constants — at 345M a 2.8 GB executable
+            # that cannot be cached and costs tens of GB of host memory
+            # to compile
+            blocks, wte, wpe, lnf_w, lnf_b = weights
             # ---- prefill: full pass, capture KV per layer
             h = wte[ids] + wpe[jnp.arange(S_prompt)]
 
@@ -1372,5 +1374,7 @@ class GPTGenerator:
         # advance per call: repeated sampling yields distinct completions
         self._calls = getattr(self, "_calls", 0) + 1
         key = jax.random.fold_in(jax.random.key(self.seed), self._calls)
-        new = self._compiled[sig](ids, key)
+        new = self._compiled[sig](
+            (self.blocks, self.wte, self.wpe, self.lnf_w, self.lnf_b),
+            ids, key)
         return Tensor(jnp.concatenate([ids, new], axis=1))

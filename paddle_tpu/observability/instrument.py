@@ -652,7 +652,6 @@ CHIP_SPECS = {
     "v6":  dict(peak_flops=918e12, hbm_bw=1640e9, ici_bw=367e9, hbm_gb=32),
     "cpu": dict(peak_flops=1e12, hbm_bw=50e9, ici_bw=10e9, hbm_gb=8),
 }
-_DEFAULT_CHIP = "v5p"
 
 _cpu_bench_cache: dict | None = None
 
@@ -722,7 +721,13 @@ def chip_specs(kind: str | None = None) -> dict:
             spec = dict(row, name=k)
             break
     if spec is None:
-        spec = dict(CHIP_SPECS[_DEFAULT_CHIP], name=_DEFAULT_CHIP)
+        # a device the table does not know has no peak to divide by: an
+        # MFU or a roofline priced with another chip's row is a wrong
+        # number, not an estimate
+        raise ValueError(
+            f"chip_specs: unknown device kind {kind!r} — not one of "
+            f"{sorted(CHIP_SPECS)}; add its row to CHIP_SPECS or pass "
+            f"kind=/PADDLE_CHIP_KIND for a trace-only tool")
     if spec["name"] == "cpu":
         spec.update(_cpu_microbench())
     from .calibration import active_calibration, apply_to_chip
@@ -730,8 +735,8 @@ def chip_specs(kind: str | None = None) -> dict:
 
 
 def peak_flops_per_chip() -> float:
-    """bf16 peak for the attached chip; conservative v5p default (the
-    table bench.py historically carried, now shared)."""
+    """bf16 peak for the attached chip (raises on a device kind the
+    table does not know)."""
     return chip_specs()["peak_flops"]
 
 

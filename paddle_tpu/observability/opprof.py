@@ -166,7 +166,7 @@ def _inner_jaxpr(eqn):
     """(jaxpr, consts) of a transparent call-like eqn the interpreters
     descend into — matching the cost walk, so site ids line up."""
     name = eqn.primitive.name
-    if name in ("pjit", "closed_call", "custom_jvp_call",
+    if name in ("jit", "closed_call", "custom_jvp_call",
                 "custom_vjp_call", "remat2", "checkpoint", "remat"):
         inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
         if hasattr(inner, "jaxpr"):          # ClosedJaxpr
@@ -196,7 +196,7 @@ def _run_jaxpr(jaxpr, consts, args, timings=None):
     **tagging pass**: pure re-evaluation, safe to trace/jit, leaving
     the scope names in the lowered program's op metadata."""
     import jax
-    from jax import core
+    from jax.extend import core
     env: dict = {}
 
     def read(v):

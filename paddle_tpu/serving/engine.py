@@ -496,6 +496,12 @@ class ServingEngine:
                 for sb in self.prefill_buckets}
             self._scatter_jit = jax.jit(
                 scatter_kv_fn, donate_argnums=(0, 1) if donate else ())
+        else:
+            # the weights live with the pool, where the programs run: a
+            # model built on another backend (host-side init) would
+            # otherwise cross to the device again on every call
+            self.params = jax.device_put(
+                self.params, next(iter(self.pool.k_pages.devices())))
         self._decode_exe: dict = {}
         self._prefill_exe: dict = {}
         self._chunk_exe = None

@@ -254,6 +254,9 @@ class MoEServingEngine:
                              head_dim=cfg.head_dim,
                              dtype=self.params["wte"].dtype,
                              max_seq_len=max_seq_len)
+        # the weights live with the pool, where the programs run
+        self.params = jax.device_put(
+            self.params, next(iter(self.pool.k_pages.devices())))
         self._key = jax.random.key(int(seed))
         self._calls = 0
         self._last_token: dict = {}

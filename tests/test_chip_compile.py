@@ -1,0 +1,139 @@
+"""The kernels of the main path, compiled at real widths for the chip.
+
+The only test file that describes the chip. The TPU's compiler is
+installed here and compiles for a v5e that is described, not attached
+(``/opt/skills/guides/on-chip-measurement`` section 2): what it refuses
+here it refuses there, at no chip time. Interpret mode — all the other
+kernel tests — cannot see tiling, 64-bit indices or VMEM limits.
+
+Only one process may load the TPU's library, and every xdist worker
+imports every test file: the topology is described inside a
+module-scoped fixture (never at import, in a ``skipif`` or in a
+``parametrize`` argument), each test compiles in its own process, and
+these tests stay in this one file. Each test flips the kernel module's
+own ``_interpret`` gate — the program has no option for it.
+"""
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+import paddle_tpu  # noqa: F401  (x64 on, as every user of the kernels has it)
+from paddle_tpu.kernels import (flash_attention, int8_matmul, moe_dispatch,
+                                paged_attention)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compile(monkeypatch, module, sharding, fn, *shapes):
+    """Compile ``fn`` for the described chip with ``module``'s kernels
+    lowered by Mosaic, and return the number of kernels in the program."""
+    monkeypatch.setattr(module, "_interpret", lambda: False)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    # conftest.py asks for exact f32 matmuls (its oracles need them); the
+    # chip runs the production default, and Mosaic refuses an fp32
+    # contraction of bf16 operands
+    with jax.default_matmul_precision("default"):
+        return jax.jit(fn).lower(*args).compile().as_text().count(
+            "tpu_custom_call")
+
+
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+_FLASH = ((96, 1024, 128), BF16)      # B12 x 8 heads, S1024, d128
+
+
+def test_flash_fwd(monkeypatch, one_chip, no_compile_cache):
+    def fwd(q, k, v):
+        return flash_attention.flash_attention(q, k, v, causal=True)
+    assert _compile(monkeypatch, flash_attention, one_chip, fwd,
+                    _FLASH, _FLASH, _FLASH) == 1
+
+
+def test_flash_fwd_bwd(monkeypatch, one_chip, no_compile_cache):
+    def loss(q, k, v):
+        return flash_attention.flash_attention(
+            q, k, v, causal=True).astype(F32).sum()
+    assert _compile(monkeypatch, flash_attention, one_chip,
+                    jax.grad(loss, argnums=(0, 1, 2)),
+                    _FLASH, _FLASH, _FLASH) == 3
+
+
+@pytest.mark.parametrize("nh,d", [(8, 128), (16, 64)])
+def test_paged_decode(monkeypatch, one_chip, no_compile_cache, nh, d):
+    pool = ((512, 64, nh, d), BF16)
+    assert _compile(monkeypatch, paged_attention, one_chip,
+                    paged_attention.paged_attention_decode,
+                    ((8, nh, d), BF16), pool, pool,
+                    ((8, 16), I32), ((8,), I32)) == 1
+
+
+@pytest.mark.parametrize("nh,d", [(8, 128), (16, 64)])
+def test_ragged_prefill_c256(monkeypatch, one_chip, no_compile_cache,
+                             nh, d):
+    pool = ((512, 64, nh, d), BF16)
+    assert _compile(monkeypatch, paged_attention, one_chip,
+                    paged_attention.ragged_prefill_attention,
+                    ((1, 256, nh, d), BF16), pool, pool,
+                    ((1, 16), I32), ((), I32)) == 1
+
+
+@pytest.mark.parametrize("m,k,n", [(8, 1024, 4096), (8, 4096, 1024)])
+def test_int8_matmul(monkeypatch, one_chip, no_compile_cache, m, k, n):
+    assert _compile(monkeypatch, int8_matmul, one_chip,
+                    int8_matmul.int8_matmul,
+                    ((m, k), BF16), ((k, n), I8), ((n,), F32)) == 1
+
+
+# ERNIE-MoE-base widths: T1024 tokens, 8 experts, M768, top-2, capacity
+# 1.25 x T x k / E = 320
+_MOE = dict(T=1024, E=8, M=768, K=2, C=320)
+
+
+def test_moe_dispatch(monkeypatch, one_chip, no_compile_cache):
+    T, E, M, K, C = (_MOE[k] for k in "TEMKC")
+
+    def dispatch(x, gw, gb):
+        return moe_dispatch.fused_moe_dispatch(
+            x, gw, gb, num_expert=E, capacity=C, top_k=K,
+            gate_kind="gshard")
+    assert _compile(monkeypatch, moe_dispatch, one_chip, dispatch,
+                    ((T, M), BF16), ((M, E), F32), ((E,), F32)) == 1
+
+
+def test_moe_combine(monkeypatch, one_chip, no_compile_cache):
+    T, E, M, K, C = (_MOE[k] for k in "TEMKC")
+    assert _compile(monkeypatch, moe_dispatch, one_chip,
+                    moe_dispatch.fused_moe_combine,
+                    ((E * C, M), BF16), ((T, K), F32), ((T, K), I32)) == 1
