@@ -214,6 +214,7 @@ def _fwd(q, k, v, causal, scale, g=1, kv_len=None, q_offset=0):
             ],
             compiler_params=_ARB,
             interpret=_interpret(),
+            name="flash_attention_fwd",
         )(q, k, v)
 
 
@@ -344,6 +345,7 @@ def _bwd(causal, scale, g, kv_len, q_offset, residuals, do):
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
             compiler_params=_ARB,
             interpret=_interpret(),
+            name="flash_attention_bwd_dq",
         )(q, k, v, do, lse, delta)
 
         # dk/dv: one program per KV head; the innermost dim walks the g*nq
@@ -378,6 +380,7 @@ def _bwd(causal, scale, g, kv_len, q_offset, residuals, do):
             ],
             compiler_params=_ARB,
             interpret=_interpret(),
+            name="flash_attention_bwd_dkv",
         )(q, k, v, do, lse, delta)
         return dq, dk, dv
 

@@ -40,6 +40,7 @@ from ..nn import initializer as I
 from ..framework.tensor import Tensor, Parameter
 from ..framework import random as random_mod
 from ..ops._dispatch import apply, unwrap
+from ..profiler.utils import RecordEvent
 
 __all__ = [
     "GPTConfig", "GPTDecoderLayer", "GPTEmbeddings", "GPTModel",
@@ -1123,9 +1124,14 @@ class GPTHybridTrainStep:
 
     # ------------------------------------------------------------------
     def __call__(self, input_ids, labels):
+        with RecordEvent("train.step", "Operator",
+                         annotation=jax.profiler.StepTraceAnnotation,
+                         step_num=self._t + 1):
+            return self._step(input_ids, labels)
+
+    def _step(self, input_ids, labels):
         import time as _time
         from ..observability import instrument as _obs
-        from ..profiler.utils import RecordEvent
         t_step = _time.perf_counter()
         ids = unwrap(input_ids) if isinstance(input_ids, Tensor) \
             else jnp.asarray(input_ids)
@@ -1167,6 +1173,8 @@ class GPTHybridTrainStep:
             lr_val = lr_src
         lr = jnp.asarray(lr_val, jnp.float32)
         t = jnp.asarray(self._t, jnp.float32)
+        # the compiled call until it returns: the host's time to hand the
+        # step to the device, not the step's device time
         with RecordEvent("GPTHybridTrainStep.step", "Operator"):
             loss, self.params, self.opt_state = self._compiled(
                 self.params, self.opt_state, ids, labs, lr, t)
@@ -1178,11 +1186,13 @@ class GPTHybridTrainStep:
             _obs.record_compile(_time.perf_counter() - t_built,
                                 what="GPTHybridTrainStep.first_call")
         else:
-            _obs.record_train_step(
-                _time.perf_counter() - t_step, tokens=int(ids.size),
-                flops_per_token=getattr(self, "flops_per_token", None),
-                path="gpt_hybrid", loss=loss)
-        _obs.sample_device_memory()
+            with RecordEvent("train.account"):
+                _obs.record_train_step(
+                    _time.perf_counter() - t_step, tokens=int(ids.size),
+                    flops_per_token=getattr(self, "flops_per_token", None),
+                    path="gpt_hybrid", loss=loss)
+        with RecordEvent("train.mem_sample"):
+            _obs.sample_device_memory()
         return Tensor(loss)
 
     train_batch = __call__

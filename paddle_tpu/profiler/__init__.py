@@ -4,8 +4,14 @@ The profiler is the *tracing* half of the observability stack:
 
 - :class:`Profiler` — scheduler-driven record spans; ``export()`` writes
   chrome://tracing JSON containing the ``RecordEvent`` spans emitted by
-  the instrumented hot paths (``ParallelTrainStep``, the eager
-  collectives) plus ``"ph": "C"`` counter tracks (device memory).
+  the instrumented hot paths (the train steps, the eager collectives,
+  the serving scheduler and engine) plus ``"ph": "C"`` counter tracks
+  (device memory).
+- :class:`RecordEvent` — the one span recorder: kept while a
+  ``Profiler`` records **or** a jax device trace is being taken (then
+  also a jax trace annotation on the trace's clock), one predicate
+  otherwise; :func:`~paddle_tpu.profiler.utils.recorded_spans` reads
+  the records (a tree by ``parent_id``) without draining them.
 - :func:`~paddle_tpu.profiler.utils.record_counter` — add a counter
   sample to the active record span.
 - ``tools/trace_summary.py`` — post-hoc aggregate table over an exported

@@ -158,7 +158,7 @@ class Profiler:
         self.timer_only = timer_only
         self.step_num = 0
         self.current_state = ProfilerState.CLOSED
-        self._events = []            # drained host events across record spans
+        self._events = []            # drained utils.Span records
         self._counters = []          # drained (name, ts_ns, value) samples
         self._jax_trace_dir = None
         self._jax_tracing = False
@@ -256,11 +256,14 @@ class Profiler:
                 "tools/perf_doctor.py --ops and tools/trace_summary.py)")
         assert format == "json", format
         events = []
-        for name, tid, t0, t1, etype in self._events:
+        for ev in self._events:
             events.append({
-                "name": name, "ph": "X", "cat": etype,
-                "pid": os.getpid(), "tid": tid,
-                "ts": t0 / 1e3, "dur": (t1 - t0) / 1e3,  # µs
+                "name": ev.name, "ph": "X", "cat": ev.type,
+                "pid": os.getpid(), "tid": ev.tid,
+                "ts": ev.start_ns / 1e3,  # µs
+                "dur": (ev.end_ns - ev.start_ns) / 1e3,
+                "args": dict(ev.attrs, span_id=ev.span_id,
+                             parent_id=ev.parent_id),
             })
         for name, ts, value in self._counters:
             events.append({
@@ -286,7 +289,7 @@ class Profiler:
         dict (profiler_statistic.py condensed; totals keyed ``total_ms``
         for stability plus ``total_<unit>`` for the requested unit)."""
         agg = aggregate_events(
-            (name, t1 - t0) for name, _tid, t0, t1, _etype in self._events)
+            (ev.name, ev.end_ns - ev.start_ns) for ev in self._events)
         lines = format_agg_table(agg, time_unit=time_unit)
         if self._step_times:
             scale, unit = _time_scale(time_unit)

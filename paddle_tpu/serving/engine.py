@@ -27,7 +27,11 @@ tokens/s, per-token samples) lands on each finished
 :class:`~.scheduler.Request` via its ``observability.reqtrace.
 RequestTrace``. :meth:`ServingEngine.status` is the engine-side slice of
 the scheduler's live ``/status`` endpoint (weights, buckets, compile
-time, pool utilization/fragmentation).
+time, pool utilization/fragmentation). Under a device trace the chunked
+prefill and the decode are ``RecordEvent`` spans (``engine.prefill_begin``
+/ ``prefill_step`` / ``decode``) with ``engine.host_prep`` /
+``dispatch`` / ``readback`` inside; ``in_flight`` on them counts the
+programs dispatched since the engine's last readback.
 """
 from __future__ import annotations
 
@@ -44,6 +48,7 @@ from ..models.gpt import (GPTConfig, _ln, flash_attention_gate, gpt_block,
 from ..kernels.paged_attention import (paged_attention_decode,
                                        paged_attention_reference,
                                        paged_prefill_attention)
+from ..profiler.utils import RecordEvent
 from .kv_pool import PagePool
 from .prefix_cache import PrefixCache
 
@@ -386,6 +391,10 @@ class ServingEngine:
         self.max_seq_len = max_seq_len
         self._key = jax.random.key(int(seed))
         self._calls = 0
+        # programs dispatched since the last readback: a readback waits
+        # for all of them, so this tells a decode that waited for its
+        # own program from one that also waited for a chunk
+        self._in_flight = 0
         # ---- chunked prefill + prefix cache (tentpole features) -----
         # prefix sharing needs the offset-aware chunk program (a suffix
         # prefill starts mid-prompt), so prefix_cache implies chunking
@@ -774,6 +783,7 @@ class ServingEngine:
             jnp.asarray(rows), self._next_key())
         self.pool.bind(kp, vp)
         tok = int(np.asarray(tok)[0])
+        self._in_flight = 0
         self._last_token[seq_id] = tok
         return tok
 
@@ -805,6 +815,8 @@ class ServingEngine:
         kp, vp = scatter(self.pool.k_pages, self.pool.v_pages, ks, vs,
                          self._to_decode(rows))
         self.pool.bind(kp, vp)
+        self._in_flight += 1    # the scatter: the token's readback
+        #                         waits for the prefill side alone
         tok = int(np.asarray(tok)[0])
         self._last_token[seq_id] = tok
         return tok
@@ -823,12 +835,29 @@ class ServingEngine:
                 "(prefill_chunk=...)")
         prompt = self._check_prompt_room(prompt_ids)
         n = int(prompt.shape[0])
-        cached_len = 0
-        if self.prefix_cache is not None:
-            cache = self.prefix_cache
+        with RecordEvent("engine.prefill_begin", rid=seq_id,
+                         prompt_len=n) as ev:
+            cached_len = self._alloc_prompt(seq_id, prompt, n)
+            ev.set(cached_len=cached_len)
+        self._chunk_state[seq_id] = {"prompt": prompt, "pos": cached_len,
+                                     "n": n}
+        self._cached_len[seq_id] = cached_len
+        return cached_len
+
+    def _alloc_prompt(self, seq_id, prompt, n) -> int:
+        """Pages for a prompt of ``n`` tokens, the cached prefix mapped
+        in; returns the cached prefix length."""
+        if self.prefix_cache is None:
+            self.pool.note_prefix_lookup(0)
+            with RecordEvent("pool.alloc"):
+                self.pool.alloc(seq_id, n)
+            return 0
+        cache = self.prefix_cache
+        with RecordEvent("prefix.match"):
             nodes, boundary, cached_len = cache.match(prompt)
             pages = cache.map_into(seq_id, nodes, boundary)
-            cow = None
+        cow = None
+        with RecordEvent("pool.alloc", cow=boundary is not None):
             try:
                 if boundary is not None:
                     cow = self.pool._take_page()
@@ -839,6 +868,7 @@ class ServingEngine:
                         jnp.asarray(np.int32(boundary[0].page)),
                         jnp.asarray(np.int32(cow)))
                     self.pool.bind(kp, vp)
+                    self._in_flight += 1
                     pages = pages + [cow]
                 self.pool.alloc_prefixed(seq_id, n, pages, cached_len)
             except Exception:
@@ -853,12 +883,6 @@ class ServingEngine:
                 # COW page; drop the engine's transient one (net: the
                 # copy is private to the sequence)
                 self.pool.decref([cow])
-        else:
-            self.pool.note_prefix_lookup(0)
-            self.pool.alloc(seq_id, n)
-        self._chunk_state[seq_id] = {"prompt": prompt, "pos": cached_len,
-                                     "n": n}
-        self._cached_len[seq_id] = cached_len
         return cached_len
 
     def prefill_step(self, seq_id):
@@ -867,33 +891,49 @@ class ServingEngine:
         its per-tick prefill token budget on these, so a long prompt
         interleaves with decode ticks instead of stalling them."""
         st = self._chunk_state[seq_id]
-        C = self.prefill_chunk
         start, n = st["pos"], st["n"]
-        clen = min(C, n - start)
-        ids = np.zeros((1, C), np.int32)
-        ids[0, :clen] = st["prompt"][start:start + clen]
-        rows = self.pool.chunk_rows(seq_id, start, C)
-        table = self.pool.table_array([seq_id])
-        fn = self._chunk_exe if self._chunk_exe is not None \
-            else self._chunk_jit
-        kp, vp, tok = fn(
-            self.params, self.pool.k_pages, self.pool.v_pages,
-            jnp.asarray(ids), jnp.asarray(np.int32(start)),
-            jnp.asarray(np.int32(clen)), jnp.asarray(table),
-            jnp.asarray(rows), self._next_key())
-        self.pool.bind(kp, vp)
-        st["pos"] = start + clen
-        if st["pos"] < n:
-            return clen, False, None
-        tok = int(np.asarray(tok)[0])
-        self._last_token[seq_id] = tok
-        del self._chunk_state[seq_id]
-        if self.prefix_cache is not None:
-            # content now exists: publish the prompt's full pages so
-            # queued same-prefix requests hit them
-            self.prefix_cache.insert(st["prompt"],
-                                     self.pool.table(seq_id))
+        clen = min(self.prefill_chunk, n - start)
+        with RecordEvent("engine.prefill_step", rid=seq_id, start=start,
+                         clen=clen, final=start + clen >= n,
+                         in_flight=self._in_flight):
+            tok = self._run_chunk(seq_id, st, start, clen)
+            if tok is None:
+                return clen, False, None
+            del self._chunk_state[seq_id]
+            if self.prefix_cache is not None:
+                # content now exists: publish the prompt's full pages so
+                # queued same-prefix requests hit them
+                self.prefix_cache.insert(st["prompt"],
+                                         self.pool.table(seq_id))
         return clen, True, tok
+
+    def _run_chunk(self, seq_id, st, start, clen):
+        """Prepare, dispatch and, on a prompt's last chunk only, read
+        back one chunk program: the first token, or None before it."""
+        C = self.prefill_chunk
+        with RecordEvent("engine.host_prep"):
+            ids = np.zeros((1, C), np.int32)
+            ids[0, :clen] = st["prompt"][start:start + clen]
+            rows = self.pool.chunk_rows(seq_id, start, C)
+            table = self.pool.table_array([seq_id])
+            fn = self._chunk_exe if self._chunk_exe is not None \
+                else self._chunk_jit
+            args = (jnp.asarray(ids), jnp.asarray(np.int32(start)),
+                    jnp.asarray(np.int32(clen)), jnp.asarray(table),
+                    jnp.asarray(rows), self._next_key())
+        with RecordEvent("engine.dispatch"):
+            kp, vp, tok = fn(self.params, self.pool.k_pages,
+                             self.pool.v_pages, *args)
+            self.pool.bind(kp, vp)
+        self._in_flight += 1
+        st["pos"] = start + clen
+        if st["pos"] < st["n"]:
+            return None
+        with RecordEvent("engine.readback", in_flight=self._in_flight):
+            tok = int(np.asarray(tok)[0])
+        self._in_flight = 0
+        self._last_token[seq_id] = tok
+        return tok
 
     def cached_prefix_len(self, seq_id) -> int:
         """Tokens this sequence reused from the prefix cache."""
@@ -907,21 +947,30 @@ class ServingEngine:
         bucket = self.decode_bucket(n) if bucket is None else bucket
         if n > bucket:
             raise EngineShapeError(f"{n} sequences > bucket {bucket}")
-        slots = list(seq_ids) + [None] * (bucket - n)
-        lens = self.pool.lens_array(slots)
-        table = self.pool.table_array(slots)
-        tokens = np.asarray(
-            [self._last_token.get(sid, 0) for sid in slots], np.int32)
-        positions = np.maximum(lens - 1, 0).astype(np.int32)
-        kp, vp, nxt = self._decode_fn(bucket)(
-            self.params, self.pool.k_pages, self.pool.v_pages,
-            self._to_decode(tokens), self._to_decode(positions),
-            self._to_decode(table), self._to_decode(lens),
-            self._to_decode(self._next_key()))
-        self.pool.bind(kp, vp)
-        out = [int(t) for t in np.asarray(nxt)[:n]]
-        for sid, t in zip(seq_ids, out):
-            self._last_token[sid] = t
+        with RecordEvent("engine.decode", n=n, bucket=bucket,
+                         in_flight=self._in_flight):
+            with RecordEvent("engine.host_prep"):
+                slots = list(seq_ids) + [None] * (bucket - n)
+                lens = self.pool.lens_array(slots)
+                table = self.pool.table_array(slots)
+                tokens = np.asarray(
+                    [self._last_token.get(sid, 0) for sid in slots],
+                    np.int32)
+                positions = np.maximum(lens - 1, 0).astype(np.int32)
+                args = [self._to_decode(x) for x in (
+                    tokens, positions, table, lens, self._next_key())]
+            with RecordEvent("engine.dispatch"):
+                kp, vp, nxt = self._decode_fn(bucket)(
+                    self.params, self.pool.k_pages, self.pool.v_pages,
+                    *args)
+                self.pool.bind(kp, vp)
+            self._in_flight += 1
+            with RecordEvent("engine.readback",
+                             in_flight=self._in_flight):
+                out = [int(t) for t in np.asarray(nxt)[:n]]
+                for sid, t in zip(seq_ids, out):
+                    self._last_token[sid] = t
+            self._in_flight = 0
         return out
 
     # engine tracks each sequence's pending (last sampled, not yet

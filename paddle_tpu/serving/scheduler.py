@@ -33,6 +33,11 @@ Telemetry — aggregate AND request-scoped:
   (``slo=...``) enforces TTFT / per-token / queue-wait targets with
   burn-rate accounting, violation events, and flight dumps naming the
   offending rids;
+- while a device trace is being taken (or a ``Profiler`` records) every
+  tick is a ``sched.step`` :class:`~paddle_tpu.profiler.RecordEvent`
+  with one child per phase (``sched.expire`` / ``evict`` / ``admit`` /
+  ``prefill_tick`` / ``hooks`` / ``decode_tick`` / ``account``) and the
+  engine's spans under those; off, each is one predicate;
 - :meth:`ContinuousBatchingScheduler.serve_http` exposes ``/metrics``,
   ``/healthz`` (flips unhealthy after an engine failure), and
   ``/status`` (queue/pool/SLO snapshot) on a stdlib HTTP thread.
@@ -49,6 +54,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..observability import lockwitness
+from ..profiler.utils import RecordEvent
 
 __all__ = ["Request", "ContinuousBatchingScheduler",
            "simulate_decode_signatures"]
@@ -71,6 +77,7 @@ class Request:
     eos_id: int | None = None
     submit_time: float = field(default_factory=time.perf_counter)
     admit_time: float | None = None
+    prefill_start_time: float | None = None  # its first chunk began
     first_token_time: float | None = None
     finish_time: float | None = None
     prefill_s: float | None = None     # measured prefill walltime
@@ -120,9 +127,12 @@ class Request:
         None`` guards throughout: a monotonic clock CAN legitimately
         read 0.0, so truthiness would misreport a real timestamp as
         missing."""
-        queue_wait = ttft = decode_s = total_s = tps = None
+        queue_wait = prefill_wait = ttft = decode_s = total_s = tps = None
         if self.admit_time is not None:
             queue_wait = self.admit_time - self.submit_time
+            if self.prefill_start_time is not None:
+                # admitted, waiting behind other prompts' chunks
+                prefill_wait = self.prefill_start_time - self.admit_time
         if self.first_token_time is not None:
             ttft = self.first_token_time - self.submit_time
         if self.finish_time is not None:
@@ -136,7 +146,8 @@ class Request:
                "prompt_len": int(self.prompt.shape[0]),
                "new_tokens": len(self.tokens),
                "router_wait_s": self.router_wait_s,
-               "queue_wait_s": queue_wait, "ttft_s": ttft,
+               "queue_wait_s": queue_wait,
+               "prefill_wait_s": prefill_wait, "ttft_s": ttft,
                "prefill_s": self.prefill_s,
                "cached_prefix_len": self.cached_prefix_len,
                "prefill_chunks": self.prefill_chunks,
@@ -499,9 +510,10 @@ class ContinuousBatchingScheduler:
             except Exception:
                 pass  # telemetry must never take the serving loop down
 
-    def _evict_finished(self):
+    def _evict_finished(self) -> int:
         from ..observability import instrument as obs
-        for rid in [rid for rid, r in self._running.items() if r.done]:
+        done = [rid for rid, r in self._running.items() if r.done]
+        for rid in done:
             r = self._running.pop(rid)
             held = len(self.engine.pool.table(rid))
             self._reserved_pages -= self._completion_pages(r) - held
@@ -521,6 +533,7 @@ class ContinuousBatchingScheduler:
             del self.finished[:-self.max_retained]
             obs.serving_requests_counter().inc(event="finished")
             self._log_request(r)
+        return len(done)
 
     def _page_room(self, need: int) -> bool:
         """Free pages (after reservations) cover ``need``? Under
@@ -595,6 +608,7 @@ class ContinuousBatchingScheduler:
             pool = eng.pool
             t0 = time.perf_counter()
             if rid not in self._begun:
+                r.prefill_start_time = t0
                 cached = eng.prefill_begin(rid, r.prompt)
                 self._begun.add(rid)
                 r.cached_prefix_len = cached
@@ -608,9 +622,10 @@ class ContinuousBatchingScheduler:
             spent += processed
             r.prefill_s += dt
             r.prefill_chunks += 1
-            obs.serving_prefill_chunks_counter().inc()
-            obs.record_train_step(dt, tokens=processed,
-                                  path="serving_prefill")
+            with RecordEvent("sched.account", path="serving_prefill"):
+                obs.serving_prefill_chunks_counter().inc()
+                obs.record_train_step(dt, tokens=processed,
+                                      path="serving_prefill")
             if not done:
                 continue
             del self._prefilling[rid]
@@ -620,21 +635,23 @@ class ContinuousBatchingScheduler:
             r.state = "running"
             r.first_token_time = t_done
             self._running[rid] = r
-            if r.trace is not None:
-                r.trace.span("prefill", r.admit_time, t_done,
-                             prompt_len=int(r.prompt.shape[0]),
-                             chunks=r.prefill_chunks,
-                             cached_prefix_len=r.cached_prefix_len)
-            obs.serving_prefill_histogram().observe(r.prefill_s)
-            obs.serving_ttft_histogram().observe(
-                r.first_token_time - r.submit_time)
-            obs.serving_tokens_out_counter().inc()
-            if self.slo is not None:
-                self.slo.observe_admission(
-                    rid, ttft_s=r.first_token_time - r.submit_time,
-                    queue_wait_s=r.admit_time - r.submit_time)
+            with RecordEvent("sched.account", path="first_token"):
+                if r.trace is not None:
+                    r.trace.span("prefill", r.admit_time, t_done,
+                                 prompt_len=int(r.prompt.shape[0]),
+                                 chunks=r.prefill_chunks,
+                                 cached_prefix_len=r.cached_prefix_len)
+                obs.serving_prefill_histogram().observe(r.prefill_s)
+                obs.serving_ttft_histogram().observe(
+                    r.first_token_time - r.submit_time)
+                obs.serving_tokens_out_counter().inc()
+                if self.slo is not None:
+                    self.slo.observe_admission(
+                        rid, ttft_s=r.first_token_time - r.submit_time,
+                        queue_wait_s=r.admit_time - r.submit_time)
         if spent:
             self.prefill_tokens_per_tick.append(spent)
+        return spent
 
     def _admit(self):
         from ..observability import instrument as obs
@@ -652,7 +669,7 @@ class ContinuousBatchingScheduler:
             del self._queue[i]
             self._brownout_clamp(r)
             need = self._completion_pages(r)
-            r.admit_time = time.perf_counter()
+            r.admit_time = r.prefill_start_time = time.perf_counter()
             # the prefill IS part of the serving hot path: time it, so
             # it reaches the histogram, the flight recorder, and the
             # anomaly monitors (path="serving_prefill") — invisible
@@ -692,7 +709,10 @@ class ContinuousBatchingScheduler:
         failure marks the scheduler unhealthy (``/healthz`` → 503) and
         re-raises."""
         try:
-            with self._lock:
+            with self._lock, RecordEvent(
+                    "sched.step", step=self.steps, queued=len(self._queue),
+                    prefilling=len(self._prefilling),
+                    running=len(self._running)):
                 return self._step_locked()
         except Exception as e:
             self.healthy = False
@@ -704,23 +724,34 @@ class ContinuousBatchingScheduler:
             raise
 
     def _step_locked(self) -> bool:
+        """The tick, phase by phase. Each phase is a ``RecordEvent``
+        under ``step()``'s ``sched.step`` (kept only while a trace is
+        being taken): what is left of the step outside them is its own
+        time."""
         from ..observability import instrument as obs
-        now = time.perf_counter()
-        self._update_mode(now)
-        self._cancel_expired(now)
-        self._evict_finished()
-        self._admit()
+        with RecordEvent("sched.expire"):
+            now = time.perf_counter()
+            self._update_mode(now)
+            self._cancel_expired(now)
+        with RecordEvent("sched.evict") as ev:
+            ev.set(n_evicted=self._evict_finished())
+        with RecordEvent("sched.admit") as ev:
+            waiting = len(self._queue)
+            self._admit()
+            ev.set(n_admitted=waiting - len(self._queue))
         if self.chunked:
-            self._prefill_tick()
-        if self.mode == "healthy":
+            with RecordEvent("sched.prefill_tick") as ev:
+                ev.set(tokens=self._prefill_tick())
+        if self.mode == "healthy" and self.background_hooks:
             # speculative/background work runs only with headroom;
             # brownout/shedding pause it (cache reclaim stays on — it
             # frees capacity, it doesn't spend it)
-            for hook in self.background_hooks:
-                try:
-                    hook()
-                except Exception:
-                    pass  # background work must never take the loop down
+            with RecordEvent("sched.hooks"):
+                for hook in self.background_hooks:
+                    try:
+                        hook()
+                    except Exception:
+                        pass  # background work must never take the loop down
         obs.serving_queue_depth_gauge().set(float(len(self._queue)))
         obs.serving_kv_pages_gauge().set(
             float(self.engine.pool.pages_in_use))
@@ -732,34 +763,39 @@ class ContinuousBatchingScheduler:
         # ONE bucket-selection implementation: the engine's (raises
         # EngineShapeError on overflow, same as every other shape gate)
         bucket = self.engine.decode_bucket(len(active))
-        pool = self.engine.pool
-        for r in active:
-            held = len(pool.table(r.rid))
-            pool.extend(r.rid, 1)
-            self._reserved_pages -= len(pool.table(r.rid)) - held
-        toks = self.engine.decode([r.rid for r in active], bucket)
+        with RecordEvent("sched.decode_tick", n_active=len(active),
+                         bucket=bucket):
+            pool = self.engine.pool
+            with RecordEvent("pool.extend"):
+                for r in active:
+                    held = len(pool.table(r.rid))
+                    pool.extend(r.rid, 1)
+                    self._reserved_pages -= len(pool.table(r.rid)) - held
+            toks = self.engine.decode([r.rid for r in active], bucket)
         dt = time.perf_counter() - t0
-        per_token = obs.serving_per_token_histogram()
         for r, t in zip(active, toks):
             r.tokens.append(t)
-            if r.trace is not None:
-                r.trace.add_token(dt)
-            per_token.observe(dt)
-        if self.slo is not None:
-            self.slo.observe_tokens([r.rid for r in active], dt)
-        if self.mode != "healthy":
-            # degraded time is attributable: the doctor carves it out
-            # of the decode residual exactly like migration cost
-            self.degraded_s_total += dt
-            obs.serving_degraded_seconds_counter().inc(dt)
+        with RecordEvent("sched.account", path="serving"):
+            per_token = obs.serving_per_token_histogram()
             for r in active:
-                r.degraded_s += dt
-        self.steps += 1
-        self.step_times.append(dt)
-        obs.serving_tokens_out_counter().inc(float(len(active)))
-        # serving steps feed the flight recorder + anomaly monitors the
-        # same way train steps do
-        obs.record_train_step(dt, tokens=len(active), path="serving")
+                if r.trace is not None:
+                    r.trace.add_token(dt)
+                per_token.observe(dt)
+            if self.slo is not None:
+                self.slo.observe_tokens([r.rid for r in active], dt)
+            if self.mode != "healthy":
+                # degraded time is attributable: the doctor carves it
+                # out of the decode residual exactly like migration cost
+                self.degraded_s_total += dt
+                obs.serving_degraded_seconds_counter().inc(dt)
+                for r in active:
+                    r.degraded_s += dt
+            self.steps += 1
+            self.step_times.append(dt)
+            obs.serving_tokens_out_counter().inc(float(len(active)))
+            # serving steps feed the flight recorder + anomaly monitors
+            # the same way train steps do
+            obs.record_train_step(dt, tokens=len(active), path="serving")
         return True
 
     def run(self, max_steps: int | None = None) -> list:
