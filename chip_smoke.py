@@ -203,17 +203,32 @@ def _engine(model, cfg, **kw):
     return engine
 
 
+def _tolerance(want, live, dtype):
+    """Outputs are convex mixes of V rows, rounded once to the pool's
+    dtype: bf16 pools are held to two bf16 ulps (2^-8 each) at the
+    largest output, f32 pools to f32 rounding of a 1k-term sum."""
+    import jax.numpy as jnp
+    import numpy as np
+    if dtype != jnp.bfloat16:
+        return 1e-4
+    return 2 ** -7 * max(1.0, float(np.abs(np.asarray(want, np.float32))
+                                    [live].max()))
+
+
 def _kernel_vs_reference(engine, seed):
     """The Pallas decode kernel against the XLA reference on the engine's
-    REAL pool (whatever the requests left in it), one layer, ragged
-    lengths with an idle slot. Returns (max abs error, tolerance)."""
+    REAL pool (whatever the requests left in it): the whole pool read at
+    its last layer, as the engine's programs call it, and that layer's
+    pages alone (a rank-4 pool), ragged lengths with an idle slot.
+    Returns (max abs error, tolerance)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from paddle_tpu.kernels.paged_attention import (
         paged_attention_decode, paged_attention_reference)
     pool = engine.pool
-    kp, vp = pool.k_pages[0], pool.v_pages[0]
+    last = pool.k_pages.shape[0] - 1
+    kp, vp = pool.k_pages[last], pool.v_pages[last]
     check(float(jnp.abs(kp.astype(jnp.float32)).max()) > 0,
           "the pool holds no keys after serving")
     rng = np.random.default_rng(seed)
@@ -226,23 +241,56 @@ def _kernel_vs_reference(engine, seed):
     lens = rng.integers(1, cap + 1, (B,))
     lens[0], lens[1], lens[2] = cap, 0, 1       # full, idle, one token
     lens = jnp.asarray(lens, jnp.int32)
-    got = paged_attention_decode(q, kp, vp, table, lens)
+    got = paged_attention_decode(q, pool.k_pages, pool.v_pages, table,
+                                 lens, layer=jnp.int32(last))
+    one = paged_attention_decode(q, kp, vp, table, lens)
     # the reference's f32 einsums would otherwise take the chip's default
     # single bf16 pass and be the less exact of the two
     with jax.default_matmul_precision("highest"):
         want = paged_attention_reference(q, kp, vp, table, lens)
     live = np.asarray(lens) > 0
+    check(bool((np.asarray(got, np.float32)[live]
+                == np.asarray(one, np.float32)[live]).all()),
+          "the decode kernel reads another layer of the whole pool than "
+          "of that layer's pages alone")
     err = float(np.abs(np.asarray(got, np.float32)
                        - np.asarray(want, np.float32))[live].max())
     check(np.isfinite(np.asarray(got, np.float32)).all(),
           "non-finite row out of the decode kernel")
-    # outputs are convex mixes of V rows, rounded once to the pool's
-    # dtype: bf16 pools are held to two bf16 ulps (2^-8 each) at the
-    # largest output, f32 pools to f32 rounding of a 1k-term sum
-    tol = 2 ** -7 * max(1.0, float(np.abs(np.asarray(want, np.float32))
-                                   [live].max())) \
-        if kp.dtype == jnp.bfloat16 else 1e-4
+    tol = _tolerance(want, live, kp.dtype)
     check(err <= tol, f"decode kernel vs reference: {err} > {tol}")
+    return err, tol
+
+
+def _rewrite_vs_dense_gather(engine, seed):
+    """The ``ragged_prefill`` rewrite on a program that still holds the
+    dense page gather (the engine's own chunk program calls the ragged
+    kernel itself): one chunk over one layer's pages of the engine's
+    REAL pool, rewritten, against the same program left as it was.
+    Returns (max abs error, tolerance)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.analysis import rewrite
+    from paddle_tpu.kernels.paged_attention import paged_prefill_attention
+    pool = engine.pool
+    kp, vp = pool.k_pages[0], pool.v_pages[0]
+    rng = np.random.default_rng(seed)
+    C, pps = engine.prefill_chunk, pool.max_pages_per_seq
+    q = jnp.asarray(rng.standard_normal(
+        (1, C, engine.cfg.num_heads, engine.cfg.head_dim)), kp.dtype)
+    table = jnp.asarray(rng.integers(1, pool.num_pages, (1, pps)),
+                        jnp.int32)
+    off = jnp.int32(pps * pool.page_size - C)   # the chunk ends the table
+    got = jax.jit(rewrite.autofuse(
+        paged_prefill_attention, label="chip_smoke.dense_gather"))(
+        q, kp, vp, table, off)
+    want = jax.jit(paged_prefill_attention)(q, kp, vp, table, off)
+    err = float(np.abs(np.asarray(got, np.float32)
+                       - np.asarray(want, np.float32)).max())
+    tol = _tolerance(want, slice(None), kp.dtype)
+    check(err <= tol, f"ragged_prefill rewrite vs dense gather: "
+                      f"{err} > {tol}")
     return err, tol
 
 
@@ -314,15 +362,17 @@ def serve_phase(cfg, seed, prompt_lens=PROMPT_LENS, max_new=64,
            peak_bytes_in_use=_peak_bytes())
 
     # ---- prefix cache + chunked prefill, the model cast to bf16 as a
-    # 16 GB deployment would hold it: the chunk program and the
-    # ragged_prefill rewrite compile and run, and the two serving kernels
-    # run on a bf16 pool
+    # 16 GB deployment would hold it: the chunk program (the ragged
+    # kernel on the whole pool) and the ragged_prefill rewrite (on a
+    # dense gather) compile and run, and the two serving kernels run on
+    # a bf16 pool
     del engine, gen
     rewrite.reset_records()
     engine = _engine(model.bfloat16(), cfg, prefix_cache=True,
                      prefill_chunk=chunk, **engine_kw)
     tokens2, wall, tick_ms = _serve(engine, prompts, max_new)
     err, tol = _kernel_vs_reference(engine, seed)
+    rewrite_err, rewrite_tol = _rewrite_vs_dense_gather(engine, seed)
     records = rewrite.match_records()
     statuses = collections.Counter(
         f"{r.get('rule') or r.get('kind')}:{r['status']}" for r in records)
@@ -337,6 +387,9 @@ def serve_phase(cfg, seed, prompt_lens=PROMPT_LENS, max_new=64,
            prefill_chunk=chunk, rewrite_statuses=dict(statuses),
            pool_dtype=str(engine.pool.k_pages.dtype),
            kernel_vs_reference_max_abs_err=err, tolerance=tol,
+           rewrite_vs_dense_gather_max_abs_err=rewrite_err,
+           rewrite_tolerance=rewrite_tol,
+           program_memory=engine.program_memory(),
            prompts_token_identical_to_f32_classic=same,
            engine_compile_s=round(engine.compile_s, 2),
            wall_s=round(wall, 2), decode_tick_ms_median=round(tick_ms, 2),

@@ -12,9 +12,12 @@ record carrying the predicted Δms.
 Shipped rules:
 
 - ``ragged_prefill`` — the chunk-prefill dense page gather
-  (``k_pages[page_table]`` + causal softmax attention) becomes
+  (``k_pages[page_table]`` over one layer's pages + causal softmax
+  attention: the XLA path of the chunk program) becomes
   :func:`~paddle_tpu.kernels.paged_attention.ragged_prefill_attention`:
   the page table rides scalar prefetch exactly like the decode kernel.
+  (``ServingEngine(use_kernel=True)`` calls that kernel itself, on the
+  whole pool with a layer index, and leaves the rule nothing to match.)
 - ``int8_dequant_matmul`` — weight-only-int8 decode matmuls
   (``convert(int8→float) → dot_general → mul(scale)``) become
   :func:`~paddle_tpu.kernels.int8_matmul.int8_matmul`: dequant in

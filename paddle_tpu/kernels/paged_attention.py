@@ -10,9 +10,14 @@ this kernel gathers exactly those pages.
 Layout contract:
 
 - ``q``        ``[B, num_heads, d]`` — the new token's projected queries.
-- ``k_pages``/``v_pages`` ``[num_pages, page_size, num_kv_heads, d]`` —
-  the pool. Page 0 is the pool's reserved *sink* page (padding page-table
-  entries point at it; it is never read unmasked).
+- ``k_pages``/``v_pages`` ``[num_layers, num_pages, page_size,
+  num_kv_heads, d]`` — the WHOLE pool, with ``layer`` (a traced int32
+  scalar) saying which layer's pages this call reads. A rank-4
+  ``[num_pages, page_size, num_kv_heads, d]`` array is the same call on
+  a pool of one layer read at layer 0 (a leading axis of one is a
+  bitcast): one path, chosen by the rank of the input. Page 0 is the
+  pool's reserved *sink* page (padding page-table entries point at it;
+  it is never read unmasked).
 - ``page_table`` ``[B, pages_per_seq]`` int32 — entry ``j`` is the HBM
   page holding tokens ``[j*page_size, (j+1)*page_size)`` of sequence
   ``b``; entries beyond the sequence's pages are sink references.
@@ -21,24 +26,30 @@ Layout contract:
   A zero length marks an idle batch slot: every key is masked and the
   (finite, garbage) output row is discarded by the caller.
 
-Grid: one step per ``(sequence, kv_page)`` — the page table rides
-:class:`pltpu.PrefetchScalarGridSpec` scalar prefetch so the ``k_pages``
-BlockSpec index_map can gather the right HBM page into VMEM while the
+Grid: one step per ``(sequence, kv_page)`` — the page table AND the
+layer index ride :class:`pltpu.PrefetchScalarGridSpec` scalar prefetch so
+the ``k_pages`` BlockSpec index_map ``(layer, page_table[b, j], 0, 0, 0)``
+can gather the right HBM page of the right layer into VMEM while the
 online-softmax state (m/l/acc) lives in VMEM scratch, exactly the
 flash-attention streaming scheme but with an indirection per block.
-Fully-padded pages (``j*page_size >= seq_len``) early-out.
+Fully-padded pages (``j*page_size >= seq_len``) early-out. Because the
+layer is picked by the block's index and not by a slice, the serving
+programs carry the whole (donated, aliased) pool through their layer
+loop and never cut a layer's pages out of it.
 
-A block holds ALL KV heads of one page, ``(1, page_size, nkv, d)``: the
-TPU lowering wants a block's last two dims to be multiples of (8, 128)
-or the array's own, and one head out of ``nkv`` is neither. The heads
-are walked inside the kernel body. (A flat ``[pages, page, nkv*d]`` view
-of the pool would give lane-aligned head slices, but on the chip that
-reshape is a relayout copy of the whole pool per call, not a bitcast.)
+A block holds ALL KV heads of one page of one layer, ``(layer squeezed,
+1, page_size, nkv, d)``: the TPU lowering wants a block's last two dims
+to be multiples of (8, 128) or the array's own, and one head out of
+``nkv`` is neither. The heads are walked inside the kernel body. (A flat
+``[pages, page, nkv*d]`` view of the pool would give lane-aligned head
+slices, but on the chip that reshape is a relayout copy of the whole
+pool per call, not a bitcast.)
 
 On CPU the kernels run in interpreter mode so tier-1 asserts
 paged-decode == XLA reference attention without a TPU; the same
 ``pallas_call`` compiles for the chip (x64 off around the trace;
-``tests/test_chip_compile.py`` asks the v5e compiler at 345M widths).
+``tests/test_chip_compile.py`` asks the v5e compiler at 345M and 1.3B
+widths, rank-4 and layer-indexed rank-5 pools).
 
 **Shared (prefix-cache) pages**: all reads here are page-table gathers,
 so a page mapped into many sequences' tables (refcounted sharing in
@@ -70,7 +81,35 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
+def _layer_index(k_pages, layer):
+    """``layer`` as an int32 scalar, checked against the pool's rank: a
+    rank-4 pool is a pool of one layer, read at layer 0."""
+    if (k_pages.ndim == 5) == (layer is None):
+        raise ValueError(
+            f"a rank-5 pool needs the `layer` to read and a rank-4 pool "
+            f"holds just one: got rank {k_pages.ndim}, layer={layer!r}")
+    return jnp.asarray(0 if layer is None else layer, jnp.int32)
+
+
+def _pool_and_layer(k_pages, v_pages, layer):
+    """The kernels' one view of a pool: rank 5 ``[L, P, ps, nkv, d]``
+    and the layer as a ``(1,)`` int32 for the scalar prefetch."""
+    layer = jnp.reshape(_layer_index(k_pages, layer), (1,))
+    if k_pages.ndim == 4:
+        k_pages, v_pages = k_pages[None], v_pages[None]
+    return k_pages, v_pages, layer
+
+
+def _layer_pages(k_pages, v_pages, layer):
+    """The XLA paths' view: one layer's pages ``[P, ps, nkv, d]``."""
+    layer = _layer_index(k_pages, layer)
+    if k_pages.ndim == 4:
+        return k_pages, v_pages
+    return (jax.lax.dynamic_index_in_dim(k_pages, layer, 0, keepdims=False),
+            jax.lax.dynamic_index_in_dim(v_pages, layer, 0, keepdims=False))
+
+
+def _decode_kernel(pt_ref, sl_ref, ly_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, page_size, g, scale):
     """One (sequence b, page j) step of the online softmax over every
     head at once; scratch carries the running (max, denom, weighted-V)
@@ -121,17 +160,20 @@ def _decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens,
-                           scale=None):
+                           scale=None, layer=None):
     """Single-token decode attention over a paged KV cache.
 
-    ``q`` ``[B, num_heads, d]``; pages ``[num_pages, page_size,
-    num_kv_heads, d]`` (num_kv_heads may divide num_heads — MQA/GQA:
-    query heads ``[h*g, (h+1)*g)`` read kv head ``h``); ``page_table``
-    ``[B, pages_per_seq]`` int32; ``seq_lens`` ``[B]`` int32 true
-    lengths (0 = idle slot). Returns ``[B, num_heads, d]``.
+    ``q`` ``[B, num_heads, d]``; pages ``[num_layers, num_pages,
+    page_size, num_kv_heads, d]`` with ``layer`` the (traced) layer to
+    read, or rank 4 without it — one layer (num_kv_heads may divide
+    num_heads — MQA/GQA: query heads ``[h*g, (h+1)*g)`` read kv head
+    ``h``); ``page_table`` ``[B, pages_per_seq]`` int32; ``seq_lens``
+    ``[B]`` int32 true lengths (0 = idle slot). Returns
+    ``[B, num_heads, d]``.
     """
     B, nh, d = q.shape
-    _, page_size, nkv, _ = k_pages.shape
+    k_pages, v_pages, layer = _pool_and_layer(k_pages, v_pages, layer)
+    _, _, page_size, nkv, _ = k_pages.shape
     if nh % nkv:
         raise ValueError(f"num_heads {nh} must be a multiple of "
                          f"num_kv_heads {nkv}")
@@ -143,13 +185,14 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens,
         # q rides as [B, g, nkv, d]: row r of a block holds the r-th
         # query of every kv head's group, aligned with a page's heads
         q_block = pl.BlockSpec((1, g, nkv, d),
-                               lambda b, j, pt, sl: (b, 0, 0, 0))
-        # the paged gather: the page table picks which HBM page this
-        # grid step DMAs into VMEM
-        kv_block = pl.BlockSpec((1, page_size, nkv, d),
-                                lambda b, j, pt, sl: (pt[b, j], 0, 0, 0))
+                               lambda b, j, pt, sl, ly: (b, 0, 0, 0))
+        # the paged gather: the layer and the page table pick which HBM
+        # page this grid step DMAs into VMEM (the layer axis squeezed)
+        kv_block = pl.BlockSpec(
+            (None, 1, page_size, nkv, d),
+            lambda b, j, pt, sl, ly: (ly[0], pt[b, j], 0, 0, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, page_table.shape[1]),
             in_specs=[q_block, kv_block, kv_block],
             out_specs=q_block,
@@ -167,12 +210,12 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens,
             compiler_params=_ARB2,
             interpret=interpret,
             name="paged_attention_decode",
-        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), layer,
           q.reshape(B, nkv, g, d).swapaxes(1, 2), k_pages, v_pages)
     return out.swapaxes(1, 2).reshape(B, nh, d)
 
 
-def _prefill_kernel(pt_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
+def _prefill_kernel(pt_ref, off_ref, ly_ref, q_ref, k_ref, v_ref, o_ref,
                     m_scr, l_scr, acc_scr, *, page_size, scale):
     """One (sequence b, page j) step of the ragged chunk prefill: a whole
     C-row chunk attends one paged KV block per step, head by head,
@@ -227,26 +270,30 @@ def _prefill_kernel(pt_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def ragged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
-                             scale=None, interpret=None):
+                             scale=None, interpret=None, layer=None):
     """True ragged Pallas chunk-prefill attention over a paged KV cache.
 
-    Drop-in fused form of :func:`paged_prefill_attention` (same
-    signature, same numerics): instead of the dense page gather
-    (``k_pages[page_table]`` materializes every sequence's KV twice),
-    the page table rides :class:`pltpu.PrefetchScalarGridSpec` scalar
-    prefetch — exactly the decode kernel's scheme — and each grid step
-    DMAs one page into VMEM while online-softmax state (m/l/acc per
-    chunk row) lives in scratch. The causal rule uses the **traced**
-    ``q_offset``, so one compiled program covers every chunk position.
+    Fused form of :func:`paged_prefill_attention` (same signature, same
+    numerics; pool and ``layer`` as in :func:`paged_attention_decode`):
+    instead of the dense page gather (``k_pages[page_table]``
+    materializes every sequence's KV twice), the page table and the
+    layer ride :class:`pltpu.PrefetchScalarGridSpec` scalar prefetch —
+    exactly the decode kernel's scheme — and each grid step DMAs one
+    page into VMEM while online-softmax state (m/l/acc per chunk row)
+    lives in scratch. The causal rule uses the **traced** ``q_offset``,
+    so one compiled program covers every chunk position.
 
-    This is the target template of the ``ragged_prefill`` auto-fusion
-    rewrite rule (:mod:`paddle_tpu.analysis.rewrite`); the
-    ``pallas_call`` is named ``autofuse_ragged_prefill`` so the cost
-    pass recognizes rewritten programs (PTCS005). MQA/GQA grouping is
-    not supported here (``num_heads`` must equal ``num_kv_heads``).
+    The engine's chunk program calls it on the whole pool; it is also
+    the target template of the ``ragged_prefill`` auto-fusion rewrite
+    rule (:mod:`paddle_tpu.analysis.rewrite`), which swaps it in for a
+    dense gather over one layer's pages. The ``pallas_call`` is named
+    ``autofuse_ragged_prefill`` so the cost pass recognizes rewritten
+    programs (PTCS005). MQA/GQA grouping is not supported here
+    (``num_heads`` must equal ``num_kv_heads``).
     """
     B, C, nh, d = q.shape
-    _, ps, nkv, _ = k_pages.shape
+    k_pages, v_pages, layer = _pool_and_layer(k_pages, v_pages, layer)
+    _, _, ps, nkv, _ = k_pages.shape
     if nh != nkv:
         raise ValueError(f"ragged_prefill_attention needs num_heads "
                          f"({nh}) == num_kv_heads ({nkv})")
@@ -261,17 +308,18 @@ def ragged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
         if Cp != C:
             q = jnp.pad(q, [(0, 0), (0, Cp - C), (0, 0), (0, 0)])
         q_block = pl.BlockSpec((1, Cp, nh, d),
-                               lambda b, j, pt, off: (b, 0, 0, 0))
-        kv_block = pl.BlockSpec((1, ps, nh, d),
-                                lambda b, j, pt, off: (pt[b, j], 0, 0, 0))
+                               lambda b, j, pt, off, ly: (b, 0, 0, 0))
+        kv_block = pl.BlockSpec(
+            (None, 1, ps, nh, d),
+            lambda b, j, pt, off, ly: (ly[0], pt[b, j], 0, 0, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, page_table.shape[1]),
             in_specs=[q_block, kv_block, kv_block],
             # head-major out: a whole [Cp, d] store per head (Mosaic
             # refuses the strided bf16 store into [Cp, nh, d] at d < 128)
             out_specs=pl.BlockSpec((1, nh, Cp, d),
-                                   lambda b, j, pt, off: (b, 0, 0, 0)),
+                                   lambda b, j, pt, off, ly: (b, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((nh, Cp, 1), jnp.float32),
                 pltpu.VMEM((nh, Cp, 1), jnp.float32),
@@ -287,18 +335,19 @@ def ragged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
             interpret=interpret,
             name="autofuse_ragged_prefill",
         )(page_table.astype(jnp.int32),
-          jnp.reshape(jnp.asarray(q_offset, jnp.int32), (1,)),
+          jnp.reshape(jnp.asarray(q_offset, jnp.int32), (1,)), layer,
           q, k_pages, v_pages)
     return out.swapaxes(1, 2)[:, :C]
 
 
 def paged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
-                            scale=None):
+                            scale=None, layer=None):
     """Chunk/suffix prefill attention over a paged KV cache (XLA path).
 
     ``q`` ``[B, C, num_heads, d]`` — a prompt *chunk* whose row ``i``
-    sits at absolute position ``q_offset + i``; pages/table as in
-    :func:`paged_attention_decode`. Row ``i`` attends keys at positions
+    sits at absolute position ``q_offset + i``; pages/table/``layer`` as
+    in :func:`paged_attention_decode` (this path cuts the layer's pages
+    out of a rank-5 pool itself). Row ``i`` attends keys at positions
     ``<= q_offset + i`` — the flash-attention ``q_offset`` masking rule
     (PR 8), but with a **traced** offset, so ONE compiled program covers
     every chunk position and every cached-prefix length: chunked prefill
@@ -312,6 +361,7 @@ def paged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
     barrier, never this read path.
     """
     B, C, nh, d = q.shape
+    k_pages, v_pages = _layer_pages(k_pages, v_pages, layer)
     _, ps, nkv, _ = k_pages.shape
     g = nh // nkv
     t = page_table.shape[1] * ps
@@ -335,11 +385,13 @@ def paged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
 
 
 def paged_attention_reference(q, k_pages, v_pages, page_table, seq_lens,
-                              scale=None):
-    """XLA reference: gather the paged KV dense, mask to each sequence's
-    true length, plain softmax attention. The correctness oracle for the
+                              scale=None, layer=None):
+    """XLA reference: gather the paged KV dense (one layer's pages, cut
+    out of a rank-5 pool at ``layer``), mask to each sequence's true
+    length, plain softmax attention. The correctness oracle for the
     kernel and the modelable decode path the static cost pass prices."""
     B, nh, d = q.shape
+    k_pages, v_pages = _layer_pages(k_pages, v_pages, layer)
     _, ps, nkv, _ = k_pages.shape
     g = nh // nkv
     if scale is None:

@@ -17,8 +17,22 @@ Decode math: one token per live sequence per step. Each layer projects
 q/k/v for the new token, scatters k/v into the sequence's current page
 slot, then attends over the page table with the Pallas ragged
 paged-attention kernel (:mod:`paddle_tpu.kernels.paged_attention`; XLA
-reference path on request). Page buffers are donated on TPU, so decode
-updates the pool in place.
+reference path on request). The chunk program does the same for a chunk
+of one prompt with the ragged-prefill kernel.
+
+**The pool stays where it is.** Both programs carry the whole
+``[L, P, ps, nkv, d]`` K and V pools through their layer loop (the
+``lax.scan`` carry; the scan's ``xs`` are the stacked weights and the
+layer index, and it has no stacked output). A layer writes its B (or C)
+new rows into the carried pool at ``(layer, rows)`` and hands the kernel
+the whole pool with the layer index, which rides the kernel's scalar
+prefetch beside the page table: no layer's pages are ever cut out of the
+pool or written back into a second one. The page buffers are donated on
+TPU, so XLA aliases the carried pool to the program's input and output:
+a call moves the rows it writes and the pages it reads, not the pool.
+:meth:`ServingEngine.status` reports, for every AOT-compiled program,
+what the compiler says of it (``temp_bytes``, ``alias_bytes``): in place
+means ``alias_bytes`` >= the pool's bytes and ``temp_bytes`` far under.
 
 Telemetry: every prefill/decode step feeds the metric registry, the
 flight recorder, and the anomaly monitor under ``path="serving"`` (see
@@ -47,7 +61,8 @@ from ..models.gpt import (GPTConfig, _ln, flash_attention_gate, gpt_block,
                           sample_logits, stack_gpt_weights)
 from ..kernels.paged_attention import (paged_attention_decode,
                                        paged_attention_reference,
-                                       paged_prefill_attention)
+                                       paged_prefill_attention,
+                                       ragged_prefill_attention)
 from ..profiler.utils import RecordEvent
 from .kv_pool import PagePool
 from .prefix_cache import PrefixCache
@@ -114,6 +129,16 @@ def _compute_dtype(params, compute_dtype):
     return wte["s"].dtype if _is_quant(wte) else wte.dtype
 
 
+def _write_rows(pages, layer, rows, new):
+    """Write ``new`` ``[n, nkv, d]`` into the carried pool ``[L, P, ps,
+    nkv, d]`` at token rows ``rows`` of ``layer`` (a scatter of n rows
+    on the ``[L, P*ps, nkv, d]`` view: in place on a loop-carried,
+    donated buffer)."""
+    L, np_, ps, nkv, d = pages.shape
+    return pages.reshape(L, np_ * ps, nkv, d).at[layer, rows].set(
+        new.astype(pages.dtype)).reshape(pages.shape)
+
+
 def decode_step_fn(params, k_pages, v_pages, tokens, positions, page_table,
                    seq_lens, key, *, eps, temperature, top_k, use_kernel,
                    compute_dtype=None):
@@ -124,7 +149,9 @@ def decode_step_fn(params, k_pages, v_pages, tokens, positions, page_table,
     ``tokens``/``positions`` ``[B]`` int32 (position = seq_len-1);
     ``page_table`` ``[B, pages_per_seq]``; ``seq_lens`` ``[B]`` (0 =
     idle slot → all writes land in the sink page, output is discarded).
-    Returns ``(k_pages, v_pages, next_tokens)``.
+    Returns ``(k_pages, v_pages, next_tokens)``: the pools are the layer
+    loop's carry, written at ``(layer, rows)`` and read by the kernel at
+    ``layer`` — in place where the caller donates them.
 
     ``params`` may carry weight-only-int8 leaves (``{"q", "s"}`` from
     ``quantize_stacked_gpt_weights``): the decode matmuls then run the
@@ -135,7 +162,7 @@ def decode_step_fn(params, k_pages, v_pages, tokens, positions, page_table,
     blocks, wte, wpe = params["blocks"], params["wte"], params["wpe"]
     dt = _compute_dtype(params, compute_dtype)
     B = tokens.shape[0]
-    np_, ps = k_pages.shape[1], k_pages.shape[2]
+    ps = k_pages.shape[2]
     pos = jnp.maximum(positions, 0).astype(jnp.int32)
     page_table = page_table.astype(jnp.int32)
     seq_lens = seq_lens.astype(jnp.int32)
@@ -146,28 +173,27 @@ def decode_step_fn(params, k_pages, v_pages, tokens, positions, page_table,
     attend = paged_attention_decode if use_kernel \
         else paged_attention_reference
 
-    def layer(carry, p_kp_vp):
-        (x,) = carry
-        p, kp, vp = p_kp_vp
-        nkv, d = kp.shape[2], kp.shape[3]
+    def layer(carry, p_l):
+        x, kp, vp = carry
+        p, l = p_l
         h = _ln(x, p["ln1_w"], p["ln1_b"], eps)
         qkv = _mm("bsh,hknd->bsknd", h, p["wqkv"], dt) + p["bqkv"]
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # [B,1,nh,d]
-        kp = kp.reshape(np_ * ps, nkv, d).at[rows].set(
-            k[:, 0].astype(kp.dtype)).reshape(np_, ps, nkv, d)
-        vp = vp.reshape(np_ * ps, nkv, d).at[rows].set(
-            v[:, 0].astype(vp.dtype)).reshape(np_, ps, nkv, d)
-        attn = attend(q[:, 0], kp, vp, page_table, seq_lens)
+        kp = _write_rows(kp, l, rows, k[:, 0])
+        vp = _write_rows(vp, l, rows, v[:, 0])
+        attn = attend(q[:, 0], kp, vp, page_table, seq_lens, layer=l)
         o = _mm("bnd,ndh->bh", attn.astype(x.dtype), p["wo"], dt)
         x = x + o[:, None, :] + p["bo"]
         h2 = _ln(x, p["ln2_w"], p["ln2_b"], eps)
         u = jax.nn.gelu(_mm("bsh,hf->bsf", h2, p["w1"], dt) + p["b1"],
                         approximate=True)
         x = x + _mm("bsf,fh->bsh", u, p["w2"], dt) + p["b2"]
-        return (x,), (kp, vp)
+        return (x, kp, vp), None
 
-    (x,), (k_pages, v_pages) = jax.lax.scan(
-        layer, (x,), (blocks, k_pages, v_pages))
+    # the pool rides in the carry; xs are the weights and the layer index
+    layers = jnp.arange(k_pages.shape[0], dtype=jnp.int32)
+    (x, k_pages, v_pages), _ = jax.lax.scan(
+        layer, (x, k_pages, v_pages), (blocks, layers))
     h = _ln(x, params["lnf_w"], params["lnf_b"], eps)
     logits = _mm("bsh,vh->bsv", h, wte, dt)[:, 0]
     nxt = sample_logits(logits, key, temperature, top_k).astype(jnp.int32)
@@ -216,7 +242,7 @@ def prefill_fn(params, k_pages, v_pages, ids, true_len, dest_rows, key, *,
 
 def chunk_prefill_fn(params, k_pages, v_pages, ids, q_offset, chunk_len,
                      page_table, dest_rows, key, *, eps, temperature,
-                     top_k, compute_dtype=None):
+                     top_k, use_kernel=False, compute_dtype=None):
     """Prefill one CHUNK of a prompt (batch 1, ``ids`` padded to the
     engine's chunk length ``C``): embed the chunk at absolute positions
     ``q_offset + i``, scatter its K/V into the sequence's pages
@@ -233,12 +259,16 @@ def chunk_prefill_fn(params, k_pages, v_pages, ids, q_offset, chunk_len,
     program — the chunk shape set stays closed (one signature) and
     serving never recompiles.
 
+    The pools are the layer loop's carry, as in :func:`decode_step_fn`;
+    ``use_kernel`` picks the ragged Pallas kernel on the whole pool over
+    the XLA dense gather of one layer's pages (the modelable path, and
+    the one the ``ragged_prefill`` rewrite rule matches).
+
     Returns ``(k_pages, v_pages, tok[1])``.
     """
     blocks, wte, wpe = params["blocks"], params["wte"], params["wpe"]
     dt = _compute_dtype(params, compute_dtype)
     C = ids.shape[1]
-    np_, ps = k_pages.shape[1], k_pages.shape[2]
     q_offset = jnp.asarray(q_offset, jnp.int32)
     chunk_len = jnp.asarray(chunk_len, jnp.int32)
     max_pos = (wpe["q"] if _is_quant(wpe) else wpe).shape[0]
@@ -247,29 +277,30 @@ def chunk_prefill_fn(params, k_pages, v_pages, ids, q_offset, chunk_len,
     x = (_emb(wte, ids, dt) + _emb(wpe, positions, dt)[None]).astype(dt)
     rows = dest_rows.astype(jnp.int32)
     page_table = page_table.astype(jnp.int32)
+    attend = ragged_prefill_attention if use_kernel \
+        else paged_prefill_attention
 
-    def layer(carry, p_kp_vp):
-        (x,) = carry
-        p, kp, vp = p_kp_vp
-        nkv, d = kp.shape[2], kp.shape[3]
+    def layer(carry, p_l):
+        x, kp, vp = carry
+        p, l = p_l
         h = _ln(x, p["ln1_w"], p["ln1_b"], eps)
         qkv = _mm("bsh,hknd->bsknd", h, p["wqkv"], dt) + p["bqkv"]
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # [1,C,nh,d]
-        kp = kp.reshape(np_ * ps, nkv, d).at[rows].set(
-            k[0].astype(kp.dtype)).reshape(np_, ps, nkv, d)
-        vp = vp.reshape(np_ * ps, nkv, d).at[rows].set(
-            v[0].astype(vp.dtype)).reshape(np_, ps, nkv, d)
-        attn = paged_prefill_attention(q, kp, vp, page_table, q_offset)
+        kp = _write_rows(kp, l, rows, k[0])
+        vp = _write_rows(vp, l, rows, v[0])
+        attn = attend(q, kp, vp, page_table, q_offset, layer=l)
         o = _mm("bsnd,ndh->bsh", attn.astype(x.dtype), p["wo"], dt)
         x = x + o + p["bo"]
         h2 = _ln(x, p["ln2_w"], p["ln2_b"], eps)
         u = jax.nn.gelu(_mm("bsh,hf->bsf", h2, p["w1"], dt) + p["b1"],
                         approximate=True)
         x = x + _mm("bsf,fh->bsh", u, p["w2"], dt) + p["b2"]
-        return (x,), (kp, vp)
+        return (x, kp, vp), None
 
-    (x,), (k_pages, v_pages) = jax.lax.scan(
-        layer, (x,), (blocks, k_pages, v_pages))
+    # the pool rides in the carry; xs are the weights and the layer index
+    layers = jnp.arange(k_pages.shape[0], dtype=jnp.int32)
+    (x, k_pages, v_pages), _ = jax.lax.scan(
+        layer, (x, k_pages, v_pages), (blocks, layers))
     h_last = jax.lax.dynamic_slice_in_dim(
         x, jnp.maximum(chunk_len - 1, 0), 1, axis=1)
     h_last = _ln(h_last, params["lnf_w"], params["lnf_b"], eps)
@@ -442,9 +473,10 @@ class ServingEngine:
         eps = cfg.layer_norm_epsilon
         cdt = str(np.dtype(self.compute_dtype))
         # auto-fusion: rewrite the decode/chunk programs before jit so
-        # PTCS004 glue chains (int8 dequant matmuls, the chunk program's
-        # dense page gather) compile as Pallas kernels; None defers to
-        # the PADDLE_NO_AUTOFUSE env gate
+        # PTCS004 glue chains (int8 dequant matmuls; with
+        # use_kernel=False the chunk program's dense page gather)
+        # compile as Pallas kernels; None defers to the
+        # PADDLE_NO_AUTOFUSE env gate
         from ..analysis import rewrite as _rewrite
         self.autofuse = (_rewrite.autofuse_enabled() if autofuse is None
                          else bool(autofuse))
@@ -474,7 +506,9 @@ class ServingEngine:
         self._chunk_jit = jax.jit(
             _fuse(functools.partial(chunk_prefill_fn, eps=eps,
                                     temperature=self.temperature,
-                                    top_k=self.top_k, compute_dtype=cdt),
+                                    top_k=self.top_k,
+                                    use_kernel=self.use_kernel,
+                                    compute_dtype=cdt),
                   "serving.chunk_prefill"),
             donate_argnums=(1, 2) if donate else ()) \
             if self.prefill_chunk is not None else None
@@ -516,6 +550,7 @@ class ServingEngine:
         self._chunk_exe = None
         self._copy_exe = None
         self._scatter_exe: dict = {}
+        self._program_memory: dict = {"decode": {}}
         self.compile_s = 0.0
         if aot:
             self.compile_buckets()
@@ -629,6 +664,14 @@ class ServingEngine:
             self._copy_exe = self._copy_page_jit.lower(
                 kp, kp, jax.ShapeDtypeStruct((), i32),
                 jax.ShapeDtypeStruct((), i32)).compile()
+        def sizes(exe):
+            m = exe.memory_analysis()
+            return {"temp_bytes": int(m.temp_size_in_bytes),
+                    "alias_bytes": int(m.alias_size_in_bytes)}
+        self._program_memory = {"decode": {
+            b: sizes(e) for b, e in sorted(self._decode_exe.items())}}
+        if self._chunk_exe is not None:
+            self._program_memory["chunk"] = sizes(self._chunk_exe)
         self.compile_s += time.perf_counter() - t0
         record_compile(time.perf_counter() - t0, what="serving_buckets")
 
@@ -670,9 +713,23 @@ class ServingEngine:
             return 0
         return self.prefix_cache.reclaim(int(n_pages))
 
+    def program_memory(self) -> dict:
+        """What the compiler says of every AOT-compiled program that
+        carries the pool: ``{"decode": {bucket: {...}}, "chunk": {...}}``
+        with ``temp_bytes`` and ``alias_bytes`` from
+        ``compiled.memory_analysis()``, read once when the programs
+        compile. The pool is updated in place where ``alias_bytes`` >=
+        ``pool_bytes`` (both donated pools are the program's outputs)
+        and ``temp_bytes`` is far under it; no program without AOT
+        (``aot=False``)."""
+        return dict(self._program_memory,
+                    pool_bytes=int(self.pool.k_pages.nbytes
+                                   + self.pool.v_pages.nbytes))
+
     def status(self) -> dict:
         """Engine-side JSON snapshot for the live ``/status`` endpoint:
-        weight/pool sizing, bucket sets, compile accounting, prefix
+        weight/pool sizing, bucket sets, compile accounting (with each
+        pool-carrying program's temporaries and aliased bytes), prefix
         cache + disaggregation state."""
         st = {
             "compute_dtype": str(np.dtype(self.compute_dtype)),
@@ -689,6 +746,7 @@ class ServingEngine:
                              + len(self._scatter_exe)
                              + (1 if self._chunk_exe is not None else 0)
                              + (1 if self._copy_exe is not None else 0)),
+            "program_memory": self.program_memory(),
             "pool": self.pool.stats(),
         }
         if self.prefix_cache is not None:

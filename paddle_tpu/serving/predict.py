@@ -835,9 +835,13 @@ def predicted_autofusion_row(export_path: str | None = None) -> dict:
     rng = np.random.default_rng(0)
 
     cfg = gpt_tiny_config()
+    # use_kernel=False: the XLA chunk program, whose dense page gather
+    # is what the ragged_prefill rule prices and rewrites (the kernel
+    # path calls the ragged kernel itself)
     eng = ServingEngine(GPTForPretraining(GPTModel(cfg)), cfg,
                         page_size=8, decode_buckets=(1, 2), aot=False,
-                        prefill_chunk=16, quantize="int8", autofuse=True)
+                        prefill_chunk=16, quantize="int8", autofuse=True,
+                        use_kernel=False)
     eng.prefill("a", rng.integers(0, cfg.vocab_size,
                                   (23,)).astype(np.int32))
     eng.pool.extend("a")

@@ -233,10 +233,14 @@ def test_serving_engine_autofuse_parity():
         model, cfg, page_size=8, decode_buckets=(1,), aot=False,
         prefill_chunk=16, quantize="int8", **kw)
     eng, base = mk(autofuse=True), mk(autofuse=False)
+    # the engine's own chunk program calls the ragged kernel on the
+    # whole pool; the dense gather the rule matches is the XLA path's
+    dense = mk(autofuse=True, use_kernel=False)
     assert eng.status()["autofuse"] and not base.status()["autofuse"]
     prompt = np.random.default_rng(0).integers(
         0, cfg.vocab_size, (23,)).astype(np.int32)
-    assert eng.prefill("a", prompt) == base.prefill("a", prompt)
+    assert eng.prefill("a", prompt) == base.prefill("a", prompt) \
+        == dense.prefill("a", prompt)
     toks = ([], [])
     for _ in range(4):
         eng.pool.extend("a")

@@ -12,12 +12,20 @@ module-scoped fixture (never at import, in a ``skipif`` or in a
 ``parametrize`` argument), each test compiles in its own process, and
 these tests stay in this one file. Each test flips the kernel module's
 own ``_interpret`` gate — the program has no option for it.
+
+Beside the kernels: the two serving programs that carry the KV pool
+through their layer loop, whole and at the benchmark's size, for what
+only the compiler can say of them — that the pool is aliased and the
+temporaries are not a second pool (three compiles of a few seconds).
 """
 import os
 
 import pytest
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -56,18 +64,24 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _compile(monkeypatch, module, sharding, fn, *shapes):
-    """Compile ``fn`` for the described chip with ``module``'s kernels
-    lowered by Mosaic, and return the number of kernels in the program."""
+def _compiled(monkeypatch, module, fn, *args, donate=()):
+    """``fn`` compiled for the described chip with ``module``'s kernels
+    lowered by Mosaic; ``args`` are shapes that carry its sharding."""
     monkeypatch.setattr(module, "_interpret", lambda: False)
-    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
-            for s, d in shapes]
     # conftest.py asks for exact f32 matmuls (its oracles need them); the
     # chip runs the production default, and Mosaic refuses an fp32
     # contraction of bf16 operands
     with jax.default_matmul_precision("default"):
-        return jax.jit(fn).lower(*args).compile().as_text().count(
-            "tpu_custom_call")
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+def _compile(monkeypatch, module, sharding, fn, *shapes):
+    """Compile ``fn`` for the described chip and return the number of
+    kernels in the program."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    return _compiled(monkeypatch, module, fn, *args).as_text().count(
+        "tpu_custom_call")
 
 
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
@@ -107,6 +121,95 @@ def test_ragged_prefill_c256(monkeypatch, one_chip, no_compile_cache,
                     paged_attention.ragged_prefill_attention,
                     ((1, 256, nh, d), BF16), pool, pool,
                     ((1, 16), I32), ((), I32)) == 1
+
+
+# the serving programs hand the kernels the whole pool [L, P, ps, nkv, d]
+# and a traced layer, which rides the scalar prefetch: 1.3B (16 x d128)
+# and 345M (16 x d64) widths
+@pytest.mark.parametrize("d", [128, 64])
+def test_paged_decode_layer_of_pool(monkeypatch, one_chip, no_compile_cache,
+                                    d):
+    pool = ((4, 129, 64, 16, d), BF16)
+
+    def decode(q, kp, vp, table, lens, layer):
+        return paged_attention.paged_attention_decode(
+            q, kp, vp, table, lens, layer=layer)
+    assert _compile(monkeypatch, paged_attention, one_chip, decode,
+                    ((8, 16, d), BF16), pool, pool,
+                    ((8, 16), I32), ((8,), I32), ((), I32)) == 1
+
+
+@pytest.mark.parametrize("d", [128, 64])
+def test_ragged_prefill_c256_layer_of_pool(monkeypatch, one_chip,
+                                           no_compile_cache, d):
+    pool = ((4, 129, 64, 16, d), BF16)
+
+    def prefill(q, kp, vp, table, off, layer):
+        return paged_attention.ragged_prefill_attention(
+            q, kp, vp, table, off, layer=layer)
+    assert _compile(monkeypatch, paged_attention, one_chip, prefill,
+                    ((1, 256, 16, d), BF16), pool, pool,
+                    ((1, 16), I32), ((), I32), ((), I32)) == 1
+
+
+# The whole serving programs of the benchmark's serving cell: GPT-1.3B in
+# bf16, 32 slots, 32 pages of 64 a sequence, chunks of 256. In place
+# means: the two donated pools are aliased to the outputs, and the
+# program's temporaries are not another pool (they were 5.70 GiB beside
+# a pool of 5.26 GiB while the layer loop stacked the pool).
+_GIB = 2 ** 30
+
+
+def _serving_program(one_chip, what, pool_tokens):
+    """(step function, its abstract arguments, the two pools' bytes)."""
+    from paddle_tpu.models.gpt import gpt_1p3b_config
+    from paddle_tpu.serving import engine
+    from paddle_tpu.serving.predict import _params_avals
+    cfg = gpt_1p3b_config()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        _params_avals(cfg, "bfloat16", None))
+    pool = sds((cfg.num_layers, pool_tokens // 64 + 1, 64, cfg.num_heads,
+                cfg.head_dim), BF16)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    key = sds(key.shape, key.dtype)
+    kw = dict(eps=cfg.layer_norm_epsilon, temperature=0.0, top_k=0,
+              use_kernel=True, compute_dtype="bfloat16")
+    if what == "decode":
+        fn = functools.partial(engine.decode_step_fn, **kw)
+        args = (sds((32,), I32), sds((32,), I32), sds((32, 32), I32),
+                sds((32,), I32))
+    else:
+        fn = functools.partial(engine.chunk_prefill_fn, **kw)
+        args = (sds((1, 256), I32), sds((), I32), sds((), I32),
+                sds((1, 32), I32), sds((256,), I32))
+    pool_bytes = 2 * 2 * math.prod(pool.shape)
+    return fn, (params, pool, pool) + args + (key,), pool_bytes
+
+
+@pytest.mark.parametrize("what", ["decode", "chunk"])
+def test_serving_program_updates_pool_in_place(monkeypatch, one_chip,
+                                               no_compile_cache, what):
+    fn, args, pool_bytes = _serving_program(one_chip, what, 28672)
+    exe = _compiled(monkeypatch, paged_attention, fn, *args, donate=(1, 2))
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes > 5 * _GIB
+    assert mem.temp_size_in_bytes < _GIB
+    assert exe.as_text().count("tpu_custom_call") == 1
+
+
+def test_decode_program_compiles_with_49k_token_pool(monkeypatch, one_chip,
+                                                     no_compile_cache):
+    """Refused while the program kept a second pool ("Used 20.85G of
+    15.75G hbm"): 9.0 GiB of pool beside 2.45 GiB of weights."""
+    fn, args, pool_bytes = _serving_program(one_chip, "decode", 49152)
+    exe = _compiled(monkeypatch, paged_attention, fn, *args, donate=(1, 2))
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes > 9 * _GIB
+    assert mem.temp_size_in_bytes < _GIB
 
 
 @pytest.mark.parametrize("m,k,n", [(8, 1024, 4096), (8, 4096, 1024)])
