@@ -45,6 +45,15 @@ to be multiples of (8, 128) or the array's own, and one head out of
 slices, but on the chip that reshape is a relayout copy of the whole
 pool per call, not a bitcast.)
 
+A **wide group** (queries a KV head a multiple of 16: a block engine
+hands a KV head ``block x group`` = 32 of them) takes
+:func:`_decode_kernel_grouped`: the same grid, page walk and length
+mask, but each KV head's ``[g, d]`` queries meet the page's keys in one
+MXU product, q and the output head-major; the trace calls it
+``paged_attention_decode_grouped``. Walked one query at a time on the VPU
+those 32 read 1.7% of the kernel's roofline on the chip (PR 30); a group
+of one (GPT) is untouched and keeps the name ``paged_attention_decode``.
+
 On CPU the kernels run in interpreter mode so tier-1 asserts
 paged-decode == XLA reference attention without a TPU; the same
 ``pallas_call`` compiles for the chip (x64 off around the trace;
@@ -79,6 +88,25 @@ _ARB2 = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 def _interpret() -> bool:
     return jax.default_backend() == "cpu"
+
+
+# scoped VMEM a kernel may ask for without saying so (v5e)
+_VMEM_DEFAULT = 16 << 20
+
+
+def _prefill_params(nh, Cp, d, dtype):
+    """The chunk kernel's compiler parameters: the default ones while its
+    scratch (three f32 ``[nh, Cp, .]`` of a lane tile or ``d``) and its
+    double-buffered q and out blocks fit the default scoped VMEM, as at
+    16 heads of a 256-row chunk; a stated limit with room where they do
+    not, as at 32."""
+    need = nh * Cp * (2 * 128 + max(d, 128)) * 4 \
+        + 4 * nh * Cp * d * jnp.dtype(dtype).itemsize
+    if need <= _VMEM_DEFAULT * 3 // 4:
+        return _ARB2
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=int(need * 3 // 2))
 
 
 def _layer_index(k_pages, layer):
@@ -159,6 +187,62 @@ def _decode_kernel(pt_ref, sl_ref, ly_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
+def _decode_kernel_grouped(pt_ref, sl_ref, ly_ref, q_ref, k_ref, v_ref,
+                           o_ref, m_scr, l_scr, acc_scr, *, page_size,
+                           scale):
+    """:func:`_decode_kernel` for wide groups (a block engine hands a KV
+    head ``block x group`` queries): one (sequence b, page j) step, KV
+    head by KV head on the MXU: the head's ``[g, d]`` queries against
+    the page's ``[page, d]`` keys, as the chunk kernel does with its
+    rows. ``q`` and the output are head-major, ``[nkv, g, d]`` a
+    sequence."""
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    npg = pl.num_programs(1)
+    nkv = q_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    sl = sl_ref[b]
+
+    @pl.when(j * np.int32(page_size) < sl)
+    def _():
+        live = j * np.int32(page_size) + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1) < sl
+        for h in range(nkv):
+            q = q_ref[0, h]                # [g, d]
+            k = k_ref[0, :, h, :]          # [page_size, d]
+            v = v_ref[0, :, h, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) \
+                * jnp.float32(scale)       # [g, page_size]
+            s = jnp.where(live, s, jnp.float32(_NEG_INF))
+            m_prev = m_scr[h]              # [g, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            m_scr[h] = m_new
+            l_scr[h] = corr * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = corr * acc_scr[h] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    @pl.when(j == npg - 1)
+    def _():
+        for h in range(nkv):
+            l = jnp.maximum(l_scr[h], jnp.float32(1e-30))
+            o_ref[0, h] = (acc_scr[h] / l).astype(o_ref.dtype)
+
+
+# groups at least this wide (and a multiple of it: whole bf16 sublane
+# tiles) go through the MXU, head by head; narrower ones stay on the VPU
+_GROUP_ON_MXU = 16
+
+
 def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens,
                            scale=None, layer=None):
     """Single-token decode attention over a paged KV cache.
@@ -181,10 +265,14 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     interpret = _interpret()
+    grouped = g % _GROUP_ON_MXU == 0
     with x64_off(interpret):
         # q rides as [B, g, nkv, d]: row r of a block holds the r-th
         # query of every kv head's group, aligned with a page's heads
-        q_block = pl.BlockSpec((1, g, nkv, d),
+        # (head-major [B, nkv, g, d] for a wide group: a [g, d] matrix
+        # a KV head for the MXU)
+        q_shape = (nkv, g) if grouped else (g, nkv)
+        q_block = pl.BlockSpec((1,) + q_shape + (d,),
                                lambda b, j, pt, sl, ly: (b, 0, 0, 0))
         # the paged gather: the layer and the page table pick which HBM
         # page this grid step DMAs into VMEM (the layer axis squeezed)
@@ -197,30 +285,40 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens,
             in_specs=[q_block, kv_block, kv_block],
             out_specs=q_block,
             scratch_shapes=[
-                pltpu.VMEM((g, nkv, 1), jnp.float32),
-                pltpu.VMEM((g, nkv, 1), jnp.float32),
-                pltpu.VMEM((g, nkv, d), jnp.float32),
+                pltpu.VMEM(q_shape + (1,), jnp.float32),
+                pltpu.VMEM(q_shape + (1,), jnp.float32),
+                pltpu.VMEM(q_shape + (d,), jnp.float32),
             ],
         )
+        kernel = functools.partial(
+            _decode_kernel_grouped, page_size=page_size,
+            scale=float(scale)) if grouped else functools.partial(
+            _decode_kernel, page_size=page_size, g=g, scale=float(scale))
+        q = q.reshape(B, nkv, g, d)
         out = pl.pallas_call(
-            functools.partial(_decode_kernel, page_size=page_size, g=g,
-                              scale=float(scale)),
+            kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, g, nkv, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((B,) + q_shape + (d,), q.dtype),
             compiler_params=_ARB2,
             interpret=interpret,
-            name="paged_attention_decode",
+            # its own name in the trace, so that a reader can tell which
+            # body ran
+            name="paged_attention_decode_grouped" if grouped
+            else "paged_attention_decode",
         )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), layer,
-          q.reshape(B, nkv, g, d).swapaxes(1, 2), k_pages, v_pages)
-    return out.swapaxes(1, 2).reshape(B, nh, d)
+          q if grouped else q.swapaxes(1, 2), k_pages, v_pages)
+    return (out if grouped else out.swapaxes(1, 2)).reshape(B, nh, d)
 
 
 def _prefill_kernel(pt_ref, off_ref, ly_ref, q_ref, k_ref, v_ref, o_ref,
-                    m_scr, l_scr, acc_scr, *, page_size, scale):
+                    m_scr, l_scr, acc_scr, *, page_size, scale, g, block):
     """One (sequence b, page j) step of the ragged chunk prefill: a whole
-    C-row chunk attends one paged KV block per step, head by head,
-    online-softmax state in VMEM scratch, the causal rule applied with
-    the TRACED chunk offset (row ``off + i`` sees cols ``<= off + i``)."""
+    C-row chunk attends one paged KV block per step, head by head (query
+    head ``h`` reads KV head ``h // g``), online-softmax state in VMEM
+    scratch, the causal rule applied with the TRACED chunk offset (row
+    ``off + i`` sees cols ``<= off + i``; with ``block`` > 1 a column is
+    seen if its block of that many positions is not later than the
+    row's)."""
     j = pl.program_id(1)
     npg = pl.num_programs(1)
     off = off_ref[0]
@@ -233,19 +331,25 @@ def _prefill_kernel(pt_ref, off_ref, ly_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     # ragged early-out: pages wholly past the last chunk row's position
-    # (col_start > off + C - 1) are fully masked — skip them
-    run = j * np.int32(page_size) <= off + np.int32(C - 1)
+    # (col_start > off + C - 1; its block's last position under the
+    # block rule) are fully masked — skip them
+    last = off + np.int32(C - 1)
+    if block > 1:
+        last = last // np.int32(block) * np.int32(block) \
+            + np.int32(block - 1)
+    run = j * np.int32(page_size) <= last
 
     @pl.when(run)
     def _():
         row = off + jax.lax.broadcasted_iota(jnp.int32, (C, page_size), 0)
         col = j * np.int32(page_size) + jax.lax.broadcasted_iota(
             jnp.int32, (C, page_size), 1)
-        seen = col <= row
+        seen = col <= row if block == 1 \
+            else col // np.int32(block) <= row // np.int32(block)
         for h in range(nh):
             q = q_ref[0, :, h, :]          # [C, d]
-            k = k_ref[0, :, h, :]          # [page_size, d]
-            v = v_ref[0, :, h, :]
+            k = k_ref[0, :, h // g, :]     # [page_size, d]
+            v = v_ref[0, :, h // g, :]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) \
@@ -270,7 +374,8 @@ def _prefill_kernel(pt_ref, off_ref, ly_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def ragged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
-                             scale=None, interpret=None, layer=None):
+                             scale=None, interpret=None, layer=None,
+                             block=1):
     """True ragged Pallas chunk-prefill attention over a paged KV cache.
 
     Fused form of :func:`paged_prefill_attention` (same signature, same
@@ -288,15 +393,17 @@ def ragged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
     rule (:mod:`paddle_tpu.analysis.rewrite`), which swaps it in for a
     dense gather over one layer's pages. The ``pallas_call`` is named
     ``autofuse_ragged_prefill`` so the cost pass recognizes rewritten
-    programs (PTCS005). MQA/GQA grouping is not supported here
-    (``num_heads`` must equal ``num_kv_heads``).
+    programs (PTCS005). ``num_kv_heads`` may divide ``num_heads``
+    (MQA/GQA, as in the decode kernel). ``block`` (static) widens the
+    causal rule to blocks of that many positions: row ``i`` sees column
+    ``j`` iff ``j // block <= i // block``; 1 is the plain causal rule.
     """
     B, C, nh, d = q.shape
     k_pages, v_pages, layer = _pool_and_layer(k_pages, v_pages, layer)
     _, _, ps, nkv, _ = k_pages.shape
-    if nh != nkv:
-        raise ValueError(f"ragged_prefill_attention needs num_heads "
-                         f"({nh}) == num_kv_heads ({nkv})")
+    if nh % nkv:
+        raise ValueError(f"num_heads {nh} must be a multiple of "
+                         f"num_kv_heads {nkv}")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
@@ -310,7 +417,7 @@ def ragged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
         q_block = pl.BlockSpec((1, Cp, nh, d),
                                lambda b, j, pt, off, ly: (b, 0, 0, 0))
         kv_block = pl.BlockSpec(
-            (None, 1, ps, nh, d),
+            (None, 1, ps, nkv, d),
             lambda b, j, pt, off, ly: (ly[0], pt[b, j], 0, 0, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -328,10 +435,11 @@ def ragged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
         )
         out = pl.pallas_call(
             functools.partial(_prefill_kernel, page_size=ps,
-                              scale=float(scale)),
+                              scale=float(scale), g=nh // nkv,
+                              block=int(block)),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, nh, Cp, d), q.dtype),
-            compiler_params=_ARB2,
+            compiler_params=_prefill_params(nh, Cp, d, q.dtype),
             interpret=interpret,
             name="autofuse_ragged_prefill",
         )(page_table.astype(jnp.int32),
@@ -341,7 +449,7 @@ def ragged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
 
 
 def paged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
-                            scale=None, layer=None):
+                            scale=None, layer=None, block=1):
     """Chunk/suffix prefill attention over a paged KV cache (XLA path).
 
     ``q`` ``[B, C, num_heads, d]`` — a prompt *chunk* whose row ``i``
@@ -351,7 +459,8 @@ def paged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
     ``<= q_offset + i`` — the flash-attention ``q_offset`` masking rule
     (PR 8), but with a **traced** offset, so ONE compiled program covers
     every chunk position and every cached-prefix length: chunked prefill
-    and prefix-cache suffix prefill never recompile. The chunk's own
+    and prefix-cache suffix prefill never recompile (``block``: the
+    block rule of :func:`ragged_prefill_attention`). The chunk's own
     K/V must already be scattered into the pages (same contract as
     decode: a position's K/V is written before it is attended).
 
@@ -378,7 +487,7 @@ def paged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
     row = jnp.asarray(q_offset, jnp.int32) \
         + jnp.arange(C, dtype=jnp.int32)[:, None]
     col = jnp.arange(t, dtype=jnp.int32)[None, :]
-    mask = (col <= row)[None, None, :, :]
+    mask = (col // block <= row // block)[None, None, :, :]
     logits = jnp.where(mask, logits, jnp.asarray(_NEG_INF, logits.dtype))
     probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(q.dtype)
     return jnp.einsum("bnst,btnd->bsnd", probs, v)

@@ -18,3 +18,6 @@ from .ernie import (  # noqa: F401
     ErnieMoeGenerator, stack_ernie_moe_weights,
     ernie_moe_tiny_config, ernie_moe_base_config,
 )
+from .sdar import (  # noqa: F401
+    SdarMoeConfig, sdar_moe_tiny_config, init_sdar_weights,
+)

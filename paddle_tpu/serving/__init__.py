@@ -91,6 +91,15 @@ bucket-closed AOT programs, and the **fused Pallas MoE dispatch**
 (``kernels.moe_dispatch``) inside every decode/prefill program; greedy
 parity with eager ``ErnieMoeGenerator`` asserted in tier-1.
 
+Block-diffusion serving (README "Block-diffusion serving"):
+:mod:`.sdar_engine` — ``SdarServingEngine`` serves SDAR-MoE
+(``models.sdar``: RMSNorm, RoPE, grouped heads with QK-norm, 128
+dropless SiLU experts through ``jax.lax.ragged_dot``) from the same pool
+and scheduler. A step is one denoising or commit pass over each running
+sequence's block of ``block_len`` positions and yields 0 to ``block_len``
+tokens; prefill yields none. The scheduler reads ``engine.block_len``
+and drives such an engine through its ``_block_tick``.
+
 The static gate: ``python tools/check_program.py --model serving`` lints
 the decode step AND the chunk program, and replays a randomized
 admission mix through the real scheduler
@@ -116,17 +125,22 @@ from .engine import (EngineShapeError, ServingEngine,  # noqa: F401
                      prefill_kv_fn, scatter_kv_fn)
 from .moe_engine import (MoEServingEngine,  # noqa: F401
                          moe_decode_step_fn, moe_prefill_fn)
+from .sdar_engine import (SdarServingEngine,  # noqa: F401
+                          sdar_block_step_fn, sdar_chunk_prefill_fn)
 from .prefix_cache import (PrefixCache,  # noqa: F401
                            make_shared_prefix_workload)
 from .scheduler import (ContinuousBatchingScheduler,  # noqa: F401
-                        Request, simulate_decode_signatures)
+                        MigrationUnsupported, Request,
+                        simulate_decode_signatures)
 from .router import PrefixAffinityRouter, SLOAutoscaler  # noqa: F401
 from .fleet import FleetError, FleetRouter, ReplicaHandle  # noqa: F401
 
 __all__ = [
     "PagePool", "PagePoolError", "PagePoolOOM",
     "ServingEngine", "EngineShapeError", "MoEServingEngine",
+    "SdarServingEngine",
     "PrefixCache", "ContinuousBatchingScheduler", "Request",
+    "MigrationUnsupported",
     "simulate_decode_signatures", "make_shared_prefix_workload",
     "FleetRouter", "FleetError", "ReplicaHandle",
     "PrefixAffinityRouter", "SLOAutoscaler",

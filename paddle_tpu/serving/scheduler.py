@@ -12,6 +12,14 @@ and drives the engine one decode step at a time:
 3. **decode** — the active set, in deterministic (admission-order) slot
    order, runs one step of the smallest AOT batch bucket that fits.
 
+An engine whose step is not one token declares ``block_len`` (and a
+prefill that yields no token): the decode phase is then
+:meth:`ContinuousBatchingScheduler._block_tick`, one pass over each
+running sequence's block that yields 0 to ``block_len`` tokens for it;
+the pool grows by a block, time to first token is the first block, and
+a request still ends at ``max_new_tokens`` exactly. The one-token path
+pays one comparison a tick for it.
+
 Every decode signature the scheduler can ever request is therefore
 ``(bucket, pages_per_seq)`` for a configured bucket —
 :func:`simulate_decode_signatures` replays this exact logic (device-free)
@@ -57,7 +65,12 @@ from ..observability import lockwitness
 from ..profiler.utils import RecordEvent
 
 __all__ = ["Request", "ContinuousBatchingScheduler",
-           "simulate_decode_signatures"]
+           "MigrationUnsupported", "simulate_decode_signatures"]
+
+
+class MigrationUnsupported(RuntimeError):
+    """A running request of this engine cannot be checkpointed for live
+    migration (a block engine: a block in flight)."""
 
 
 def _env_pos_float(name: str):
@@ -95,6 +108,14 @@ class Request:
     degraded_s: float = 0.0            # decode walltime spent while the
     #                                    scheduler was in brownout/shed
     tokens: list = field(default_factory=list)   # generated ids
+    # block engines only (a step is a pass over a block, not a token):
+    passes: int = 0                    # passes this request took part in
+    last_emit_time: float | None = None  # its last block came out
+    block_record: list | None = None   # (token, pass of its block at
+    #                                    which it was unmasked, its
+    #                                    confidence there) of every
+    #                                    generated position, those past
+    #                                    max_new_tokens too
     state: str = "queued"              # queued|prefilling|running|
     #                                    finished|rejected|
     #                                    deadline_exceeded
@@ -164,6 +185,8 @@ class Request:
             out["retry_after_s"] = round(self.retry_after_s, 3)
         if self.degraded_s:
             out["degraded_s"] = round(self.degraded_s, 6)
+        if self.passes:
+            out["passes"] = self.passes
         if self.trace is not None and self.trace.token_samples:
             out["per_token_s"] = self.trace.per_token_stats()
         return out
@@ -192,6 +215,12 @@ class ContinuousBatchingScheduler:
         # spends at most this many prefill tokens (chunk-granular; the
         # default of one chunk is the tightest decode-stall bound)
         self.chunked = getattr(engine, "prefill_chunk", None) is not None
+        # how a step advances a sequence: one token (1), or one pass over
+        # a block of this many positions that yields 0..block tokens and
+        # whose prefill yields none. A page holds whole blocks (the
+        # engine checks), so a completion rounded up to a block needs the
+        # pages that `_completion_pages` already reckons
+        self.block_len = int(getattr(engine, "block_len", 1))
         self.prefill_token_budget = int(prefill_token_budget) \
             if prefill_token_budget else (engine.prefill_chunk
                                           if self.chunked else None)
@@ -518,9 +547,11 @@ class ContinuousBatchingScheduler:
             held = len(self.engine.pool.table(rid))
             self._reserved_pages -= self._completion_pages(r) - held
             # everything but the final sampled token has K/V in the
-            # pool — exactly what the prefix cache may re-serve
+            # pool — exactly what the prefix cache may re-serve (a block
+            # engine emits a block once it is committed: all of them)
+            cached = r.tokens if self.block_len > 1 else r.tokens[:-1]
             self.engine.release(rid, token_ids=np.concatenate(
-                [r.prompt, np.asarray(r.tokens[:-1], np.int32)]))
+                [r.prompt, np.asarray(cached, np.int32)]))
             r.state = "finished"
             r.finish_time = time.perf_counter()
             self._finish_ts.append(r.finish_time)
@@ -631,10 +662,22 @@ class ContinuousBatchingScheduler:
             del self._prefilling[rid]
             self._begun.discard(rid)
             t_done = time.perf_counter()
-            r.tokens.append(tok)
             r.state = "running"
-            r.first_token_time = t_done
             self._running[rid] = r
+            if tok is None:
+                # a block engine's prefill yields no token: the first
+                # block is the first token (`_block_tick` stamps it)
+                r.last_emit_time = t_done
+                r.block_record = []
+                if r.trace is not None:
+                    r.trace.span("prefill", r.admit_time, t_done,
+                                 prompt_len=int(r.prompt.shape[0]),
+                                 chunks=r.prefill_chunks,
+                                 cached_prefix_len=r.cached_prefix_len)
+                obs.serving_prefill_histogram().observe(r.prefill_s)
+                continue
+            r.tokens.append(tok)
+            r.first_token_time = t_done
             with RecordEvent("sched.account", path="first_token"):
                 if r.trace is not None:
                     r.trace.span("prefill", r.admit_time, t_done,
@@ -763,6 +806,8 @@ class ContinuousBatchingScheduler:
         # ONE bucket-selection implementation: the engine's (raises
         # EngineShapeError on overflow, same as every other shape gate)
         bucket = self.engine.decode_bucket(len(active))
+        if self.block_len > 1:
+            return self._block_tick(active, bucket, t0)
         with RecordEvent("sched.decode_tick", n_active=len(active),
                          bucket=bucket):
             pool = self.engine.pool
@@ -783,19 +828,86 @@ class ContinuousBatchingScheduler:
                 per_token.observe(dt)
             if self.slo is not None:
                 self.slo.observe_tokens([r.rid for r in active], dt)
-            if self.mode != "healthy":
-                # degraded time is attributable: the doctor carves it
-                # out of the decode residual exactly like migration cost
-                self.degraded_s_total += dt
-                obs.serving_degraded_seconds_counter().inc(dt)
+            self._account_step(obs, active, dt, len(active))
+        return True
+
+    def _account_step(self, obs, active, dt, tokens):
+        """What a decode step counts whatever it yields (a token a
+        sequence, or a pass's 0 to ``block_len``): degraded time, the
+        step and its duration, the tokens that came out."""
+        if self.mode != "healthy":
+            # degraded time is attributable: the doctor carves it
+            # out of the decode residual exactly like migration cost
+            self.degraded_s_total += dt
+            obs.serving_degraded_seconds_counter().inc(dt)
+            for r in active:
+                r.degraded_s += dt
+        self.steps += 1
+        self.step_times.append(dt)
+        if tokens:
+            obs.serving_tokens_out_counter().inc(float(tokens))
+        # serving steps feed the flight recorder + anomaly monitors
+        # the same way train steps do
+        obs.record_train_step(dt, tokens=tokens, path="serving")
+
+    def _block_tick(self, active, bucket, t0) -> bool:
+        """The decode phase where a step is one pass over each running
+        sequence's block: the pool grows by a block where a sequence
+        starts one, the pass yields 0 to ``block_len`` tokens for it (a
+        block comes out whole, once committed), and a request ends at
+        ``max_new_tokens`` exactly: what its last block computed past
+        that is dropped. Time to first token is the first block; the
+        per-token samples are a block's time over its tokens."""
+        from ..observability import instrument as obs
+        eng, pool, bl = self.engine, self.engine.pool, self.block_len
+        rids = [r.rid for r in active]
+        with RecordEvent("sched.decode_tick", n_active=len(active),
+                         bucket=bucket, block=bl,
+                         masked=eng.masked_positions(rids)):
+            grown = 0
+            with RecordEvent("pool.extend") as ev:
                 for r in active:
-                    r.degraded_s += dt
-            self.steps += 1
-            self.step_times.append(dt)
-            obs.serving_tokens_out_counter().inc(float(len(active)))
-            # serving steps feed the flight recorder + anomaly monitors
-            # the same way train steps do
-            obs.record_train_step(dt, tokens=len(active), path="serving")
+                    if eng.starts_block(r.rid):
+                        held = len(pool.table(r.rid))
+                        pool.extend(r.rid, bl)
+                        self._reserved_pages -= len(pool.table(r.rid)) - held
+                        grown += bl
+                ev.set(n=grown)
+            out = eng.decode(rids, bucket)
+        now = time.perf_counter()
+        dt = now - t0
+        with RecordEvent("sched.account", path="serving"):
+            per_token = obs.serving_per_token_histogram()
+            emitted = 0
+            for r, (toks, passes, confs) in zip(active, out):
+                r.passes += 1
+                if not toks:
+                    continue
+                r.block_record.extend(zip(toks, passes, confs))
+                room = r.max_new_tokens - len(r.tokens)
+                if r.eos_id is not None and r.eos_id in toks[:room]:
+                    room = toks.index(r.eos_id) + 1
+                new = toks[:room]
+                eng.note_emitted(len(new), len(toks) - len(new))
+                r.tokens.extend(new)
+                emitted += len(new)
+                each = (now - r.last_emit_time) / len(new)
+                r.last_emit_time = now
+                for _ in new:
+                    if r.trace is not None:
+                        r.trace.add_token(each)
+                    per_token.observe(each)
+                if self.slo is not None:
+                    self.slo.observe_tokens([r.rid], each)
+                if r.first_token_time is None:
+                    r.first_token_time = now
+                    obs.serving_ttft_histogram().observe(
+                        now - r.submit_time)
+                    if self.slo is not None:
+                        self.slo.observe_admission(
+                            r.rid, ttft_s=now - r.submit_time,
+                            queue_wait_s=r.admit_time - r.submit_time)
+            self._account_step(obs, active, dt, emitted)
         return True
 
     def run(self, max_steps: int | None = None) -> list:
@@ -825,9 +937,24 @@ class ContinuousBatchingScheduler:
     def migratable_rids(self) -> list:
         """Rids currently RUNNING (token-exact checkpointable): decode
         state is fully described by (tokens, pool pages, last token).
-        Queued/prefilling requests are cheaper to withdraw + replay."""
+        Queued/prefilling requests are cheaper to withdraw + replay.
+        Raises :class:`MigrationUnsupported` for a block engine."""
         with self._lock:
+            self._refuse_block_migration()
             return [rid for rid, r in self._running.items() if not r.done]
+
+    def _refuse_block_migration(self):
+        """A block in flight is more than (tokens, pages, last token):
+        which of its positions are still masked, and rows in the pool
+        that the next pass overwrites. Until a checkpoint on a block
+        boundary exists, a caller that asks a block engine to migrate is
+        told so, and withdraws and replays instead."""
+        if self.block_len > 1:
+            raise MigrationUnsupported(
+                f"{type(self.engine).__name__} advances by blocks of "
+                f"{self.block_len}: its running requests have no "
+                "token-exact checkpoint; let them finish, or cancel and "
+                "resubmit")
 
     def checkpoint_request(self, rid) -> dict | None:
         """Source side: freeze one running request for migration — pull
@@ -837,11 +964,13 @@ class ContinuousBatchingScheduler:
         spanning the whole life; the K/V payload itself travels via
         ``engine.export_kv``. Returns None when the rid is not running
         (finished, queued, or unknown) — the caller falls back to
-        withdraw/requeue."""
+        withdraw/requeue. Raises :class:`MigrationUnsupported` where the
+        rid runs on a block engine (nothing is changed)."""
         with self._lock:
             r = self._running.get(rid)
             if r is None or r.done:
                 return None
+            self._refuse_block_migration()
             del self._running[rid]
             r.state = "migrating"
             self._migrating[rid] = r

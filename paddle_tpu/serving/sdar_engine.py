@@ -1,0 +1,596 @@
+"""Block-diffusion serving engine: SDAR-MoE from the page pool.
+
+``SdarServingEngine`` presents the surface that
+:class:`~.scheduler.ContinuousBatchingScheduler` drives (as
+``ServingEngine`` and ``MoEServingEngine`` do), for a model whose step is
+not one token: it declares ``block_len`` (the scheduler's
+``tokens_per_step``), its prefill yields no token, and one ``decode``
+call is one *pass* over the current block of every running sequence,
+which returns the 0 to ``block_len`` tokens each of them finished.
+
+Two programs, on PR 28's scheme (the whole ``[L, P, ps, nkv, d]`` pool in
+the ``lax.scan`` carry, written at ``(layer, rows)``, the paged kernels
+indexed by layer, the pool donated and aliased):
+
+- :func:`sdar_chunk_prefill_fn`: one chunk (256) of the prompt's whole
+  blocks through ``ragged_prefill_attention`` with ``block=block_len``
+  (a key is seen if its block is not later) and grouped heads. No head,
+  no token.
+- :func:`sdar_block_step_fn`, one per decode bucket: for each sequence
+  the ``block_len`` positions of its current block. Every layer writes
+  the block's K/V rows into the pool and attends the prefix and the
+  whole block. Inside a block all positions see the same keys, so the
+  block's ``block_len * group`` queries of a KV head go to
+  ``paged_attention_decode`` as ONE group against ``seq_len = block
+  end``: the GPT engine's kernel, its grid, page walk and masking; a
+  group this wide (32 queries) takes that kernel's MXU path, KV head by
+  KV head, where a group of one stays on the VPU. Then the head,
+  the argmax token and its confidence at every position, and the choice
+  of positions to unmask, all on the device; one packed int32 readback a
+  pass (tokens, which positions were unmasked, the confidences' bits,
+  per-expert assignment counts).
+
+**One program serves both kinds of pass.** A denoising pass's rows are
+overwritten by the next pass of the same block, and a commit pass is the
+pass whose input has no masked position: it writes the rows of the final
+tokens, unmasks nothing, and its logits are unused. So the program takes
+no flag; which sequences commit is the engine's host state
+(``masked``), never a comparison with the mask id.
+
+The host keeps, per sequence, the block's tokens, which positions are
+masked, the pass count, and for every generated position the pass of its
+block at which it was unmasked and the confidence (the softmax
+probability of its token) that the program read there (handed to the
+scheduler with the tokens: the record that the benchmark's reference
+replays, so that numbers are compared and not only choices).
+
+Spans as in ``ServingEngine``: ``engine.prefill_begin`` /
+``prefill_step`` / ``decode`` with ``engine.host_prep`` / ``dispatch`` /
+``readback`` inside; ``engine.decode`` also carries ``commit`` (1 where
+every live sequence of the pass commits), ``n_commit``, ``pass`` (the
+pass index where all live sequences are at the same one, else -1) and
+``unmasked``.
+
+Live migration is refused by name (no ``export_kv``/``begin_kv_import``,
+so the fleet answers ``engine_unsupported``; the scheduler's
+``checkpoint_request`` and ``migratable_rids`` raise
+``MigrationUnsupported``: a block in flight has no token-exact checkpoint
+yet); cancellation and eviction release a sequence at any pass, and only
+committed tokens are published to the prefix cache, whole pages only (a
+page of 64 is 16 blocks, so a hit is exact).
+"""
+from __future__ import annotations
+
+import functools
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..kernels.paged_attention import (paged_attention_decode,
+                                       paged_attention_reference,
+                                       paged_prefill_attention,
+                                       ragged_prefill_attention)
+from ..models import sdar
+from ..profiler.utils import RecordEvent
+from .engine import EngineShapeError, _write_rows
+from .kv_pool import PagePool
+from .prefix_cache import PrefixCache
+
+__all__ = ["SdarServingEngine", "sdar_block_step_fn",
+           "sdar_chunk_prefill_fn", "block_attention"]
+
+
+def block_attention(q, k_pages, v_pages, page_table, seq_lens, layer,
+                    use_kernel=True):
+    """Attention of whole blocks over the pool: ``q`` ``[B, bl, nh, d]``,
+    every position of a block against the same ``seq_lens`` keys (the
+    block's end). The ``bl * group`` queries of a KV head ride the decode
+    kernel as one group."""
+    B, bl, nh, d = q.shape
+    nkv = k_pages.shape[-2]
+    g = nh // nkv
+    grouped = q.reshape(B, bl, nkv, g, d).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, nkv * bl * g, d)
+    attend = paged_attention_decode if use_kernel \
+        else paged_attention_reference
+    out = attend(grouped, k_pages, v_pages, page_table, seq_lens,
+                 layer=layer)
+    return out.reshape(B, nkv, bl, g, d).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, bl, nh, d)
+
+
+def _layers(params, x, k_pages, v_pages, positions, rows, valid, attend,
+            cfg):
+    """The layer loop of both programs over ``x`` ``[N, H]``: the pool in
+    the carry, ``attend(q [N, nh, d], kp, vp, layer)`` the program's own
+    attention. Returns ``(x, k_pages, v_pages, load [L, E])``."""
+    experts = params["experts"]
+
+    def layer(carry, p_l):
+        x, kp, vp = carry
+        p, l = p_l
+        h = sdar.rms_norm(x, p["ln1"], cfg.rms_norm_eps)
+        q, k, v = sdar.attn_qkv(p, h, positions, cfg)
+        kp = _write_rows(kp, l, rows, k)
+        vp = _write_rows(vp, l, rows, v)
+        x = x + sdar.attn_out(p, attend(q, kp, vp, l).astype(x.dtype))
+        a = sdar.rms_norm(x, p["ln2"], cfg.rms_norm_eps)
+        y, load = sdar.moe_ffn(a, l, p["router"], experts, cfg, valid)
+        return (x + y, kp, vp), load
+
+    layers = jnp.arange(k_pages.shape[0], dtype=jnp.int32)
+    (x, k_pages, v_pages), load = jax.lax.scan(
+        layer, (x, k_pages, v_pages), (params["blocks"], layers))
+    return x, k_pages, v_pages, load
+
+
+def sdar_block_step_fn(params, k_pages, v_pages, state, *, cfg,
+                       threshold=None, use_kernel=True,
+                       return_logits=False):
+    """One pass over the current block of every (possibly idle) slot.
+
+    ``state`` ``[B, 2 * bl + 2 + pages_per_seq]`` int32, one row a slot:
+    the block's ``bl`` tokens (the mask id where masked), ``bl`` flags
+    (1 = masked), the block's first position, the sequence's length
+    including the block (0 = idle slot: its rows land in the sink page),
+    and its page table. Returns ``(k_pages, v_pages, out)`` with ``out``
+    one int32 vector: the block's tokens after the pass ``[B * bl]``,
+    which positions this pass unmasked ``[B * bl]``, the float32 bits of
+    every position's confidence ``[B * bl]``, and the live slots'
+    assignments per layer and expert ``[L * E]`` (and the logits ``[B,
+    bl, V]`` after it where ``return_logits``).
+    """
+    bl = cfg.block_length
+    threshold = cfg.confidence_threshold if threshold is None else threshold
+    B = state.shape[0]
+    ps = k_pages.shape[2]
+    tokens, masked = state[:, :bl], state[:, bl:2 * bl] > 0
+    start, seq_lens = state[:, 2 * bl], state[:, 2 * bl + 1]
+    page_table = state[:, 2 * bl + 2:]
+    pos = start[:, None] + jnp.arange(bl, dtype=jnp.int32)[None]
+    rows = (jnp.take_along_axis(page_table, pos // ps, axis=1) * ps
+            + pos % ps).reshape(-1)
+    valid = jnp.repeat(seq_lens > 0, bl)
+
+    def attend(q, kp, vp, l):
+        out = block_attention(q.reshape(B, bl, *q.shape[1:]), kp, vp,
+                              page_table, seq_lens, l, use_kernel)
+        return out.reshape(q.shape)
+
+    x = params["embed"][tokens.reshape(-1)]
+    x, k_pages, v_pages, load = _layers(
+        params, x, k_pages, v_pages, pos.reshape(-1), rows, valid, attend,
+        cfg)
+    logits = sdar.final_logits(params, x, cfg)
+    best, conf = sdar.confidence(logits)
+    pick = sdar.choose_unmask(conf.reshape(B, bl), masked, threshold,
+                              cfg.unmask_per_pass)
+    after = jnp.where(pick, best.reshape(B, bl), tokens)
+    out = jnp.concatenate([after.reshape(-1),
+                           pick.astype(jnp.int32).reshape(-1),
+                           jax.lax.bitcast_convert_type(
+                               conf.astype(jnp.float32), jnp.int32),
+                           load.reshape(-1)])
+    if return_logits:
+        return k_pages, v_pages, out, logits.reshape(B, bl, -1)
+    return k_pages, v_pages, out
+
+
+def sdar_chunk_prefill_fn(params, k_pages, v_pages, ids, q_offset,
+                          chunk_len, page_table, dest_rows, *, cfg,
+                          use_kernel=True, return_logits=False):
+    """Prefill one chunk of a prompt's whole blocks (batch 1, ``ids``
+    ``[1, C]`` padded; ``q_offset`` a multiple of the block): scatter its
+    K/V into the sequence's pages (``dest_rows``; padding rows to the
+    sink) and attend under the block rule. Returns ``(k_pages, v_pages,
+    load [L * E])``: prefill yields no token (and the logits ``[C, V]``
+    where ``return_logits``)."""
+    C = ids.shape[1]
+    q_offset = jnp.asarray(q_offset, jnp.int32)
+    positions = q_offset + jnp.arange(C, dtype=jnp.int32)
+    valid = jnp.arange(C, dtype=jnp.int32) < chunk_len
+    prefill = ragged_prefill_attention if use_kernel \
+        else paged_prefill_attention
+
+    def attend(q, kp, vp, l):
+        return prefill(q[None], kp, vp, page_table.astype(jnp.int32),
+                       q_offset, layer=l, block=cfg.block_length)[0]
+
+    x = params["embed"][ids[0]]
+    x, k_pages, v_pages, load = _layers(
+        params, x, k_pages, v_pages, positions,
+        dest_rows.astype(jnp.int32), valid, attend, cfg)
+    if return_logits:
+        return k_pages, v_pages, load.reshape(-1), \
+            sdar.final_logits(params, x, cfg)
+    return k_pages, v_pages, load.reshape(-1)
+
+
+class _Block:
+    """One running sequence's current block, on the host."""
+    __slots__ = ("start", "keep", "tokens", "masked", "n_pass",
+                 "unmasked_at", "conf_at", "begun")
+
+    def __init__(self, start, head, bl, mask_id):
+        self.start = start
+        self.keep = len(head)           # leading prompt positions
+        self.tokens = np.full(bl, mask_id, np.int32)
+        self.tokens[:self.keep] = head
+        self.masked = np.arange(bl) >= self.keep
+        self.n_pass = 0
+        self.unmasked_at = [-1] * bl
+        self.conf_at = [0.0] * bl       # the confidence it was unmasked at
+        self.begun = False              # its rows are in the pool's length
+
+
+class SdarServingEngine:
+    """See the module docstring. ``params`` is the stacked layout of
+    :func:`paddle_tpu.models.sdar.sdar_weight_shapes` (placed on the
+    pool's device here); greedy only."""
+
+    prefill_yields_token = False
+
+    def __init__(self, params, config: sdar.SdarMoeConfig, *, page_size=64,
+                 num_pages=None, max_seq_len=None,
+                 decode_buckets=(1, 2, 4, 8), prefill_chunk=256,
+                 prefix_cache=False, use_kernel=True, aot=True,
+                 threshold=None):
+        cfg = self.cfg = config
+        self.block_len = bl = cfg.block_length
+        if page_size % bl or prefill_chunk % page_size:
+            raise ValueError(
+                f"a page ({page_size}) must hold whole blocks ({bl}) and a "
+                f"chunk ({prefill_chunk}) whole pages")
+        max_seq_len = int(max_seq_len or cfg.max_position_embeddings)
+        if max_seq_len % bl or max_seq_len > cfg.max_position_embeddings:
+            raise ValueError(f"max_seq_len {max_seq_len}: whole blocks, "
+                             f"within the model's positions")
+        self.max_seq_len = max_seq_len
+        self.decode_buckets = tuple(sorted({int(b) for b in decode_buckets}))
+        self.prefill_chunk = int(prefill_chunk)
+        self.use_kernel = bool(use_kernel)
+        self.threshold = cfg.confidence_threshold if threshold is None \
+            else float(threshold)
+        self.compute_dtype = params["embed"].dtype
+        if num_pages is None:
+            num_pages = self.decode_buckets[-1] * (
+                -(-max_seq_len // page_size)) + 1
+        self.pool = PagePool(num_pages, page_size,
+                             num_layers=cfg.num_hidden_layers,
+                             num_kv_heads=cfg.num_key_value_heads,
+                             head_dim=cfg.head_dim,
+                             dtype=self.compute_dtype,
+                             max_seq_len=max_seq_len)
+        self.params = jax.device_put(
+            params, next(iter(self.pool.k_pages.devices())))
+        self.prefix_cache = PrefixCache(self.pool) if prefix_cache else None
+        self._blocks: dict = {}         # seq_id -> _Block
+        self._chunk_state: dict = {}    # seq_id -> in-flight prefill
+        self._pending_load: list = []   # chunk programs' counts, unread
+        self._in_flight = 0
+        self.counters = {"passes_denoise": 0, "passes_commit": 0,
+                         "passes_mixed": 0, "blocks_committed": 0,
+                         "tokens_emitted": 0, "tokens_dropped": 0,
+                         "positions_computed": 0, "prefill_chunks": 0}
+        self.expert_load = np.zeros(
+            (cfg.num_hidden_layers, cfg.num_experts), np.int64)
+        self.last_pass_load = None      # [L, E] of the last decode pass
+        self.last_chunk_loads = []      # of the chunks last read back
+        self._decode_exe: dict = {}
+        self._chunk_exe = None
+        self._program_memory: dict = {"decode": {}}
+        self.compile_s = 0.0
+        self._build_programs()
+        if aot:
+            self.compile_buckets()
+
+    # ------------------------------------------------------------- build
+    def _build_programs(self):
+        """(Re)make the two jitted programs from the module's step
+        functions as they stand."""
+        donate = (1, 2) if jax.default_backend() != "cpu" else ()
+        self._decode_jit = jax.jit(
+            functools.partial(sdar_block_step_fn, cfg=self.cfg,
+                              threshold=self.threshold,
+                              use_kernel=self.use_kernel),
+            donate_argnums=donate)
+        self._chunk_jit = jax.jit(
+            functools.partial(sdar_chunk_prefill_fn, cfg=self.cfg,
+                              use_kernel=self.use_kernel),
+            donate_argnums=donate)
+        self._decode_exe, self._chunk_exe = {}, None
+
+    @property
+    def _state_width(self):
+        return 2 * self.block_len + 2 + self.pool.max_pages_per_seq
+
+    def compile_buckets(self):
+        """AOT-compile the block program of every decode bucket and the
+        chunk program, so that serving never compiles."""
+        from ..observability.instrument import record_compile
+        t0 = time.perf_counter()
+        p = self.pool
+        S = jax.ShapeDtypeStruct
+        kp = S(p.k_pages.shape, p.k_pages.dtype)
+        avals = jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype),
+                                       self.params)
+        i32 = jnp.int32
+        for b in self.decode_buckets:
+            if b not in self._decode_exe:
+                self._decode_exe[b] = self._decode_jit.lower(
+                    avals, kp, kp, S((b, self._state_width), i32)).compile()
+        if self._chunk_exe is None:
+            C = self.prefill_chunk
+            self._chunk_exe = self._chunk_jit.lower(
+                avals, kp, kp, S((1, C), i32), S((), i32), S((), i32),
+                S((1, p.max_pages_per_seq), i32), S((C,), i32)).compile()
+
+        def sizes(exe):
+            m = exe.memory_analysis()
+            return {"temp_bytes": int(m.temp_size_in_bytes),
+                    "alias_bytes": int(m.alias_size_in_bytes)}
+        self._program_memory = {
+            "decode": {b: sizes(e) for b, e in
+                       sorted(self._decode_exe.items())},
+            "chunk": sizes(self._chunk_exe)}
+        self.compile_s += time.perf_counter() - t0
+        record_compile(time.perf_counter() - t0, what="serving_buckets")
+
+    def weight_bytes(self) -> int:
+        return int(sum(leaf.nbytes for leaf in
+                       jax.tree_util.tree_leaves(self.params)))
+
+    def reclaim_cache_pages(self, n_pages: int) -> int:
+        if self.prefix_cache is None:
+            return 0
+        return self.prefix_cache.reclaim(int(n_pages))
+
+    def status(self) -> dict:
+        st = {
+            "compute_dtype": str(np.dtype(self.compute_dtype)),
+            "weights_mb": round(self.weight_bytes() / 2 ** 20, 2),
+            "decode_buckets": list(self.decode_buckets),
+            "prefill_chunk": self.prefill_chunk,
+            "block_len": self.block_len,
+            "max_seq_len": self.max_seq_len,
+            "compile_s": round(self.compile_s, 3),
+            "aot_programs": len(self._decode_exe)
+            + (self._chunk_exe is not None),
+            "program_memory": dict(
+                self._program_memory,
+                pool_bytes=int(self.pool.k_pages.nbytes
+                               + self.pool.v_pages.nbytes)),
+            "pool": self.pool.stats(),
+            "passes": dict(self.counters),
+            "expert_load": self.expert_load.tolist(),
+        }
+        if self.prefix_cache is not None:
+            st["prefix_cache"] = self.prefix_cache.stats()
+        return st
+
+    def decode_bucket(self, n_active: int) -> int:
+        for b in self.decode_buckets:
+            if n_active <= b:
+                return b
+        raise EngineShapeError(
+            f"{n_active} active sequences exceed the largest decode "
+            f"bucket {self.decode_buckets[-1]}")
+
+    # ----------------------------------------------------------- prefill
+    def prefill_begin(self, seq_id, prompt_ids) -> int:
+        """Pages for the prompt's whole blocks (cached whole pages mapped
+        in), the first block laid out from the prompt's remaining
+        tokens. Returns the cached prefix length."""
+        bl = self.block_len
+        prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
+        n = int(prompt.shape[0])
+        n_full = n // bl * bl
+        if n_full + bl > self.max_seq_len:
+            raise EngineShapeError(
+                f"prompt of {n} tokens leaves no room for a block within "
+                f"max_seq_len {self.max_seq_len}")
+        with RecordEvent("engine.prefill_begin", rid=seq_id,
+                         prompt_len=n) as ev:
+            cached_len = self._alloc_prompt(seq_id, prompt[:n_full])
+            ev.set(cached_len=cached_len)
+        block = _Block(n_full, prompt[n_full:], bl, self.cfg.mask_token_id)
+        block.begun = n_full == 0       # its rows were allocated above
+        self._blocks[seq_id] = block
+        self._chunk_state[seq_id] = {"prompt": prompt[:n_full],
+                                     "pos": cached_len, "n": n_full}
+        return cached_len
+
+    def _alloc_prompt(self, seq_id, whole) -> int:
+        n = int(whole.shape[0])
+        if not n:                       # shorter than a block: no prefill
+            self.pool.note_prefix_lookup(0)
+            self.pool.alloc(seq_id, self.block_len)
+            return 0
+        if self.prefix_cache is None:
+            self.pool.note_prefix_lookup(0)
+            with RecordEvent("pool.alloc"):
+                self.pool.alloc(seq_id, n)
+            return 0
+        cache = self.prefix_cache
+        with RecordEvent("prefix.match"):
+            # whole pages only: inside a block every position's K/V
+            # depends on the block's other tokens, so a hit that ends
+            # inside a page (the GPT engine's copy-on-write boundary)
+            # would not be exact
+            nodes, _boundary, _ = cache.match(whole)
+            pages = cache.map_into(seq_id, nodes, None)
+        cached_len = len(nodes) * self.pool.page_size
+        with RecordEvent("pool.alloc", cow=False):
+            try:
+                self.pool.alloc_prefixed(seq_id, n, pages, cached_len)
+            except Exception:
+                cache.release(seq_id)
+                raise
+        return cached_len
+
+    def prefill_step(self, seq_id):
+        """Run one chunk of an in-flight prefill: ``(tokens processed,
+        done, None)``. The last chunk reads back (the chunks' expert
+        counts), so that a finished prefill is a finished program."""
+        st = self._chunk_state[seq_id]
+        start, n = st["pos"], st["n"]
+        if start >= n:                  # nothing to prefill
+            del self._chunk_state[seq_id]
+            return 0, True, None
+        clen = min(self.prefill_chunk, n - start)
+        final = start + clen >= n
+        with RecordEvent("engine.prefill_step", rid=seq_id, start=start,
+                         clen=clen, final=final, in_flight=self._in_flight):
+            C = self.prefill_chunk
+            with RecordEvent("engine.host_prep"):
+                ids = np.zeros((1, C), np.int32)
+                ids[0, :clen] = st["prompt"][start:start + clen]
+                rows = self.pool.chunk_rows(seq_id, start, C)
+                table = self.pool.table_array([seq_id])
+                fn = self._chunk_exe if self._chunk_exe is not None \
+                    else self._chunk_jit
+                args = (jnp.asarray(ids), jnp.asarray(np.int32(start)),
+                        jnp.asarray(np.int32(clen)), jnp.asarray(table),
+                        jnp.asarray(rows))
+            with RecordEvent("engine.dispatch"):
+                kp, vp, load = fn(self.params, self.pool.k_pages,
+                                  self.pool.v_pages, *args)
+                self.pool.bind(kp, vp)
+            self._in_flight += 1
+            self._pending_load.append(load)
+            self.counters["prefill_chunks"] += 1
+            st["pos"] = start + clen
+            if not final:
+                return clen, False, None
+            with RecordEvent("engine.readback", in_flight=self._in_flight):
+                self.last_chunk_loads = [
+                    np.asarray(load).reshape(self.expert_load.shape)
+                    for load in self._pending_load]
+            for load in self.last_chunk_loads:
+                self.expert_load += load
+            self._pending_load.clear()
+            self._in_flight = 0
+            del self._chunk_state[seq_id]
+            if self.prefix_cache is not None:
+                self.prefix_cache.insert(st["prompt"],
+                                         self.pool.table(seq_id))
+        return clen, True, None
+
+    # ------------------------------------------------------------ decode
+    def starts_block(self, seq_id) -> bool:
+        """Whether the sequence's next pass is the first of a block whose
+        rows the pool does not hold yet (the scheduler then extends it by
+        ``block_len``)."""
+        return not self._blocks[seq_id].begun
+
+    def masked_positions(self, seq_ids) -> int:
+        return int(sum(self._blocks[s].masked.sum() for s in seq_ids))
+
+    def _pass_tokens(self, block):
+        """The tokens a pass is fed for a block."""
+        return block.tokens
+
+    def decode(self, seq_ids, bucket=None):
+        """One pass over the current block of ``seq_ids`` (each holding
+        its block's rows via ``pool.extend``), padded to ``bucket``.
+        Returns, per sequence, ``(tokens, passes, confidences)``: empty
+        unless this was the block's commit pass, else the block's
+        generated tokens (the prompt's own left out) and for each the
+        pass at which it was unmasked and its confidence there."""
+        n, bl = len(seq_ids), self.block_len
+        bucket = self.decode_bucket(n) if bucket is None else bucket
+        if n > bucket:
+            raise EngineShapeError(f"{n} sequences > bucket {bucket}")
+        blocks = [self._blocks[s] for s in seq_ids]
+        commits = [not b.masked.any() for b in blocks]
+        at = {b.n_pass for b in blocks}
+        with RecordEvent("engine.decode", n=n, bucket=bucket,
+                         in_flight=self._in_flight,
+                         commit=int(all(commits)), n_commit=sum(commits),
+                         **{"pass": at.pop() if len(at) == 1 else -1}) as ev:
+            with RecordEvent("engine.host_prep"):
+                state = np.zeros((bucket, self._state_width), np.int32)
+                held = self.pool.lens_array(seq_ids)
+                for i, (sid, b) in enumerate(zip(seq_ids, blocks)):
+                    if held[i] != b.start + bl:
+                        raise EngineShapeError(
+                            f"sequence {sid!r}: the pool holds {held[i]} "
+                            f"rows, its block ends at {b.start + bl}")
+                    b.begun = True
+                    state[i, :bl] = self._pass_tokens(b)
+                    state[i, bl:2 * bl] = b.masked
+                    state[i, 2 * bl] = b.start
+                    state[i, 2 * bl + 1] = b.start + bl
+                state[:, 2 * bl + 2:] = self.pool.table_array(
+                    list(seq_ids) + [None] * (bucket - n))
+                fn = self._decode_exe.get(bucket, self._decode_jit)
+                arg = jnp.asarray(state)
+            with RecordEvent("engine.dispatch"):
+                kp, vp, out = fn(self.params, self.pool.k_pages,
+                                 self.pool.v_pages, arg)
+                self.pool.bind(kp, vp)
+            self._in_flight += 1
+            with RecordEvent("engine.readback",
+                             in_flight=self._in_flight):
+                out = np.asarray(out)
+            self._in_flight = 0
+            after = out[:bucket * bl].reshape(bucket, bl)
+            picked = out[bucket * bl:2 * bucket * bl].reshape(bucket, bl) > 0
+            conf = out[2 * bucket * bl:3 * bucket * bl].view(
+                np.float32).reshape(bucket, bl)
+            load = out[3 * bucket * bl:].reshape(self.expert_load.shape)
+            self.expert_load += load
+            self.last_pass_load = load
+            ev.set(unmasked=int(picked[:n].sum()))
+            results = [
+                self._advance(b, commit, after[i], picked[i], conf[i])
+                for i, (b, commit) in enumerate(zip(blocks, commits))]
+            c = self.counters
+            kind = "passes_commit" if all(commits) else \
+                "passes_mixed" if any(commits) else "passes_denoise"
+            c[kind] += 1
+            c["positions_computed"] += n * bl
+            for sid, commit in zip(seq_ids, commits):
+                if commit:
+                    c["blocks_committed"] += 1
+                    self._blocks[sid] = _Block(
+                        self._blocks[sid].start + bl, (), bl,
+                        self.cfg.mask_token_id)
+        return results
+
+    @staticmethod
+    def _advance(block, commit, after, picked, conf):
+        """Apply one pass's result to a block; what it yields."""
+        if commit:
+            keep = block.keep
+            return ([int(t) for t in block.tokens[keep:]],
+                    block.unmasked_at[keep:], block.conf_at[keep:])
+        for i in np.flatnonzero(picked & block.masked):
+            block.tokens[i] = after[i]
+            block.masked[i] = False
+            block.unmasked_at[i] = block.n_pass
+            block.conf_at[i] = float(conf[i])
+        block.n_pass += 1
+        return [], [], []
+
+    def note_emitted(self, emitted: int, dropped: int):
+        """The scheduler's count of what a commit's tokens became."""
+        self.counters["tokens_emitted"] += emitted
+        self.counters["tokens_dropped"] += dropped
+
+    def release(self, seq_id, token_ids=None):
+        """Free a sequence at any pass. ``token_ids`` (prompt and
+        generated tokens whose blocks were committed) publishes its whole
+        pages to the prefix cache first."""
+        self._blocks.pop(seq_id, None)
+        self._chunk_state.pop(seq_id, None)
+        if self.prefix_cache is not None:
+            if token_ids is not None and len(token_ids):
+                ids = np.asarray(token_ids, np.int32).reshape(-1)
+                valid = min(int(ids.shape[0]), self.pool.seq_len(seq_id))
+                self.prefix_cache.insert(ids[:valid],
+                                         self.pool.table(seq_id))
+            self.prefix_cache.release(seq_id)
+        self.pool.free(seq_id)

@@ -212,6 +212,48 @@ def test_decode_program_compiles_with_49k_token_pool(monkeypatch, one_chip,
     assert mem.temp_size_in_bytes < _GIB
 
 
+# The block-diffusion programs of the benchmark's SDAR cell (7 layers at
+# the published widths: 32 / 4 heads of 128, 128 experts of 768): the
+# block pass of 64 slots hands the decode kernel 128 queries a sequence
+# (32 a KV head: its MXU path), the chunk kernel takes grouped heads and
+# the block rule; both carry the pool of 131,072 tokens in place, and
+# the expert stacks are read where they lie (no 400 MB slice a layer).
+def _sdar_program(one_chip, what):
+    from paddle_tpu.models import sdar
+    from paddle_tpu.serving import sdar_engine
+    cfg = sdar.SdarMoeConfig(num_hidden_layers=7)
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        sds, sdar.sdar_weight_shapes(cfg),
+        is_leaf=lambda x: isinstance(x, tuple))
+    pool = sds((7, 2049, 64, 4, 128))
+    if what == "block":
+        fn = functools.partial(sdar_engine.sdar_block_step_fn, cfg=cfg)
+        args = (sds((64, 2 * 4 + 2 + 32), I32),)
+    else:
+        fn = functools.partial(sdar_engine.sdar_chunk_prefill_fn, cfg=cfg)
+        args = (sds((1, 256), I32), sds((), I32), sds((), I32),
+                sds((1, 32), I32), sds((256,), I32))
+    return fn, (params, pool, pool) + args, 2 * 2 * math.prod(pool.shape)
+
+
+@pytest.mark.parametrize("what", ["block", "chunk"])
+def test_sdar_program_in_place_and_no_expert_slice(monkeypatch, one_chip,
+                                                   no_compile_cache, what):
+    fn, args, pool_bytes = _sdar_program(one_chip, what)
+    exe = _compiled(monkeypatch, paged_attention, fn, *args, donate=(1, 2))
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes > 1.7 * _GIB
+    # the logits of 256 positions are 0.15 GiB; a layer's experts 1.1
+    assert mem.temp_size_in_bytes < 0.25 * _GIB
+    text = exe.as_text()
+    # one attention kernel, and XLA's grouped product with its metadata
+    assert text.count("tpu_custom_call") == 4
+    assert "ragged-dot" in text
+
+
 @pytest.mark.parametrize("m,k,n", [(8, 1024, 4096), (8, 4096, 1024)])
 def test_int8_matmul(monkeypatch, one_chip, no_compile_cache, m, k, n):
     assert _compile(monkeypatch, int8_matmul, one_chip,
