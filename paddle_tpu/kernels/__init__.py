@@ -18,6 +18,9 @@ Pallas TPU kernels instead of hand-written CUDA.
   for the weighted combine (scalar-prefetch row gather); gather-based
   reference + recompute VJPs, so fused training is trajectory-
   equivalent to the unfused path.
+- :mod:`.grouped_matmul` — grouped matmul over rows sorted by group
+  against a flat stack of expert weights read at a layer's index (the
+  dropless expert product of ``models.sdar``; row tile from the shapes).
 - :mod:`.int8_matmul` — weight-only-int8 dequant-matmul.
 - :mod:`._mosaic` — what the kernel files share (x64 off around a
   ``pallas_call`` traced for the chip).

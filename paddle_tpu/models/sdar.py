@@ -43,12 +43,16 @@ position is ``b(p) = p // block_length``.
   block's tokens are the request's next tokens.
 
 **Expert weights are stacked flat** over layers and experts, ``[L * E,
-...]``: the expert product is :func:`jax.lax.ragged_dot` over the tokens
-sorted by expert, and a layer reads its own experts by giving every other
-group a size of 0, so no layer's 400 MB of experts is ever cut out of the
-stack (a ``lax.scan`` over ``[L, E, ...]`` copies each slice before the
-kernel may read it). The gate and up projections are one array
-``[L * E, hidden, 2 * width]``.
+...]``: the expert product is a grouped matmul over the tokens sorted by
+expert (:mod:`paddle_tpu.kernels.grouped_matmul`: a Pallas kernel whose
+row tile follows the rows an expert gets), and a layer reads its own
+experts by handing the kernel its index, which the weight block's index
+map adds to the expert's, so no layer's 1.2 GB of experts is ever cut
+out of the stack (a ``lax.scan`` over ``[L, E, ...]`` copies each slice
+before a kernel may read it). The gate and up projections are one array
+``[L * E, hidden, 2 * width]``. ``jax.lax.ragged_dot`` with every other
+layer's groups empty is the same product and stays as the reference path
+(``use_kernel=False``).
 """
 from __future__ import annotations
 
@@ -56,6 +60,9 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+
+from ..kernels.grouped_matmul import (group_rows, grouped_matmul,
+                                      grouped_matmul_reference)
 
 __all__ = ["SdarMoeConfig", "sdar_moe_tiny_config", "sdar_weight_shapes",
            "init_sdar_weights", "rms_norm", "rope", "route", "moe_ffn",
@@ -195,26 +202,32 @@ def route(a, w_router, cfg):
     return w, idx.astype(jnp.int32)
 
 
-def moe_ffn(a, layer, w_router, experts, cfg, valid=None):
+def moe_ffn(a, layer, w_router, experts, cfg, valid=None, use_kernel=True):
     """The expert layer of ``a`` ``[N, H]``: route, sort the ``N * k``
     assignments by expert, two grouped products over the sorted rows
-    (``ragged_dot`` against the flat ``[L * E, ...]`` stacks, the groups
-    of every other layer empty), weigh and add each token's k results.
-    Returns ``(out [N, H], load [E])``: ``load`` counts the assignments
-    of the rows that ``valid`` marks (all of them where it is None)."""
+    (gate and up in one, then down) against the flat ``[L * E, ...]``
+    stacks read at ``layer``, weigh and add each token's k results. The
+    products are the grouped-matmul kernel, its group metadata made once
+    for both; ``use_kernel=False`` takes ``ragged_dot`` (the reference
+    path). Returns ``(out [N, H], load [E])``: ``load`` counts the
+    assignments of the rows that ``valid`` marks (all of them where it
+    is None)."""
     N, k, E = a.shape[0], cfg.num_experts_per_tok, cfg.num_experts
     w, idx = route(a, w_router, cfg)
     flat = idx.reshape(-1)
     order = jnp.argsort(flat, stable=True)
     counts = jnp.zeros((E,), jnp.int32).at[flat].add(1)
-    groups = jax.lax.dynamic_update_slice(
-        jnp.zeros((experts["gate_up"].shape[0],), jnp.int32), counts,
-        (jnp.asarray(layer, jnp.int32) * E,))
-    rows = a[order // k]
-    gate, up = jnp.split(
-        jax.lax.ragged_dot(rows, experts["gate_up"], groups), 2, axis=-1)
-    y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(a.dtype),
-                           experts["down"], groups)
+    if use_kernel:
+        rows = group_rows(counts, N * k)
+
+        def product(x, stack):
+            return grouped_matmul(x, stack, rows, layer)
+    else:
+        def product(x, stack):
+            return grouped_matmul_reference(x, stack, counts, layer)
+    gate, up = jnp.split(product(a[order // k], experts["gate_up"]), 2,
+                         axis=-1)
+    y = product((jax.nn.silu(gate) * up).astype(a.dtype), experts["down"])
     y = y.astype(jnp.float32) * w.reshape(-1)[order][:, None]
     out = y[jnp.argsort(order)].reshape(N, k, -1).sum(1).astype(a.dtype)
     if valid is None:

@@ -94,10 +94,11 @@ parity with eager ``ErnieMoeGenerator`` asserted in tier-1.
 Block-diffusion serving (README "Block-diffusion serving"):
 :mod:`.sdar_engine` — ``SdarServingEngine`` serves SDAR-MoE
 (``models.sdar``: RMSNorm, RoPE, grouped heads with QK-norm, 128
-dropless SiLU experts through ``jax.lax.ragged_dot``) from the same pool
-and scheduler. A step is one denoising or commit pass over each running
-sequence's block of ``block_len`` positions and yields 0 to ``block_len``
-tokens; prefill yields none. The scheduler reads ``engine.block_len``
+dropless SiLU experts through the grouped-matmul kernel,
+``kernels.grouped_matmul``) from the same pool and scheduler. A step is
+one denoising or commit pass over each running sequence's block of
+``block_len`` positions and yields 0 to ``block_len`` tokens; prefill
+yields none. The scheduler reads ``engine.block_len``
 and drives such an engine through its ``_block_tick``.
 
 The static gate: ``python tools/check_program.py --model serving`` lints

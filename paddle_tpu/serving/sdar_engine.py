@@ -30,6 +30,14 @@ indexed by layer, the pool donated and aliased):
   pass (tokens, which positions were unmasked, the confidences' bits,
   per-expert assignment counts).
 
+In both programs every layer's two expert products (gate and up, then
+down, over the positions' ``num_experts_per_tok`` assignments sorted by
+expert) are :func:`~paddle_tpu.kernels.grouped_matmul.grouped_matmul`
+against the flat expert stacks, read where they lie at the layer's index;
+its row tile follows the program's rows (``status()["expert_product"]``).
+``use_kernel=False`` takes the reference paths of both kernels
+(``paged_attention_reference``, ``ragged_dot``).
+
 **One program serves both kinds of pass.** A denoising pass's rows are
 overwritten by the next pass of the same block, and a commit pass is the
 pass whose input has no masked position: it writes the rows of the final
@@ -68,6 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.grouped_matmul import row_tile, tile_visits
 from ..kernels.paged_attention import (paged_attention_decode,
                                        paged_attention_reference,
                                        paged_prefill_attention,
@@ -102,10 +111,11 @@ def block_attention(q, k_pages, v_pages, page_table, seq_lens, layer,
 
 
 def _layers(params, x, k_pages, v_pages, positions, rows, valid, attend,
-            cfg):
+            cfg, use_kernel):
     """The layer loop of both programs over ``x`` ``[N, H]``: the pool in
     the carry, ``attend(q [N, nh, d], kp, vp, layer)`` the program's own
-    attention. Returns ``(x, k_pages, v_pages, load [L, E])``."""
+    attention, the expert products the grouped-matmul kernel where
+    ``use_kernel``. Returns ``(x, k_pages, v_pages, load [L, E])``."""
     experts = params["experts"]
 
     def layer(carry, p_l):
@@ -117,7 +127,8 @@ def _layers(params, x, k_pages, v_pages, positions, rows, valid, attend,
         vp = _write_rows(vp, l, rows, v)
         x = x + sdar.attn_out(p, attend(q, kp, vp, l).astype(x.dtype))
         a = sdar.rms_norm(x, p["ln2"], cfg.rms_norm_eps)
-        y, load = sdar.moe_ffn(a, l, p["router"], experts, cfg, valid)
+        y, load = sdar.moe_ffn(a, l, p["router"], experts, cfg, valid,
+                               use_kernel)
         return (x + y, kp, vp), load
 
     layers = jnp.arange(k_pages.shape[0], dtype=jnp.int32)
@@ -162,7 +173,7 @@ def sdar_block_step_fn(params, k_pages, v_pages, state, *, cfg,
     x = params["embed"][tokens.reshape(-1)]
     x, k_pages, v_pages, load = _layers(
         params, x, k_pages, v_pages, pos.reshape(-1), rows, valid, attend,
-        cfg)
+        cfg, use_kernel)
     logits = sdar.final_logits(params, x, cfg)
     best, conf = sdar.confidence(logits)
     pick = sdar.choose_unmask(conf.reshape(B, bl), masked, threshold,
@@ -201,7 +212,7 @@ def sdar_chunk_prefill_fn(params, k_pages, v_pages, ids, q_offset,
     x = params["embed"][ids[0]]
     x, k_pages, v_pages, load = _layers(
         params, x, k_pages, v_pages, positions,
-        dest_rows.astype(jnp.int32), valid, attend, cfg)
+        dest_rows.astype(jnp.int32), valid, attend, cfg, use_kernel)
     if return_logits:
         return k_pages, v_pages, load.reshape(-1), \
             sdar.final_logits(params, x, cfg)
@@ -368,7 +379,28 @@ class SdarServingEngine:
         }
         if self.prefix_cache is not None:
             st["prefix_cache"] = self.prefix_cache.stats()
+        if self.use_kernel:
+            st["expert_product"] = self._expert_product()
         return st
+
+    def _expert_product(self) -> dict:
+        """The grouped product's row tile in each program (its rows are
+        the program's positions times ``num_experts_per_tok``) and how
+        full the last pass's row-tile visits were: the live sequences'
+        assignments over the rows their visits hold, over all layers."""
+        E, k = self.cfg.num_experts, self.cfg.num_experts_per_tok
+        out = {"row_tile": {
+            "decode": {b: row_tile(b * self.block_len * k, E)
+                       for b in self.decode_buckets},
+            "chunk": row_tile(self.prefill_chunk * k, E)},
+            "tile_fill": None}
+        load = self.last_pass_load
+        if load is not None and load.sum():
+            n = int(load[0].sum()) // (self.block_len * k)
+            tm = out["row_tile"]["decode"][self.decode_bucket(n)]
+            out["tile_fill"] = float(load.sum()) / (
+                tile_visits(load, tm) * tm)
+        return out
 
     def decode_bucket(self, n_active: int) -> int:
         for b in self.decode_buckets:

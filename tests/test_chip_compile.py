@@ -32,8 +32,8 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 import paddle_tpu  # noqa: F401  (x64 on, as every user of the kernels has it)
-from paddle_tpu.kernels import (flash_attention, int8_matmul, moe_dispatch,
-                                paged_attention)
+from paddle_tpu.kernels import (flash_attention, grouped_matmul, int8_matmul,
+                                moe_dispatch, paged_attention)
 
 
 @pytest.fixture(scope="module")
@@ -217,7 +217,9 @@ def test_decode_program_compiles_with_49k_token_pool(monkeypatch, one_chip,
 # block pass of 64 slots hands the decode kernel 128 queries a sequence
 # (32 a KV head: its MXU path), the chunk kernel takes grouped heads and
 # the block rule; both carry the pool of 131,072 tokens in place, and
-# the expert stacks are read where they lie (no 400 MB slice a layer).
+# the expert stacks are read where they lie (no 1.2 GB slice a layer) by
+# the grouped-matmul kernel: 2,048 sorted rows in the block program of 64
+# slots and in the chunk program, 32 (under one row tile) in that of 1.
 def _sdar_program(one_chip, what):
     from paddle_tpu.models import sdar
     from paddle_tpu.serving import sdar_engine
@@ -229,9 +231,9 @@ def _sdar_program(one_chip, what):
         sds, sdar.sdar_weight_shapes(cfg),
         is_leaf=lambda x: isinstance(x, tuple))
     pool = sds((7, 2049, 64, 4, 128))
-    if what == "block":
+    if what.startswith("block"):
         fn = functools.partial(sdar_engine.sdar_block_step_fn, cfg=cfg)
-        args = (sds((64, 2 * 4 + 2 + 32), I32),)
+        args = (sds((int(what[5:]), 2 * 4 + 2 + 32), I32),)
     else:
         fn = functools.partial(sdar_engine.sdar_chunk_prefill_fn, cfg=cfg)
         args = (sds((1, 256), I32), sds((), I32), sds((), I32),
@@ -239,19 +241,44 @@ def _sdar_program(one_chip, what):
     return fn, (params, pool, pool) + args, 2 * 2 * math.prod(pool.shape)
 
 
-@pytest.mark.parametrize("what", ["block", "chunk"])
+@pytest.mark.parametrize("what", ["block64", "chunk", "block1"])
 def test_sdar_program_in_place_and_no_expert_slice(monkeypatch, one_chip,
                                                    no_compile_cache, what):
     fn, args, pool_bytes = _sdar_program(one_chip, what)
+    monkeypatch.setattr(grouped_matmul, "_interpret", lambda: False)
     exe = _compiled(monkeypatch, paged_attention, fn, *args, donate=(1, 2))
     mem = exe.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes > 1.7 * _GIB
     # the logits of 256 positions are 0.15 GiB; a layer's experts 1.1
     assert mem.temp_size_in_bytes < 0.25 * _GIB
     text = exe.as_text()
-    # one attention kernel, and XLA's grouped product with its metadata
-    assert text.count("tpu_custom_call") == 4
-    assert "ragged-dot" in text
+    # the layer loop holds three kernels: attention, and the grouped
+    # product twice (gate and up, then down) under the name the
+    # benchmark's roofline reads; XLA's own grouped product is gone
+    assert text.count("tpu_custom_call") == 3
+    assert sum("tpu_custom_call" in ln
+               and ln.lstrip().startswith("%grouped_ragged-dot")
+               for ln in text.splitlines()) == 2
+    assert "ragged-dot-metadata" not in text and " ragged-dot(" not in text
+
+
+@pytest.mark.parametrize("m", [32, 1024, 2048])
+def test_grouped_matmul(monkeypatch, one_chip, no_compile_cache, m):
+    """The SDAR cell's two expert products alone (a bucket of 1, of 32,
+    of 64 slots or a chunk) against its flat stacks of 7 x 128 experts:
+    two kernels, and no temporary the size of a layer's experts."""
+    def products(rows, gate_up, down, counts, layer):
+        meta = grouped_matmul.group_rows(counts, m)
+        gate, up = jnp.split(grouped_matmul.grouped_matmul(
+            rows, gate_up, meta, layer), 2, axis=-1)
+        return grouped_matmul.grouped_matmul(
+            (jax.nn.silu(gate) * up).astype(rows.dtype), down, meta, layer)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((m, 2048), BF16), ((896, 2048, 1536), BF16),
+        ((896, 768, 2048), BF16), ((128,), I32), ((), I32))]
+    exe = _compiled(monkeypatch, grouped_matmul, products, *args)
+    assert exe.as_text().count("tpu_custom_call") == 2
+    assert exe.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 @pytest.mark.parametrize("m,k,n", [(8, 1024, 4096), (8, 4096, 1024)])

@@ -117,9 +117,12 @@ def _looped_moe(a, w_router, gate_up, down, cfg):
     return out
 
 
+@pytest.mark.parametrize("use_kernel", [True, False],
+                         ids=["kernel", "ragged_dot"])
 @pytest.mark.parametrize("layer", [0, 2])
-def test_grouped_expert_product_against_the_loop(weights, layer):
-    """One expert gets every token and one gets none."""
+def test_grouped_expert_product_against_the_loop(weights, layer, use_kernel):
+    """One expert gets every token and one gets none; through the
+    grouped-matmul kernel and through the reference path."""
     E = CFG.num_experts
     rng = np.random.default_rng(7)
     a = jnp.asarray(rng.standard_normal((13, CFG.hidden_size)), jnp.float32)
@@ -129,7 +132,7 @@ def test_grouped_expert_product_against_the_loop(weights, layer):
     router[0, :] = 0.0
     router[0, 0], router[0, 1] = 40.0, -40.0
     out, load = sdar.moe_ffn(a, layer, jnp.asarray(router),
-                             weights["experts"], CFG)
+                             weights["experts"], CFG, use_kernel=use_kernel)
     sl = slice(layer * E, (layer + 1) * E)
     want = _looped_moe(a, router, weights["experts"]["gate_up"][sl],
                        weights["experts"]["down"][sl], CFG)

@@ -173,6 +173,34 @@ def test_engine_trajectory_is_the_references_generate(weights, threshold):
             for r in reqs)
 
 
+def test_status_reports_the_expert_products_row_tile_and_fill(weights):
+    """The row tile of every compiled program and, from the last pass's
+    per-expert counts, the share of its row-tile visits' rows that held
+    an assignment; the reference path has no tile to report."""
+    from paddle_tpu.kernels.grouped_matmul import row_tile, tile_visits
+    eng = _engine(weights)
+    E, k, bl = CFG.num_experts, CFG.num_experts_per_tok, CFG.block_length
+    st = eng.status()["expert_product"]
+    assert st["row_tile"] == {
+        "decode": {b: row_tile(b * bl * k, E) for b in (1, 2, 4)},
+        "chunk": row_tile(16 * k, E)}
+    assert st["tile_fill"] is None      # no pass yet
+    sched = ContinuousBatchingScheduler(eng)
+    rng = np.random.default_rng(8)
+    for p in (9, 14, 5):
+        sched.submit(rng.integers(0, CFG.vocab_size, p), max_new_tokens=6)
+    sched.run()
+    load = eng.last_pass_load
+    tm = st["row_tile"]["decode"][eng.decode_bucket(
+        int(load[0].sum()) // (bl * k))]
+    fill = eng.status()["expert_product"]["tile_fill"]
+    assert fill == load.sum() / (tile_visits(load, tm) * tm)
+    # every layer's assignments lie in one or two visits of 128 rows
+    assert 0 < fill <= load[0].sum() / tm
+    assert "expert_product" not in _engine(
+        weights, use_kernel=False).status()
+
+
 def test_prefix_hit_is_exact(weights):
     """A page is a whole number of blocks: the second request maps the
     first one's pages and generates the same tokens as without a cache."""
