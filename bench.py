@@ -733,7 +733,6 @@ def emit_serving_predicted_row(timeout_s=180, quantize=None, mode=None):
     import subprocess
     metric = {"shared_prefix": "serving_shared_prefix_predicted",
               "disagg": "serving_disagg_predicted",
-              "moe": "serving_moe_predicted",
               "fused_dispatch": "moe_fused_dispatch_predicted",
               "fleet": "serving_fleet_predicted",
               "migration": "serving_fleet_migration_predicted",
@@ -791,7 +790,6 @@ def emit_serving_predicted_row(timeout_s=180, quantize=None, mode=None):
                 + (", int8 weights" if quantize else "")
                 + (", prefix cache" if mode == "shared_prefix" else "")
                 + (", disaggregated" if mode == "disagg" else "")
-                + (", ERNIE-MoE fused dispatch" if mode == "moe" else "")
                 + (", N-replica fleet router" if mode == "fleet" else "")
                 + (", deadline-met goodput under overload control at "
                    "2x-capacity arrival" if mode == "overload" else "")
@@ -1082,130 +1080,18 @@ def bench_serving(args):
 
     bench_serving_engine(args, model, cfg, on_cpu)
     bench_serving_shared_prefix(args, model, cfg, on_cpu)
-    bench_serving_moe(args, on_cpu)
     if on_cpu:
         # the measured rows above are _cpu_smoke; the artifact still owes
         # TPU-comparable serving numbers — the static cost model's, fp,
-        # int8, prefix-cache, disaggregated-split, MoE-engine, and
-        # fused-dispatch anchors
+        # int8, prefix-cache, disaggregated-split and fused-dispatch
+        # anchors
         emit_serving_predicted_row()
         emit_serving_predicted_row(quantize="int8")
         emit_serving_predicted_row(mode="shared_prefix")
         emit_serving_predicted_row(mode="disagg")
-        emit_serving_predicted_row(mode="moe")
         emit_serving_predicted_row(mode="fused_dispatch")
         # the auto-fusion rewrite's predicted per-rule Δstep-ms anchors
         emit_autofusion_predicted_rows()
-
-
-def bench_serving_moe(args, on_cpu):
-    """``serving_moe`` row: ERNIE-MoE through the continuous-batching
-    MoE serving engine (paged decode with the FUSED Pallas MoE dispatch
-    inside every program) — tok/s at N concurrent ragged streams, with
-    greedy-parity vs eager ``ErnieMoeGenerator`` asserted on a probe
-    prompt (the acceptance oracle, carried in the extras). On the real
-    TPU a fused-kernel compile failure falls back to the gather-based
-    reference dispatch and says so, rather than taking the sweep down."""
-    from paddle_tpu.models import (ErnieMoeForPretraining, ErnieMoeModel,
-                                   ernie_moe_tiny_config)
-    from paddle_tpu.models.ernie import ErnieMoeGenerator, ErnieMoeConfig
-    from paddle_tpu.serving import (ContinuousBatchingScheduler,
-                                    MoEServingEngine)
-    from paddle_tpu.observability.reqtrace import quantile as pq
-
-    try:
-        if on_cpu:
-            cfg = ernie_moe_tiny_config(
-                num_hidden_layers=2, hidden_size=32,
-                num_attention_heads=2, intermediate_size=64,
-                num_experts=4, capacity_factor=100.0,
-                max_position_embeddings=64)
-            n_req, max_new, page_size = 4, 4, 8
-            buckets = (1, 2, 4)
-        else:
-            # mid-size MoE stack: 3 MoE layers of 8 experts — large
-            # enough to be a real decode workload, small enough that
-            # the AOT program set compiles inside the serving lane's
-            # SIGALRM budget (each program is a 6-layer Python loop)
-            cfg = ErnieMoeConfig(num_hidden_layers=6, hidden_size=512,
-                                 num_attention_heads=8,
-                                 intermediate_size=2048,
-                                 capacity_factor=100.0,
-                                 max_position_embeddings=256)
-            n_req, max_new, page_size = 8, 16, 32
-            buckets = (1, 2, 4, 8)
-        import paddle_tpu as paddle
-        paddle.seed(0)
-        model = ErnieMoeForPretraining(ErnieMoeModel(cfg))
-        model.eval()
-
-        def build(use_fused, aot=True):
-            return MoEServingEngine(model, page_size=page_size,
-                                    decode_buckets=buckets,
-                                    use_fused_moe=use_fused, aot=aot)
-
-        fused = True
-        try:
-            eng = build(True)
-        except Exception as e:  # Mosaic/lowering failure on this chip
-            fused = False
-            eng = build(False)
-            print(json.dumps({
-                "metric": "serving_moe_fused_FALLBACK", "value": 0.0,
-                "unit": "info", "vs_baseline": 0.0,
-                "extras": {"reason": repr(e)[:300]}}), flush=True)
-
-        rng = np.random.default_rng(0)
-        lens = rng.integers(3, cfg.max_position_embeddings // 4,
-                            size=n_req)
-        prompts = [rng.integers(0, cfg.vocab_size, (int(n),))
-                   .astype(np.int32) for n in lens]
-        # greedy-parity probe: scheduler-batched decode must equal the
-        # eager causal generator token-for-token (tiny prompt — the
-        # eager oracle recomputes the full forward per token)
-        parity = None
-        if on_cpu or cfg.num_hidden_layers <= 4:
-            # aot=False: the probe drives one 5-token stream — no need
-            # to AOT-sweep the full bucket set a second time
-            probe_eng = build(fused, aot=False)
-            tok0 = probe_eng.prefill(0, prompts[0][:5])
-            toks = [tok0]
-            for _ in range(max_new - 1):
-                probe_eng.pool.extend(0, 1)
-                toks.append(probe_eng.decode([0])[0])
-            want = ErnieMoeGenerator(model)(prompts[0][:5],
-                                            max_new_tokens=max_new)[0]
-            parity = bool((np.asarray(toks) == np.asarray(want)).all())
-
-        telemetry = _StepTelemetry()
-        sched = ContinuousBatchingScheduler(eng)
-        reqs = [sched.submit(p, max_new_tokens=max_new) for p in prompts]
-        t0 = time.perf_counter()
-        sched.run()
-        wall = time.perf_counter() - t0
-        total_new = sum(len(r.tokens) for r in reqs)
-        step_ms = sorted(1e3 * t for t in sched.step_times)
-        emit("serving_moe_tokens_per_sec", total_new / wall,
-             "tokens/s (ERNIE-MoE continuous batching, paged decode, "
-             "fused MoE dispatch)", {
-                 "streams": n_req, "max_new": max_new,
-                 "experts": cfg.num_experts, "top_k": cfg.top_k,
-                 "moe_layers": sum(1 for k in eng.kinds if k == "moe"),
-                 "layers": cfg.num_hidden_layers,
-                 "hidden": cfg.hidden_size,
-                 "fused_dispatch": fused,
-                 "greedy_parity_vs_eager": parity,
-                 "per_token_ms_p50": round(pq(step_ms, 0.5), 3)
-                 if step_ms else None,
-                 "per_token_ms_p95": round(pq(step_ms, 0.95), 3)
-                 if step_ms else None,
-                 "compile_s": round(eng.compile_s, 2),
-                 "pool": eng.pool.stats(),
-                 **telemetry.extras(step_times=sched.step_times,
-                                    wall_s=wall),
-             })
-    except Exception as e:
-        emit_skip("serving_moe", f"moe engine failed: {repr(e)[:300]}")
 
 
 def bench_serving_shared_prefix(args, model, cfg, on_cpu):

@@ -15,7 +15,7 @@ from .bert import (  # noqa: F401
 )
 from .ernie import (  # noqa: F401
     ErnieMoeConfig, ErnieMoeModel, ErnieMoeForPretraining,
-    ErnieMoeGenerator, stack_ernie_moe_weights,
+    ErnieMoeGenerator,
     ernie_moe_tiny_config, ernie_moe_base_config,
 )
 from .sdar import (  # noqa: F401

@@ -201,100 +201,21 @@ class ErnieMoeForPretraining(nn.Layer):
 
 
 # ---------------------------------------------------------------------------
-# serving-side weight stacking + eager generation oracle
+# eager generation oracle
 # ---------------------------------------------------------------------------
-
-def stack_ernie_moe_weights(model):
-    """Stack an :class:`ErnieMoeForPretraining`'s Parameters into the
-    decode-side pytree the MoE serving engine consumes — the
-    ``stack_gpt_weights`` pattern applied to the heterogeneous
-    dense/MoE encoder stack. Because dense and MoE layers have
-    different leaf sets, layers stack as a TUPLE of per-layer dicts
-    (the layer loop in the decode program is a static Python loop, not
-    a scan), with the static layer-kind sequence returned alongside.
-
-    Returns ``(params, kinds)``: ``params = {"wte", "wpe", "eln_w",
-    "eln_b", "layers": (dict, ...), "head": {...}}``; ``kinds`` a tuple
-    of ``"dense" | "moe"``. Per-layer dicts carry q/k/v/out projections
-    + the two LayerNorms, then either the dense FFN (``w1/b1/w2/b2``)
-    or the MoE gate + stacked expert weights (``gate_w/gate_b/ew1/eb1/
-    ew2/eb2`` with the expert dim leading)."""
-    import jax.numpy as jnp
-
-    if not isinstance(model, ErnieMoeForPretraining):
-        raise TypeError("stack_ernie_moe_weights needs an "
-                        "ErnieMoeForPretraining (the LM head is part "
-                        "of the decode program)")
-    ernie = model.ernie
-    emb = ernie.embeddings
-    v = lambda p: p._value
-
-    def attn_block(attn, ln1, ln2):
-        return {
-            "wq": v(attn.q_proj.weight), "bq": v(attn.q_proj.bias),
-            "wk": v(attn.k_proj.weight), "bk": v(attn.k_proj.bias),
-            "wv": v(attn.v_proj.weight), "bv": v(attn.v_proj.bias),
-            "wo": v(attn.out_proj.weight), "bo": v(attn.out_proj.bias),
-            "ln1_w": v(ln1.weight), "ln1_b": v(ln1.bias),
-            "ln2_w": v(ln2.weight), "ln2_b": v(ln2.bias),
-        }
-
-    layers, kinds = [], []
-    for blk in ernie.layers:
-        if hasattr(blk, "moe"):
-            p = attn_block(blk.attn, blk.ln1, blk.ln2)
-            moe = blk.moe
-            p.update({
-                "gate_w": v(moe.gate.gate.weight),
-                "gate_b": v(moe.gate.gate.bias),
-                "ew1": jnp.stack([v(e.htoh4.weight) for e in moe.experts]),
-                "eb1": jnp.stack([v(e.htoh4.bias) for e in moe.experts]),
-                "ew2": jnp.stack([v(e.h4toh.weight) for e in moe.experts]),
-                "eb2": jnp.stack([v(e.h4toh.bias) for e in moe.experts]),
-            })
-            kinds.append("moe")
-        else:
-            inner = blk.inner
-            p = attn_block(inner.self_attn, inner.norm1, inner.norm2)
-            p.update({
-                "w1": v(inner.linear1.weight), "b1": v(inner.linear1.bias),
-                "w2": v(inner.linear2.weight), "b2": v(inner.linear2.bias),
-            })
-            kinds.append("dense")
-        layers.append(p)
-
-    params = {
-        "wte": v(emb.word_embeddings.weight),
-        "wpe": v(emb.position_embeddings.weight),
-        "eln_w": v(emb.layer_norm.weight),
-        "eln_b": v(emb.layer_norm.bias),
-        "layers": tuple(layers),
-        "head": {
-            "tw": v(model.transform.weight), "tb": v(model.transform.bias),
-            "ln_w": v(model.layer_norm.weight),
-            "ln_b": v(model.layer_norm.bias),
-            "dw": v(model.decoder_weight), "db": v(model.decoder_bias),
-        },
-    }
-    return params, tuple(kinds)
-
 
 class ErnieMoeGenerator:
     """Eager greedy generation oracle over :class:`ErnieMoeForPretraining`
     run as a CAUSAL decoder: each step re-runs the full forward under a
     lower-triangular bool mask and takes the argmax of the last
     position's LM-head logits. No KV cache, no compiled program —
-    deliberately the simplest possible semantics, the token-for-token
-    oracle the paged MoE serving engine
-    (:class:`paddle_tpu.serving.moe_engine.MoEServingEngine`) is
-    asserted against.
+    deliberately the simplest possible semantics: the token-for-token
+    oracle for an incremental decoder of this model.
 
     Parity caveat (MoE capacity): incremental decode routes each token
     through the experts once, while full recompute routes the whole
     prefix every step — the two agree only when no token is capacity-
-    dropped. Build the model with a no-drop ``capacity_factor`` (the
-    serving engine's own programs always size capacity at
-    ``tokens * top_k``)."""
+    dropped. Build the model with a no-drop ``capacity_factor``."""
 
     def __init__(self, model: ErnieMoeForPretraining):
         self.model = model

@@ -1,7 +1,8 @@
 """Ragged paged-attention serving engine.
 
 The "millions of users" runtime: checkpoint-load → paged-KV generator →
-continuous batching, with per-request telemetry. Four pieces:
+continuous batching, with per-request telemetry. Two engines over one
+core, a pool, a kernel and a scheduler:
 
 - :mod:`.kv_pool` — ``PagePool``: the KV cache as fixed-size HBM pages
   with per-sequence page tables and a free list, so live memory tracks
@@ -12,12 +13,20 @@ continuous batching, with per-request telemetry. Four pieces:
   KV page block), page table scalar-prefetched so BlockSpecs gather
   pages from HBM, masked to each sequence's true length; interpret-mode
   fallback on CPU so tier-1 asserts kernel == XLA reference attention.
-- :mod:`.engine` — ``ServingEngine``: stacked decode weights (shared
-  with ``GPTGenerator``), AOT-compiled prefill programs per
-  prompt-length bucket and decode programs per batch bucket (a shape
-  outside the set RAISES — serving never recompiles), page buffers
-  donated on TPU. ``ServingEngine.from_checkpoint`` wires checkpoint
-  load.
+- :mod:`.engine_core` — ``PagedEngine``, what every paged engine does
+  and no model decides (the pool and prefix cache, one AOT-compiled
+  decode program a batch bucket and one chunk program — a shape
+  outside the set RAISES, serving never recompiles —, ``status()``,
+  the chunked prefill's skeleton, ``release()``), and
+  ``EngineContract``, what the scheduler and the fleet may rely on of
+  an engine, written down once.
+- :mod:`.engine` — ``ServingEngine``, the GPT adapter: stacked decode
+  weights (shared with ``GPTGenerator``), its step functions and
+  one-token ``decode()``, one-shot prefill programs per prompt-length
+  bucket where no chunk is set, page buffers donated on TPU.
+  ``ServingEngine.from_checkpoint`` wires checkpoint load.
+- :mod:`.sdar_engine` — ``SdarServingEngine``, the SDAR-MoE adapter
+  (block diffusion; below).
 - :mod:`.scheduler` — ``ContinuousBatchingScheduler``: evict finished /
   admit queued (with full-completion page reservation, so decode can't
   OOM the pool) / one bucketed decode step, every tick. Serving steps
@@ -83,14 +92,6 @@ Fleet serving (README "Fleet serving"):
   whole thing (per-replica roofline × N minus router overhead,
   hit-rate-split TTFT) as the ``serving_fleet_predicted`` anchor.
 
-MoE serving (README "Fused MoE dispatch & MoE serving"):
-:mod:`.moe_engine` — ``MoEServingEngine`` makes ERNIE-MoE a first-class
-serving workload: stacked dense/MoE layer weights
-(``models.ernie.stack_ernie_moe_weights``), the same paged pool +
-bucket-closed AOT programs, and the **fused Pallas MoE dispatch**
-(``kernels.moe_dispatch``) inside every decode/prefill program; greedy
-parity with eager ``ErnieMoeGenerator`` asserted in tier-1.
-
 Block-diffusion serving (README "Block-diffusion serving"):
 :mod:`.sdar_engine` — ``SdarServingEngine`` serves SDAR-MoE
 (``models.sdar``: RMSNorm, RoPE, grouped heads with QK-norm, 128
@@ -98,14 +99,15 @@ dropless SiLU experts through the grouped-matmul kernel,
 ``kernels.grouped_matmul``) from the same pool and scheduler. A step is
 one denoising or commit pass over each running sequence's block of
 ``block_len`` positions and yields 0 to ``block_len`` tokens; prefill
-yields none. The scheduler reads ``engine.block_len``
+yields none. The scheduler reads the contract's ``block_len``
 and drives such an engine through its ``_block_tick``.
 
 The static gate: ``python tools/check_program.py --model serving`` lints
 the decode step AND the chunk program, and replays a randomized
 admission mix through the real scheduler
-(:func:`.scheduler.simulate_decode_signatures`) in all three engine
-modes to prove each mode's shape set is closed — zero retraces for any
+(:func:`.scheduler.simulate_decode_signatures`) in the GPT engine's
+three prefill modes and as a block engine, to prove each shape set is
+closed — zero retraces for any
 request mix. TPU-less rounds still carry serving numbers via
 :mod:`.predict` (``serving_predicted`` plus the
 ``serving_shared_prefix_predicted`` / ``serving_disagg_predicted``
@@ -121,11 +123,11 @@ Quickstart::
     out = reqs[0].output_ids
 """
 from .kv_pool import PagePool, PagePoolError, PagePoolOOM  # noqa: F401
-from .engine import (EngineShapeError, ServingEngine,  # noqa: F401
-                     chunk_prefill_fn, decode_step_fn, prefill_fn,
-                     prefill_kv_fn, scatter_kv_fn)
-from .moe_engine import (MoEServingEngine,  # noqa: F401
-                         moe_decode_step_fn, moe_prefill_fn)
+from .engine_core import (EngineContract, EngineShapeError,  # noqa: F401
+                          PagedEngine)
+from .engine import (ServingEngine, chunk_prefill_fn,  # noqa: F401
+                     decode_step_fn, prefill_fn, prefill_kv_fn,
+                     scatter_kv_fn)
 from .sdar_engine import (SdarServingEngine,  # noqa: F401
                           sdar_block_step_fn, sdar_chunk_prefill_fn)
 from .prefix_cache import (PrefixCache,  # noqa: F401
@@ -138,8 +140,8 @@ from .fleet import FleetError, FleetRouter, ReplicaHandle  # noqa: F401
 
 __all__ = [
     "PagePool", "PagePoolError", "PagePoolOOM",
-    "ServingEngine", "EngineShapeError", "MoEServingEngine",
-    "SdarServingEngine",
+    "EngineContract", "PagedEngine", "EngineShapeError",
+    "ServingEngine", "SdarServingEngine",
     "PrefixCache", "ContinuousBatchingScheduler", "Request",
     "MigrationUnsupported",
     "simulate_decode_signatures", "make_shared_prefix_workload",

@@ -1,17 +1,31 @@
-"""Serving engine: checkpoint → paged-KV generator → continuous batching.
+"""GPT serving engine: the GPT adapter over the paged-engine core.
 
-``ServingEngine`` is the deploy-side counterpart of ``GPTHybridTrainStep``
-— it owns
+``ServingEngine`` is the deploy-side counterpart of ``GPTHybridTrainStep``.
+What every paged engine does lives in :mod:`.engine_core`
+(:class:`~.engine_core.PagedEngine`: the page pool and prefix cache, the
+AOT bucket set and ``compile_buckets()``, ``status()``, the chunked
+prefill's skeleton with its spans, ``release()``), with what the
+scheduler may rely on (:class:`~.engine_core.EngineContract`). This
+module holds what GPT decides:
 
 - the stacked decode weights (:func:`~paddle_tpu.models.gpt.
-  stack_gpt_weights`, shared with ``GPTGenerator``),
-- a :class:`~.kv_pool.PagePool` of fixed-size KV pages,
-- one AOT-compiled **prefill** program per prompt-length bucket and one
-  AOT-compiled **decode** program per batch bucket. The bucket sets are
-  closed at construction: serving any request mix reuses these programs
-  — a shape outside the set raises instead of silently recompiling
+  stack_gpt_weights`, shared with ``GPTGenerator``), optionally
+  weight-only int8 (``quantize="int8"``);
+- the pure step functions below, module globals that the engine jits in
+  ``_build_programs()`` (the static cost model traces them, the lint
+  analyzes them, tests rebind them): one **decode** program per batch
+  bucket, ONE **chunk** program for every chunk of every prompt, and,
+  without ``prefill_chunk``, one one-shot **prefill** program per
+  prompt-length bucket (``disaggregated=True``: on a prefill mesh, with
+  a KV handoff). The bucket sets are closed at construction: a shape
+  outside the set raises instead of silently recompiling
   (``tools/check_program.py --model serving`` proves the scheduler never
-  requests one).
+  requests one);
+- ``decode()``: the host's part of a one-token tick (last tokens,
+  positions, page table, a sampling key);
+- the prefix cache's copy-on-write boundary page (``_alloc_prompt``) and
+  live migration (``export_kv`` / ``begin_`` / ``commit_`` /
+  ``abort_kv_import``).
 
 Decode math: one token per live sequence per step. Each layer projects
 q/k/v for the new token, scatters k/v into the sequence's current page
@@ -20,28 +34,10 @@ paged-attention kernel (:mod:`paddle_tpu.kernels.paged_attention`; XLA
 reference path on request). The chunk program does the same for a chunk
 of one prompt with the ragged-prefill kernel.
 
-**The pool stays where it is.** Both programs carry the whole
-``[L, P, ps, nkv, d]`` K and V pools through their layer loop (the
-``lax.scan`` carry; the scan's ``xs`` are the stacked weights and the
-layer index, and it has no stacked output). A layer writes its B (or C)
-new rows into the carried pool at ``(layer, rows)`` and hands the kernel
-the whole pool with the layer index, which rides the kernel's scalar
-prefetch beside the page table: no layer's pages are ever cut out of the
-pool or written back into a second one. The page buffers are donated on
-TPU, so XLA aliases the carried pool to the program's input and output:
-a call moves the rows it writes and the pages it reads, not the pool.
-:meth:`ServingEngine.status` reports, for every AOT-compiled program,
-what the compiler says of it (``temp_bytes``, ``alias_bytes``): in place
-means ``alias_bytes`` >= the pool's bytes and ``temp_bytes`` far under.
-
 Telemetry: every prefill/decode step feeds the metric registry, the
 flight recorder, and the anomaly monitor under ``path="serving"`` (see
-``observability.instrument``), and per-request timing (queue wait, TTFT,
-tokens/s, per-token samples) lands on each finished
-:class:`~.scheduler.Request` via its ``observability.reqtrace.
-RequestTrace``. :meth:`ServingEngine.status` is the engine-side slice of
-the scheduler's live ``/status`` endpoint (weights, buckets, compile
-time, pool utilization/fragmentation). Under a device trace the chunked
+``observability.instrument``), and per-request timing lands on each
+finished :class:`~.scheduler.Request`. Under a device trace the chunked
 prefill and the decode are ``RecordEvent`` spans (``engine.prefill_begin``
 / ``prefill_step`` / ``decode``) with ``engine.host_prep`` /
 ``dispatch`` / ``readback`` inside; ``in_flight`` on them counts the
@@ -50,8 +46,6 @@ programs dispatched since the engine's last readback.
 from __future__ import annotations
 
 import functools
-import math
-import time
 
 import jax
 import jax.numpy as jnp
@@ -64,17 +58,12 @@ from ..kernels.paged_attention import (paged_attention_decode,
                                        paged_prefill_attention,
                                        ragged_prefill_attention)
 from ..profiler.utils import RecordEvent
-from .kv_pool import PagePool
-from .prefix_cache import PrefixCache
+from .engine_core import (EngineShapeError, PagedEngine, _write_rows,
+                          smallest_bucket)
 
 __all__ = ["ServingEngine", "EngineShapeError", "decode_step_fn",
            "prefill_fn", "chunk_prefill_fn", "prefill_kv_fn",
            "scatter_kv_fn"]
-
-
-class EngineShapeError(RuntimeError):
-    """A shape outside the AOT-compiled bucket set was requested. The
-    engine never recompiles at serving time — fix the bucket config."""
 
 
 # ---------------------------------------------------------------------------
@@ -127,16 +116,6 @@ def _compute_dtype(params, compute_dtype):
         return jnp.dtype(compute_dtype)
     wte = params["wte"]
     return wte["s"].dtype if _is_quant(wte) else wte.dtype
-
-
-def _write_rows(pages, layer, rows, new):
-    """Write ``new`` ``[n, nkv, d]`` into the carried pool ``[L, P, ps,
-    nkv, d]`` at token rows ``rows`` of ``layer`` (a scatter of n rows
-    on the ``[L, P*ps, nkv, d]`` view: in place on a loop-carried,
-    donated buffer)."""
-    L, np_, ps, nkv, d = pages.shape
-    return pages.reshape(L, np_ * ps, nkv, d).at[layer, rows].set(
-        new.astype(pages.dtype)).reshape(pages.shape)
 
 
 def decode_step_fn(params, k_pages, v_pages, tokens, positions, page_table,
@@ -365,10 +344,12 @@ def default_prefill_buckets(page_size, max_seq_len):
 
 # ---------------------------------------------------------------------------
 
-class ServingEngine:
+class ServingEngine(PagedEngine):
     """See module docstring. ``model`` is a built GPT model (or anything
     ``stack_gpt_weights`` accepts); ``config`` its :class:`GPTConfig`
     (derived from the model when omitted)."""
+
+    can_migrate = True      # export_kv / begin_ / commit_ / abort_kv_import
 
     def __init__(self, model, config=None, *, page_size=16, num_pages=None,
                  max_seq_len=None, decode_buckets=(1, 2, 4, 8),
@@ -380,77 +361,55 @@ class ServingEngine:
         gpt = model.gpt if hasattr(model, "gpt") else model
         self.cfg: GPTConfig = config or gpt.config
         cfg = self.cfg
-        self.params = stack_gpt_weights(model)
+        params = stack_gpt_weights(model)
         # serving-side weight dtype: quantize="int8" stores every decode
         # matmul weight as int8 + per-channel f32 scales (the
         # quantization/export.py deploy scheme routed into the engine) —
         # HBM-resident weights shrink ~4x (f32) / ~2x (bf16) and the
         # memory-bound decode loop streams int8
-        self.compute_dtype = self.params["wte"].dtype
+        compute_dtype = params["wte"].dtype
         self.quantize = quantize
         if quantize is not None:
             if quantize != "int8":
                 raise ValueError(
                     f"quantize={quantize!r}: only 'int8' is supported")
             from ..quantization.export import quantize_stacked_gpt_weights
-            self.params = quantize_stacked_gpt_weights(self.params)
+            params = quantize_stacked_gpt_weights(params)
         self.temperature = float(temperature)
         self.top_k = int(top_k)
         self.use_kernel = bool(use_kernel)
+        self._use_flash = use_flash
         max_seq_len = int(max_seq_len or cfg.max_position_embeddings)
-        if max_seq_len > cfg.max_position_embeddings:
-            raise ValueError("max_seq_len exceeds the position table")
-        self.decode_buckets = tuple(sorted(set(int(b)
-                                               for b in decode_buckets)))
         self.prefill_buckets = tuple(sorted(set(
             int(b) for b in (prefill_buckets or default_prefill_buckets(
                 page_size, max_seq_len)))))
         if self.prefill_buckets[-1] < max_seq_len:
             raise ValueError("largest prefill bucket must cover "
                              "max_seq_len")
-        pages_per_seq = math.ceil(max_seq_len / page_size)
-        if num_pages is None:
-            # worst case: every slot of the widest bucket at full length,
-            # plus the sink page
-            num_pages = self.decode_buckets[-1] * pages_per_seq + 1
-        self.pool = PagePool(num_pages, page_size,
-                             num_layers=cfg.num_layers,
-                             num_kv_heads=cfg.num_heads,
-                             head_dim=cfg.head_dim,
-                             dtype=self.compute_dtype,
-                             max_seq_len=max_seq_len)
-        self.max_seq_len = max_seq_len
-        self._key = jax.random.key(int(seed))
-        self._calls = 0
-        # programs dispatched since the last readback: a readback waits
-        # for all of them, so this tells a decode that waited for its
-        # own program from one that also waited for a chunk
-        self._in_flight = 0
-        # ---- chunked prefill + prefix cache (tentpole features) -----
         # prefix sharing needs the offset-aware chunk program (a suffix
         # prefill starts mid-prompt), so prefix_cache implies chunking
         if prefix_cache and prefill_chunk is None:
             prefill_chunk = min(8 * page_size, self.prefill_buckets[-1])
-        self.prefill_chunk = None
-        if prefill_chunk is not None:
-            c = int(prefill_chunk)
-            if c < 1 or c % page_size:
-                raise ValueError(
-                    f"prefill_chunk {c} must be a positive multiple of "
-                    f"page_size {page_size} (chunks scatter whole page "
-                    f"rows)")
-            self.prefill_chunk = c
-        self.prefix_cache = PrefixCache(self.pool) if prefix_cache else None
-        self._chunk_state: dict = {}   # seq_id -> in-flight prefill
-        self._cached_len: dict = {}    # seq_id -> matched prefix tokens
         # ---- disaggregated prefill/decode (opt-in mode) -------------
         self.disaggregated = bool(disaggregated)
-        if self.disaggregated and (self.prefill_chunk is not None
-                                   or self.prefix_cache is not None):
+        if self.disaggregated and (prefill_chunk is not None
+                                   or prefix_cache):
             raise ValueError(
                 "disaggregated=True runs whole-prompt prefills on a "
                 "separate mesh; combine it with prefix_cache/"
                 "prefill_chunk in a later PR, not here")
+        super().__init__(
+            params, num_layers=cfg.num_layers, num_kv_heads=cfg.num_heads,
+            head_dim=cfg.head_dim, dtype=compute_dtype,
+            max_positions=cfg.max_position_embeddings, page_size=page_size,
+            num_pages=num_pages, max_seq_len=max_seq_len,
+            decode_buckets=decode_buckets, prefill_chunk=prefill_chunk,
+            prefix_cache=prefix_cache)
+        self._key = jax.random.key(int(seed))
+        self._calls = 0
+        # each sequence's pending (last sampled, not yet cached) token,
+        # so scheduler and engine agree on what decodes next
+        self._last_token: dict = {}
         self.kv_transfer_bytes = 0
         self.kv_transfers = 0
         # fleet live migration (export_kv / commit_kv_import): sequences
@@ -467,59 +426,6 @@ class ServingEngine:
             self._decode_device = (list(decode_devices)[0]
                                    if decode_devices
                                    else devs[-1 if len(devs) > 1 else 0])
-        # donation lets XLA update the pool in place on TPU; the CPU
-        # backend can't donate and would warn on every step
-        donate = jax.default_backend() != "cpu"
-        eps = cfg.layer_norm_epsilon
-        cdt = str(np.dtype(self.compute_dtype))
-        # auto-fusion: rewrite the decode/chunk programs before jit so
-        # PTCS004 glue chains (int8 dequant matmuls; with
-        # use_kernel=False the chunk program's dense page gather)
-        # compile as Pallas kernels; None defers to the
-        # PADDLE_NO_AUTOFUSE env gate
-        from ..analysis import rewrite as _rewrite
-        self.autofuse = (_rewrite.autofuse_enabled() if autofuse is None
-                         else bool(autofuse))
-        _fuse = ((lambda fn, label: _rewrite.autofuse(fn, label=label))
-                 if self.autofuse else (lambda fn, label: fn))
-        self._decode_jit = jax.jit(
-            _fuse(functools.partial(decode_step_fn, eps=eps,
-                                    temperature=self.temperature,
-                                    top_k=self.top_k,
-                                    use_kernel=self.use_kernel,
-                                    compute_dtype=cdt),
-                  "serving.decode_step"),
-            donate_argnums=(1, 2) if donate else ())
-        self._prefill_jit = {
-            sb: jax.jit(
-                functools.partial(
-                    prefill_fn, eps=eps, temperature=self.temperature,
-                    top_k=self.top_k,
-                    use_flash=flash_attention_gate(sb, cfg.head_dim,
-                                                   use_flash),
-                    compute_dtype=cdt),
-                donate_argnums=(1, 2) if donate else ())
-            for sb in self.prefill_buckets}
-        # ONE chunk program: q_offset/chunk_len ride as traced scalars,
-        # so every chunk of every prompt (and every cached-prefix
-        # suffix) reuses the same executable
-        self._chunk_jit = jax.jit(
-            _fuse(functools.partial(chunk_prefill_fn, eps=eps,
-                                    temperature=self.temperature,
-                                    top_k=self.top_k,
-                                    use_kernel=self.use_kernel,
-                                    compute_dtype=cdt),
-                  "serving.chunk_prefill"),
-            donate_argnums=(1, 2) if donate else ()) \
-            if self.prefill_chunk is not None else None
-        # COW boundary copy: one fixed-shape program per pool (donated
-        # on TPU so the copy is page-local, not a pool-sized shuffle)
-        self._copy_page_jit = jax.jit(
-            lambda kp, vp, src, dst: (
-                kp.at[:, dst].set(kp[:, src]),
-                vp.at[:, dst].set(vp[:, src])),
-            donate_argnums=(0, 1) if donate else ())
-        if self.disaggregated:
             # weights live on BOTH meshes (replicated at init — the
             # per-request wire traffic is only the KV handoff); the
             # pool and decode programs are committed to the decode mesh
@@ -529,29 +435,16 @@ class ServingEngine:
             self.pool.bind(
                 jax.device_put(self.pool.k_pages, self._decode_device),
                 jax.device_put(self.pool.v_pages, self._decode_device))
-            self._prefill_kv_jit = {
-                sb: jax.jit(functools.partial(
-                    prefill_kv_fn, eps=eps,
-                    temperature=self.temperature, top_k=self.top_k,
-                    use_flash=flash_attention_gate(sb, cfg.head_dim,
-                                                   use_flash),
-                    compute_dtype=cdt))
-                for sb in self.prefill_buckets}
-            self._scatter_jit = jax.jit(
-                scatter_kv_fn, donate_argnums=(0, 1) if donate else ())
-        else:
-            # the weights live with the pool, where the programs run: a
-            # model built on another backend (host-side init) would
-            # otherwise cross to the device again on every call
-            self.params = jax.device_put(
-                self.params, next(iter(self.pool.k_pages.devices())))
-        self._decode_exe: dict = {}
-        self._prefill_exe: dict = {}
-        self._chunk_exe = None
-        self._copy_exe = None
-        self._scatter_exe: dict = {}
-        self._program_memory: dict = {"decode": {}}
-        self.compile_s = 0.0
+        # auto-fusion: rewrite the decode/chunk programs before jit so
+        # PTCS004 glue chains (int8 dequant matmuls; with
+        # use_kernel=False the chunk program's dense page gather)
+        # compile as Pallas kernels; None defers to the
+        # PADDLE_NO_AUTOFUSE env gate
+        if autofuse is None:
+            from ..analysis.rewrite import autofuse_enabled
+            autofuse = autofuse_enabled()
+        self.autofuse = bool(autofuse)
+        self._build_programs()
         if aot:
             self.compile_buckets()
 
@@ -571,6 +464,60 @@ class ServingEngine:
             target = model.gpt
         target.set_state_dict(state)
         return cls(model, config, **kw)
+
+    def _build_programs(self):
+        """(Re)make the jitted programs from this module's step
+        functions as they stand."""
+        cfg = self.cfg
+        # donation lets XLA update the pool in place on TPU; the CPU
+        # backend can't donate and would warn on every step
+        donate = jax.default_backend() != "cpu"
+        pools = (1, 2) if donate else ()
+        kw = dict(eps=cfg.layer_norm_epsilon, temperature=self.temperature,
+                  top_k=self.top_k,
+                  compute_dtype=str(np.dtype(self.compute_dtype)))
+        if self.autofuse:
+            from ..analysis.rewrite import autofuse as _fuse
+        else:
+            def _fuse(fn, label):
+                return fn
+
+        def flash(sb):
+            return flash_attention_gate(sb, cfg.head_dim, self._use_flash)
+        self._decode_jit = jax.jit(
+            _fuse(functools.partial(decode_step_fn,
+                                    use_kernel=self.use_kernel, **kw),
+                  "serving.decode_step"),
+            donate_argnums=pools)
+        self._prefill_jit = {
+            sb: jax.jit(functools.partial(prefill_fn, use_flash=flash(sb),
+                                          **kw),
+                        donate_argnums=pools)
+            for sb in self.prefill_buckets}
+        self._chunk_jit = jax.jit(
+            _fuse(functools.partial(chunk_prefill_fn,
+                                    use_kernel=self.use_kernel, **kw),
+                  "serving.chunk_prefill"),
+            donate_argnums=pools) \
+            if self.prefill_chunk is not None else None
+        # COW boundary copy: one fixed-shape program per pool (donated
+        # on TPU so the copy is page-local, not a pool-sized shuffle)
+        self._copy_page_jit = jax.jit(
+            lambda kp, vp, src, dst: (
+                kp.at[:, dst].set(kp[:, src]),
+                vp.at[:, dst].set(vp[:, src])),
+            donate_argnums=(0, 1) if donate else ())
+        if self.disaggregated:
+            self._prefill_kv_jit = {
+                sb: jax.jit(functools.partial(prefill_kv_fn,
+                                              use_flash=flash(sb), **kw))
+                for sb in self.prefill_buckets}
+            self._scatter_jit = jax.jit(
+                scatter_kv_fn, donate_argnums=(0, 1) if donate else ())
+        self._decode_exe, self._chunk_exe = {}, None
+        self._prefill_exe: dict = {}
+        self._scatter_exe: dict = {}
+        self._copy_exe = None
 
     def _aval(self, shape, dtype, side="decode"):
         """ShapeDtypeStruct for AOT lowering — carrying an explicit
@@ -592,42 +539,28 @@ class ServingEngine:
             return jnp.asarray(x)
         return jax.device_put(jnp.asarray(x), self._decode_device)
 
-    def compile_buckets(self):
-        """AOT-compile every (prefill, decode) bucket program so no
-        request mix ever compiles at serving time. Records wall time in
-        ``compile_s`` and the jit-compile telemetry counters."""
-        from ..observability.instrument import record_compile
-        t0 = time.perf_counter()
-        p = self.pool
-        kp = self._aval(p.k_pages.shape, p.k_pages.dtype)
-        params_avals = jax.tree_util.tree_map(
-            lambda a: self._aval(a.shape, a.dtype), self.params)
-        key_aval = self._aval(self._key.shape, self._key.dtype)
+    def _key_aval(self):
+        return self._aval(self._key.shape, self._key.dtype)
+
+    def _decode_avals(self, b):
         i32 = jnp.int32
-        for b in self.decode_buckets:
-            if b in self._decode_exe:
-                continue
-            self._decode_exe[b] = self._decode_jit.lower(
-                params_avals, kp, kp,
-                self._aval((b,), i32),
-                self._aval((b,), i32),
-                self._aval((b, p.max_pages_per_seq), i32),
-                self._aval((b,), i32),
-                key_aval).compile()
-        if self.prefill_chunk is not None:
-            # the chunk program REPLACES the per-bucket prefill set:
-            # one executable serves every prompt length / chunk offset
-            if self._chunk_exe is None:
-                C = self.prefill_chunk
-                self._chunk_exe = self._chunk_jit.lower(
-                    params_avals, kp, kp,
-                    jax.ShapeDtypeStruct((1, C), i32),
-                    jax.ShapeDtypeStruct((), i32),
-                    jax.ShapeDtypeStruct((), i32),
-                    jax.ShapeDtypeStruct((1, p.max_pages_per_seq), i32),
-                    jax.ShapeDtypeStruct((C,), i32),
-                    key_aval).compile()
-        elif self.disaggregated:
+        return (self._aval((b,), i32), self._aval((b,), i32),
+                self._aval((b, self.pool.max_pages_per_seq), i32),
+                self._aval((b,), i32), self._key_aval())
+
+    def _chunk_extra_avals(self):
+        return (self._key_aval(),)
+
+    def _chunk_extra_args(self):
+        return (self._next_key(),)
+
+    def _compile_more(self, params_avals, kp):
+        """The one-shot prefill programs of a bucketed or disaggregated
+        engine (the chunk program REPLACES the per-bucket set: one
+        executable serves every prompt length / chunk offset) and the
+        prefix cache's boundary copy."""
+        p, i32 = self.pool, jnp.int32
+        if self.prefill_chunk is None and self.disaggregated:
             # per-side bucket sets: prefill programs compile FOR the
             # prefill mesh, the scatter (handoff landing) + decode
             # programs FOR the decode mesh — the avals carry each
@@ -647,7 +580,7 @@ class ServingEngine:
                 kv = self._aval((L, sb, nkv, d), p.k_pages.dtype)
                 self._scatter_exe[sb] = self._scatter_jit.lower(
                     kp, kp, kv, kv, self._aval((sb,), i32)).compile()
-        else:
+        elif self.prefill_chunk is None:
             for sb in self.prefill_buckets:
                 if sb in self._prefill_exe:
                     continue
@@ -656,7 +589,7 @@ class ServingEngine:
                     jax.ShapeDtypeStruct((1, sb), i32),
                     jax.ShapeDtypeStruct((), i32),
                     jax.ShapeDtypeStruct((sb,), i32),
-                    key_aval).compile()
+                    self._key_aval()).compile()
         if self.prefix_cache is not None and self._copy_exe is None:
             # the COW boundary copy is a serving-time program too: AOT
             # it so the FIRST mid-page cache hit never compiles inside
@@ -664,31 +597,6 @@ class ServingEngine:
             self._copy_exe = self._copy_page_jit.lower(
                 kp, kp, jax.ShapeDtypeStruct((), i32),
                 jax.ShapeDtypeStruct((), i32)).compile()
-        def sizes(exe):
-            m = exe.memory_analysis()
-            return {"temp_bytes": int(m.temp_size_in_bytes),
-                    "alias_bytes": int(m.alias_size_in_bytes)}
-        self._program_memory = {"decode": {
-            b: sizes(e) for b, e in sorted(self._decode_exe.items())}}
-        if self._chunk_exe is not None:
-            self._program_memory["chunk"] = sizes(self._chunk_exe)
-        self.compile_s += time.perf_counter() - t0
-        record_compile(time.perf_counter() - t0, what="serving_buckets")
-
-    def weight_bytes(self) -> int:
-        """HBM-resident bytes of the stacked decode weights (int8 +
-        scales when ``quantize="int8"``) — the number the memory-bound
-        decode roofline streams per step."""
-        return int(sum(
-            int(getattr(leaf, "nbytes", 0) or 0)
-            for leaf in jax.tree_util.tree_leaves(self.params)))
-
-    def decode_signatures(self) -> set:
-        """The closed set of decode step shapes: {(batch_bucket,
-        pages_per_seq)} — what the recompile lint checks the scheduler
-        against."""
-        return {(b, self.pool.max_pages_per_seq)
-                for b in self.decode_buckets}
 
     def prefill_signatures(self) -> set:
         """The closed set of prefill-side program shapes for THIS
@@ -697,60 +605,22 @@ class ServingEngine:
         when disaggregated, else the classic ``(1, sb)`` bucket set —
         what the recompile lint checks the scheduler against."""
         if self.prefill_chunk is not None:
-            return {("chunk", self.prefill_chunk,
-                     self.pool.max_pages_per_seq)}
+            return super().prefill_signatures()
         if self.disaggregated:
             return {("disagg", sb) for sb in self.prefill_buckets} \
                 | {("scatter", sb) for sb in self.prefill_buckets}
         return {(1, sb) for sb in self.prefill_buckets}
 
-    def reclaim_cache_pages(self, n_pages: int) -> int:
-        """Evict LRU prefix-cache entries until ``n_pages`` returned to
-        the free list (0 without a cache) — the scheduler's admission
-        pressure valve: cache-held pages are free capacity until a
-        paying sequence needs them."""
-        if self.prefix_cache is None:
-            return 0
-        return self.prefix_cache.reclaim(int(n_pages))
-
-    def program_memory(self) -> dict:
-        """What the compiler says of every AOT-compiled program that
-        carries the pool: ``{"decode": {bucket: {...}}, "chunk": {...}}``
-        with ``temp_bytes`` and ``alias_bytes`` from
-        ``compiled.memory_analysis()``, read once when the programs
-        compile. The pool is updated in place where ``alias_bytes`` >=
-        ``pool_bytes`` (both donated pools are the program's outputs)
-        and ``temp_bytes`` is far under it; no program without AOT
-        (``aot=False``)."""
-        return dict(self._program_memory,
-                    pool_bytes=int(self.pool.k_pages.nbytes
-                                   + self.pool.v_pages.nbytes))
-
     def status(self) -> dict:
-        """Engine-side JSON snapshot for the live ``/status`` endpoint:
-        weight/pool sizing, bucket sets, compile accounting (with each
-        pool-carrying program's temporaries and aliased bytes), prefix
-        cache + disaggregation state."""
-        st = {
-            "compute_dtype": str(np.dtype(self.compute_dtype)),
-            "quantize": self.quantize,
-            "autofuse": self.autofuse,
-            "weights_mb": round(self.weight_bytes() / 2 ** 20, 2),
-            "decode_buckets": list(self.decode_buckets),
-            "prefill_buckets": list(self.prefill_buckets),
-            "prefill_chunk": self.prefill_chunk,
-            "max_seq_len": self.max_seq_len,
-            "compile_s": round(self.compile_s, 3),
-            "aot_programs": (len(self._decode_exe)
-                             + len(self._prefill_exe)
-                             + len(self._scatter_exe)
-                             + (1 if self._chunk_exe is not None else 0)
-                             + (1 if self._copy_exe is not None else 0)),
-            "program_memory": self.program_memory(),
-            "pool": self.pool.stats(),
-        }
-        if self.prefix_cache is not None:
-            st["prefix_cache"] = self.prefix_cache.stats()
+        """The core's snapshot plus the GPT engine's modes: quantization,
+        auto-fusion, the one-shot buckets, disaggregation and migration
+        state."""
+        st = super().status()
+        st.update(quantize=self.quantize, autofuse=self.autofuse,
+                  prefill_buckets=list(self.prefill_buckets))
+        st["aot_programs"] += (len(self._prefill_exe)
+                               + len(self._scatter_exe)
+                               + (self._copy_exe is not None))
         if self.disaggregated:
             st["disaggregated"] = {
                 "prefill_device": str(self._prefill_device),
@@ -773,20 +643,8 @@ class ServingEngine:
         return jax.random.fold_in(self._key, self._calls)
 
     def prefill_bucket(self, prompt_len: int) -> int:
-        for sb in self.prefill_buckets:
-            if prompt_len <= sb:
-                return sb
-        raise EngineShapeError(
-            f"prompt of {prompt_len} tokens exceeds the largest prefill "
-            f"bucket {self.prefill_buckets[-1]}")
-
-    def decode_bucket(self, n_active: int) -> int:
-        for b in self.decode_buckets:
-            if n_active <= b:
-                return b
-        raise EngineShapeError(
-            f"{n_active} active sequences exceed the largest decode "
-            f"bucket {self.decode_buckets[-1]}")
+        return smallest_bucket(self.prefill_buckets, prompt_len,
+                               "prompt tokens")
 
     def _decode_fn(self, bucket):
         if bucket in self._decode_exe:
@@ -892,24 +750,15 @@ class ServingEngine:
                 "prefill_begin requires a chunked engine "
                 "(prefill_chunk=...)")
         prompt = self._check_prompt_room(prompt_ids)
-        n = int(prompt.shape[0])
-        with RecordEvent("engine.prefill_begin", rid=seq_id,
-                         prompt_len=n) as ev:
-            cached_len = self._alloc_prompt(seq_id, prompt, n)
-            ev.set(cached_len=cached_len)
-        self._chunk_state[seq_id] = {"prompt": prompt, "pos": cached_len,
-                                     "n": n}
-        self._cached_len[seq_id] = cached_len
-        return cached_len
+        return self._begin_prefill(seq_id, prompt, int(prompt.shape[0]))
 
-    def _alloc_prompt(self, seq_id, prompt, n) -> int:
-        """Pages for a prompt of ``n`` tokens, the cached prefix mapped
-        in; returns the cached prefix length."""
+    def _alloc_prompt(self, seq_id, prompt) -> int:
+        """The core's whole pages, and the page a hit ends inside: the
+        boundary page is copied (COW) so the sequence may write the rest
+        of it."""
         if self.prefix_cache is None:
-            self.pool.note_prefix_lookup(0)
-            with RecordEvent("pool.alloc"):
-                self.pool.alloc(seq_id, n)
-            return 0
+            return super()._alloc_prompt(seq_id, prompt)
+        n = int(prompt.shape[0])
         cache = self.prefix_cache
         with RecordEvent("prefix.match"):
             nodes, boundary, cached_len = cache.match(prompt)
@@ -943,59 +792,11 @@ class ServingEngine:
                 self.pool.decref([cow])
         return cached_len
 
-    def prefill_step(self, seq_id):
-        """Run ONE chunk of an in-flight prefill. Returns ``(tokens
-        processed, done, first_token_or_None)`` — the scheduler spends
-        its per-tick prefill token budget on these, so a long prompt
-        interleaves with decode ticks instead of stalling them."""
-        st = self._chunk_state[seq_id]
-        start, n = st["pos"], st["n"]
-        clen = min(self.prefill_chunk, n - start)
-        with RecordEvent("engine.prefill_step", rid=seq_id, start=start,
-                         clen=clen, final=start + clen >= n,
-                         in_flight=self._in_flight):
-            tok = self._run_chunk(seq_id, st, start, clen)
-            if tok is None:
-                return clen, False, None
-            del self._chunk_state[seq_id]
-            if self.prefix_cache is not None:
-                # content now exists: publish the prompt's full pages so
-                # queued same-prefix requests hit them
-                self.prefix_cache.insert(st["prompt"],
-                                         self.pool.table(seq_id))
-        return clen, True, tok
-
-    def _run_chunk(self, seq_id, st, start, clen):
-        """Prepare, dispatch and, on a prompt's last chunk only, read
-        back one chunk program: the first token, or None before it."""
-        C = self.prefill_chunk
-        with RecordEvent("engine.host_prep"):
-            ids = np.zeros((1, C), np.int32)
-            ids[0, :clen] = st["prompt"][start:start + clen]
-            rows = self.pool.chunk_rows(seq_id, start, C)
-            table = self.pool.table_array([seq_id])
-            fn = self._chunk_exe if self._chunk_exe is not None \
-                else self._chunk_jit
-            args = (jnp.asarray(ids), jnp.asarray(np.int32(start)),
-                    jnp.asarray(np.int32(clen)), jnp.asarray(table),
-                    jnp.asarray(rows), self._next_key())
-        with RecordEvent("engine.dispatch"):
-            kp, vp, tok = fn(self.params, self.pool.k_pages,
-                             self.pool.v_pages, *args)
-            self.pool.bind(kp, vp)
-        self._in_flight += 1
-        st["pos"] = start + clen
-        if st["pos"] < st["n"]:
-            return None
-        with RecordEvent("engine.readback", in_flight=self._in_flight):
-            tok = int(np.asarray(tok)[0])
-        self._in_flight = 0
+    def _chunk_read(self, seq_id, tok):
+        """A prompt's last chunk yields the first token."""
+        tok = int(np.asarray(tok)[0])
         self._last_token[seq_id] = tok
         return tok
-
-    def cached_prefix_len(self, seq_id) -> int:
-        """Tokens this sequence reused from the prefix cache."""
-        return self._cached_len.get(seq_id, 0)
 
     def decode(self, seq_ids, bucket=None):
         """One decode step for ``seq_ids`` (each already holding its new
@@ -1031,29 +832,8 @@ class ServingEngine:
             self._in_flight = 0
         return out
 
-    # engine tracks each sequence's pending (last sampled, not yet
-    # cached) token so scheduler and engine agree on what decodes next
-    @functools.cached_property
-    def _last_token(self) -> dict:
-        return {}
-
-    def release(self, seq_id, token_ids=None):
-        """Free a finished sequence. With a prefix cache, ``token_ids``
-        (prompt + generated tokens whose K/V actually entered the pool
-        — i.e. everything but the final sampled token) publishes the
-        sequence's full pages into the trie first, so multi-turn
-        follow-ups and repeated completions become cache hits."""
+    def _forget(self, seq_id):
         self._last_token.pop(seq_id, None)
-        self._chunk_state.pop(seq_id, None)
-        self._cached_len.pop(seq_id, None)
-        if self.prefix_cache is not None:
-            if token_ids is not None and len(token_ids):
-                ids = np.asarray(token_ids, np.int32).reshape(-1)
-                valid = min(int(ids.shape[0]), self.pool.seq_len(seq_id))
-                self.prefix_cache.insert(ids[:valid],
-                                         self.pool.table(seq_id))
-            self.prefix_cache.release(seq_id)
-        self.pool.free(seq_id)
 
     # -------------------------------------------------- live migration
     # Host-staged KV hand-off between engines (fleet live migration):
@@ -1142,7 +922,6 @@ class ServingEngine:
         self.pool.bind(jnp.asarray(kp.reshape(shape)),
                        jnp.asarray(vp.reshape(shape)))
         self._last_token[seq_id] = int(last_token)
-        self._cached_len[seq_id] = cached_len
         self.kv_migrations_in += 1
         self.kv_migration_bytes += int(k.nbytes) + int(v.nbytes)
         return cached_len

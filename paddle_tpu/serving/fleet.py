@@ -5,7 +5,7 @@ story; this module composes the existing pieces into the
 millions-of-users shape (ROADMAP item 3):
 
 - **Replicas** — each replica is ONE OS process running a full serving
-  stack (``ServingEngine``/``MoEServingEngine`` + scheduler + SLO
+  stack (``ServingEngine`` + scheduler + SLO
   tracker + per-replica ``/metrics``/``/healthz``/``/status``),
   spawned via :func:`paddle_tpu.distributed.spawn`'s store-backed
   rendezvous and warm-started with ``from_checkpoint`` when a
@@ -225,16 +225,6 @@ def _build_engine(spec: dict):
         from ..models.gpt import GPTForPretraining, GPTModel
         paddle.seed(int(spec.get("seed", 0)))
         return ServingEngine(GPTForPretraining(GPTModel(cfg)), cfg, **kw)
-    if kind == "moe":
-        from .moe_engine import MoEServingEngine
-        import paddle_tpu as paddle
-        from ..models import ErnieMoeForPretraining, ErnieMoeModel
-        if ckpt:
-            return MoEServingEngine.from_checkpoint(ckpt, cfg, **kw)
-        paddle.seed(int(spec.get("seed", 0)))
-        model = ErnieMoeForPretraining(ErnieMoeModel(cfg))
-        model.eval()
-        return MoEServingEngine(model, **kw)
     raise FleetError(f"unknown model_kind {kind!r}")
 
 
@@ -281,6 +271,14 @@ def _fleet_replica_main(spec: dict):
     submitted: set = set()      # rids ever admitted here (submit idempotency)
     mig_in: dict = {}           # rid -> staged inbound migration chunks
     mig_adopted: set = set()    # rids whose migrate_commit already applied
+    # The control plane's turn. The loop below re-takes the scheduler's
+    # lock the moment a step drops it, and Python's locks are not fair:
+    # a poll, a submit or a migrate_out would wait out a whole burst of
+    # decode steps (a request is over before `migrate` is answered, and
+    # no poll ever sees it running). A call holds `turn` while it needs
+    # the scheduler; the loop passes through `turn` between steps, so a
+    # waiting call gets in at the next step boundary.
+    turn = lockwitness.named_lock("fleet.replica_turn")
 
     def _migrate_out(msg: dict) -> dict:
         """Source side of a live migration: checkpoint the request,
@@ -290,10 +288,11 @@ def _fleet_replica_main(spec: dict):
         the run queue and the source stays authoritative."""
         gid = int(msg["rid"])
         dest = (msg["dest"][0], int(msg["dest"][1]))
-        if not hasattr(engine, "export_kv"):
+        if not engine.can_migrate:
             return {"ok": True, "migrated": False,
                     "reason": "engine_unsupported"}
-        ck = sched.checkpoint_request(gid)
+        with turn:
+            ck = sched.checkpoint_request(gid)
         if ck is None:
             return {"ok": True, "migrated": False, "reason": "not_running"}
         t0 = time.monotonic()
@@ -330,7 +329,8 @@ def _fleet_replica_main(spec: dict):
             if not commit.get("accepted"):
                 raise FleetError("destination refused commit: "
                                  f"{commit.get('reason')}")
-            sched.complete_migration(gid)
+            with turn:
+                sched.complete_migration(gid)
             engine.kv_migrations_out += 1
             engine.kv_migration_bytes += len(blob)
             return {"ok": True, "migrated": True, "bytes": len(blob),
@@ -340,7 +340,8 @@ def _fleet_replica_main(spec: dict):
         except Exception as e:
             # source stays authoritative: restore the checkpoint and
             # tell the destination to discard its half-applied staging
-            sched.abort_migration(gid)
+            with turn:
+                sched.abort_migration(gid)
             try:
                 _rpc_request(dest, {"op": "migrate_abort", "rid": gid},
                              timeout=2.0, retries=0)
@@ -476,7 +477,15 @@ def _fleet_replica_main(spec: dict):
             return {"ok": True}
         return {"ok": False, "error": f"unknown op {op!r}"}
 
-    rpc = _RPCServer(handler)
+    def served(msg: dict) -> dict:
+        # a migrate_out streams to its destination for as long as that
+        # takes: it holds `turn` around its scheduler calls only
+        if msg.get("op") == "migrate_out":
+            return handler(msg)
+        with turn:
+            return handler(msg)
+
+    rpc = _RPCServer(served)
     # publish endpoints through the spawn rendezvous store: the parent
     # blocks on these keys, so a replica that fails to build an engine
     # fails the startup handshake loudly instead of hanging the fleet
@@ -499,6 +508,8 @@ def _fleet_replica_main(spec: dict):
     last_flush = time.monotonic()
     try:
         while not stop.is_set():
+            with turn:
+                pass            # a waiting control-plane call goes first
             try:
                 busy = sched.step() if sched.pending else False
             except Exception as e:

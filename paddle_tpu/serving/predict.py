@@ -35,8 +35,8 @@ import math
 import sys
 
 __all__ = ["predicted_serving_row", "predicted_shared_prefix_row",
-           "predicted_disagg_row", "predicted_moe_serving_row",
-           "predicted_fused_dispatch_row", "predicted_fleet_row"]
+           "predicted_disagg_row", "predicted_fused_dispatch_row",
+           "predicted_fleet_row"]
 
 
 def _gpt_config(config: str):
@@ -617,135 +617,6 @@ def predicted_migration_row(config: str = "345m", prompt_len: int = 1024,
     }
 
 
-def _moe_config(config: str):
-    from ..models.ernie import ErnieMoeConfig, ernie_moe_tiny_config
-    if config == "tiny":
-        return ernie_moe_tiny_config()
-    # "base": the bench's ERNIE-MoE shape (BASELINE config #5)
-    return ErnieMoeConfig()
-
-
-def _moe_params_avals(cfg):
-    """Abstract ``stack_ernie_moe_weights`` pytree + kinds for one
-    :class:`ErnieMoeConfig` — the real decode program's weight shapes,
-    no arrays materialized."""
-    import jax
-    import jax.numpy as jnp
-    sds = jax.ShapeDtypeStruct
-    f32 = jnp.float32
-    H, F, E = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
-
-    def attn():
-        return {"wq": sds((H, H), f32), "bq": sds((H,), f32),
-                "wk": sds((H, H), f32), "bk": sds((H,), f32),
-                "wv": sds((H, H), f32), "bv": sds((H,), f32),
-                "wo": sds((H, H), f32), "bo": sds((H,), f32),
-                "ln1_w": sds((H,), f32), "ln1_b": sds((H,), f32),
-                "ln2_w": sds((H,), f32), "ln2_b": sds((H,), f32)}
-
-    layers, kinds = [], []
-    for i in range(cfg.num_hidden_layers):
-        p = attn()
-        if cfg.moe_every and (i + 1) % cfg.moe_every == 0:
-            p.update({"gate_w": sds((H, E), f32),
-                      "gate_b": sds((E,), f32),
-                      "ew1": sds((E, H, F), f32),
-                      "eb1": sds((E, F), f32),
-                      "ew2": sds((E, F, H), f32),
-                      "eb2": sds((E, H), f32)})
-            kinds.append("moe")
-        else:
-            p.update({"w1": sds((H, F), f32), "b1": sds((F,), f32),
-                      "w2": sds((F, H), f32), "b2": sds((H,), f32)})
-            kinds.append("dense")
-        layers.append(p)
-    params = {
-        "wte": sds((cfg.vocab_size, H), f32),
-        "wpe": sds((cfg.max_position_embeddings, H), f32),
-        "eln_w": sds((H,), f32), "eln_b": sds((H,), f32),
-        "layers": tuple(layers),
-        "head": {"tw": sds((H, H), f32), "tb": sds((H,), f32),
-                 "ln_w": sds((H,), f32), "ln_b": sds((H,), f32),
-                 "dw": sds((cfg.vocab_size, H), f32),
-                 "db": sds((cfg.vocab_size,), f32)},
-    }
-    return params, tuple(kinds)
-
-
-def predicted_moe_serving_row(config: str = "base", concurrency: int = 8,
-                              page_size: int = 64, chip: str = "v5e",
-                              fused: bool = True) -> dict:
-    """``serving_moe_predicted``: static cost-model row for the ERNIE-MoE
-    serving engine — the REAL :func:`..serving.moe_engine.
-    moe_decode_step_fn` traced to a jaxpr (XLA-reference attention so
-    every op is modelable; the MoE FFN runs the **fused Pallas
-    dispatch**, which the cost model prices as one anchor: body FLOPs ×
-    grid, HBM = operands + results) and rolled through the roofline.
-    ``fused=False`` prices the gather-based dispatch instead — the
-    extras carry both, so the fused-vs-unfused step-time delta is part
-    of the anchor row."""
-    import functools
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from ..analysis.passes.cost import estimate_jaxpr_cost
-    from ..observability.instrument import chip_specs
-    from .moe_engine import moe_decode_step_fn
-
-    cfg = _moe_config(config)
-    B = int(concurrency)
-    ps = int(page_size)
-    L, nh, d = (cfg.num_hidden_layers, cfg.num_attention_heads,
-                cfg.head_dim)
-    pages_per_seq = math.ceil(cfg.max_position_embeddings / ps)
-    num_pages = B * pages_per_seq + 1
-    sds = jax.ShapeDtypeStruct
-    i32 = jnp.int32
-    params, kinds = _moe_params_avals(cfg)
-    kp = sds((L, num_pages, ps, nh, d), jnp.float32)
-    spec = chip_specs(chip)
-
-    def price(use_fused):
-        fn = functools.partial(
-            moe_decode_step_fn, kinds=kinds, eps=cfg.layer_norm_eps,
-            top_k=cfg.top_k, temperature=0.0, topk_sample=0,
-            use_kernel=False, use_fused_moe=use_fused)
-        closed = jax.make_jaxpr(fn)(
-            params, kp, kp, sds((B,), i32), sds((B,), i32),
-            sds((B, pages_per_seq), i32), sds((B,), i32), None)
-        return estimate_jaxpr_cost(closed, chip=spec)
-
-    cost = price(bool(fused))
-    other = price(not fused)
-    fused_ms = cost.step_ms if fused else other.step_ms
-    unfused_ms = other.step_ms if fused else cost.step_ms
-    step_s = cost.step_ms / 1e3
-    weight_bytes = sum(
-        int(np.prod(t.shape, dtype=np.int64) * np.dtype(t.dtype).itemsize)
-        for t in jax.tree_util.tree_leaves(params))
-    return {
-        "config": config,
-        "model": "ernie_moe",
-        "concurrency": B,
-        "page_size": ps,
-        "num_experts": cfg.num_experts,
-        "top_k": cfg.top_k,
-        "moe_layers": sum(1 for k in kinds if k == "moe"),
-        "fused_dispatch": bool(fused),
-        "weights_mb": round(weight_bytes / 2 ** 20, 1),
-        "predicted_decode_step_ms": round(cost.step_ms, 3),
-        "predicted_tokens_per_sec": round(B / step_s, 1) if step_s else 0.0,
-        "predicted_per_token_ms_p50": round(cost.step_ms, 3),
-        "predicted_per_token_ms_p95": round(cost.step_ms, 3),
-        "predicted_bound": cost.bound,
-        "predicted_step_ms_fused": round(fused_ms, 3),
-        "predicted_step_ms_unfused": round(unfused_ms, 3),
-        "predicted_fused_dispatch_speedup": round(
-            unfused_ms / fused_ms, 3) if fused_ms else 0.0,
-        "chip_assumed": spec.get("name"),
-    }
-
-
 def predicted_fused_dispatch_row(tokens: int = 8192, d_model: int = 1024,
                                  num_expert: int = 64, top_k: int = 2,
                                  capacity_factor: float = 1.2,
@@ -815,20 +686,19 @@ def predicted_autofusion_row(export_path: str | None = None) -> dict:
     auto-fusion rewrite that fires on the tiny serving engines' REAL
     traced programs — :mod:`paddle_tpu.analysis.rewrite` over the GPT
     int8 chunked-prefill engine (``ragged_prefill`` +
-    ``int8_dequant_matmul``) and the unfused ERNIE-MoE engine
-    (``moe_gate_dispatch``). Trace + interpret-mode parity only, so a
-    TPU-less round still carries the anchor; future measured fused rows
+    ``int8_dequant_matmul``) and the gather-based MoE gate and dispatch
+    of ``kernels.moe_dispatch`` (``moe_gate_dispatch``). Trace +
+    interpret-mode parity only, so a TPU-less round still carries the
+    anchor; future measured fused rows
     anchor on these per-rule predictions via bench_compare.
     ``export_path`` additionally writes the raw match records
     (``autofusion.json``) for the perf doctor."""
     import numpy as np
     import paddle_tpu as paddle
     from ..analysis import rewrite
-    from ..models import (ErnieMoeForPretraining, ErnieMoeModel,
-                          ernie_moe_tiny_config)
+    from ..kernels.moe_dispatch import reference_moe_dispatch
     from ..models.gpt import GPTForPretraining, GPTModel, gpt_tiny_config
     from .engine import ServingEngine
-    from .moe_engine import MoEServingEngine
 
     rewrite.reset_records()
     paddle.seed(0)
@@ -847,18 +717,17 @@ def predicted_autofusion_row(export_path: str | None = None) -> dict:
     eng.pool.extend("a")
     eng.decode(["a"])
 
-    mcfg = ernie_moe_tiny_config(
-        num_hidden_layers=2, hidden_size=32, num_attention_heads=2,
-        intermediate_size=64, num_experts=4, capacity_factor=100.0,
-        max_position_embeddings=64)
-    mm = ErnieMoeForPretraining(ErnieMoeModel(mcfg))
-    mm.eval()
-    moe = MoEServingEngine(mm, mcfg, page_size=8, decode_buckets=(1,),
-                           aot=False, use_fused_moe=False, autofuse=True)
-    moe.prefill("s", rng.integers(0, mcfg.vocab_size,
-                                  (11,)).astype(np.int32))
-    moe.pool.extend("s")
-    moe.decode(["s"])
+    # the gather-based gate -> dispatch chain, as an unfused MoE layer
+    # traces it (S=64 tokens of 32, 8 experts, top 2, capacity 1.2x)
+    S, M, E, K = 64, 32, 8, 2
+    rewrite.autofuse(
+        lambda x, gw, gb: reference_moe_dispatch(
+            x, gw, gb, num_expert=E, capacity=int(1.2 * K * S / E),
+            top_k=K, gate_kind="gshard"),
+        label="moe.gate_dispatch")(
+        rng.standard_normal((S, M)).astype(np.float32),
+        0.1 * rng.standard_normal((M, E)).astype(np.float32),
+        0.01 * rng.standard_normal((E,)).astype(np.float32))
 
     sites = [{"label": r.get("label"), "site": r.get("site"),
               "rule": r.get("rule"),
@@ -896,14 +765,13 @@ def _main(argv=None):
                     help="price the weight-only-int8 decode program "
                          "(serving engine quantize='int8')")
     ap.add_argument("--mode", default="decode",
-                    choices=["decode", "shared_prefix", "disagg", "moe",
+                    choices=["decode", "shared_prefix", "disagg",
                              "fused_dispatch", "fleet", "migration",
                              "overload", "autofusion"],
                     help="decode = classic serving_predicted row; "
                          "shared_prefix = prefix-cache goodput/TTFT "
                          "anchor; disagg = disaggregated prefill/"
-                         "decode split anchor; moe = ERNIE-MoE engine "
-                         "(fused Pallas dispatch) anchor; "
+                         "decode split anchor; "
                          "fused_dispatch = fused-vs-unfused MoE "
                          "dispatch stage speedup anchor; fleet = "
                          "N-replica router anchor (per-replica "
@@ -950,11 +818,7 @@ def _main(argv=None):
     import jax
     jax.config.update("jax_platforms", "cpu")
     try:
-        if args.mode == "moe":
-            row = predicted_moe_serving_row(
-                "base" if args.config not in ("tiny",) else "tiny",
-                args.concurrency, args.page_size, args.chip)
-        elif args.mode == "fused_dispatch":
+        if args.mode == "fused_dispatch":
             row = predicted_fused_dispatch_row(chip=args.chip)
         elif args.mode == "autofusion":
             row = predicted_autofusion_row(args.export_records)

@@ -12,9 +12,13 @@ and drives the engine one decode step at a time:
 3. **decode** — the active set, in deterministic (admission-order) slot
    order, runs one step of the smallest AOT batch bucket that fits.
 
-An engine whose step is not one token declares ``block_len`` (and a
-prefill that yields no token): the decode phase is then
-:meth:`ContinuousBatchingScheduler._block_tick`, one pass over each
+What the scheduler may rely on of an engine is written down once, in
+:class:`~.engine_core.EngineContract` (``decode_buckets``, ``pool``,
+``block_len``, ``prefill_chunk``, ``prefix_cache``, ``can_migrate``,
+``decode_bucket``, ``reclaim_cache_pages``, ``status``): it reads those
+and probes nothing. An engine whose step is not one token declares
+``block_len`` (and a prefill that yields no token): the decode phase is
+then :meth:`ContinuousBatchingScheduler._block_tick`, one pass over each
 running sequence's block that yields 0 to ``block_len`` tokens for it;
 the pool grows by a block, time to first token is the first block, and
 a request still ends at ``max_new_tokens`` exactly. The one-token path
@@ -63,6 +67,7 @@ import numpy as np
 
 from ..observability import lockwitness
 from ..profiler.utils import RecordEvent
+from .engine_core import EngineContract, smallest_bucket
 
 __all__ = ["Request", "ContinuousBatchingScheduler",
            "MigrationUnsupported", "simulate_decode_signatures"]
@@ -214,13 +219,13 @@ class ContinuousBatchingScheduler:
         # chunked engines interleave prefill with decode: each tick
         # spends at most this many prefill tokens (chunk-granular; the
         # default of one chunk is the tightest decode-stall bound)
-        self.chunked = getattr(engine, "prefill_chunk", None) is not None
+        self.chunked = engine.prefill_chunk is not None
         # how a step advances a sequence: one token (1), or one pass over
         # a block of this many positions that yields 0..block tokens and
         # whose prefill yields none. A page holds whole blocks (the
         # engine checks), so a completion rounded up to a block needs the
         # pages that `_completion_pages` already reckons
-        self.block_len = int(getattr(engine, "block_len", 1))
+        self.block_len = int(engine.block_len)
         self.prefill_token_budget = int(prefill_token_budget) \
             if prefill_token_budget else (engine.prefill_chunk
                                           if self.chunked else None)
@@ -369,7 +374,7 @@ class ContinuousBatchingScheduler:
         refcounts and records no stats): how many prompt tokens would
         be served from cache. Brownout prefers hits at admission;
         shedding rejects misses outright."""
-        cache = getattr(self.engine, "prefix_cache", None)
+        cache = self.engine.prefix_cache
         if cache is None:
             return 0
         try:
@@ -445,20 +450,10 @@ class ContinuousBatchingScheduler:
         EVICTION, never a recompile: no new program shapes — the
         closure replay's cancellation mix proves it."""
         from ..observability import instrument as obs
-        rid = r.rid
         if phase == "prefilling":
-            if rid in self._begun:
-                self._begun.discard(rid)
-                held = len(self.engine.pool.table(rid))
-                self._reserved_pages -= self._completion_pages(r) - held
-                self.engine.release(rid)
-            else:
-                self._reserved_pages -= self._completion_pages(r)
+            self._drop_prefilling(r)
         elif phase == "running":
-            held = len(self.engine.pool.table(rid))
-            self._reserved_pages -= self._completion_pages(r) - held
-            self.engine.release(rid, token_ids=np.concatenate(
-                [r.prompt, np.asarray(r.tokens[:-1], np.int32)]))
+            self._release(r, cached=r.tokens[:-1])
         r.state = "deadline_exceeded"
         r.finish_time = now
         if r.trace is not None:
@@ -527,6 +522,24 @@ class ContinuousBatchingScheduler:
         return self.engine.pool.pages_needed(
             int(r.prompt.shape[0]) + r.max_new_tokens)
 
+    def _release(self, r: Request, cached=None):
+        """Give back what is left of ``r``'s page reservation and free
+        its sequence; ``cached`` (the generated tokens whose K/V entered
+        the pool) publishes it, after the prompt, to the prefix cache."""
+        held = len(self.engine.pool.table(r.rid))
+        self._reserved_pages -= self._completion_pages(r) - held
+        self.engine.release(
+            r.rid, token_ids=None if cached is None else np.concatenate(
+                [r.prompt, np.asarray(cached, np.int32)]))
+
+    def _drop_prefilling(self, r: Request):
+        """A request out of the prefill phase, with or without pages."""
+        if r.rid in self._begun:
+            self._begun.discard(r.rid)
+            self._release(r)
+        else:
+            self._reserved_pages -= self._completion_pages(r)
+
     def _log_request(self, r: Request):
         """Stream a request's terminal record to requests.jsonl (no-op
         outside a telemetry-enabled run)."""
@@ -544,14 +557,11 @@ class ContinuousBatchingScheduler:
         done = [rid for rid, r in self._running.items() if r.done]
         for rid in done:
             r = self._running.pop(rid)
-            held = len(self.engine.pool.table(rid))
-            self._reserved_pages -= self._completion_pages(r) - held
             # everything but the final sampled token has K/V in the
             # pool — exactly what the prefix cache may re-serve (a block
             # engine emits a block once it is committed: all of them)
-            cached = r.tokens if self.block_len > 1 else r.tokens[:-1]
-            self.engine.release(rid, token_ids=np.concatenate(
-                [r.prompt, np.asarray(cached, np.int32)]))
+            self._release(r, cached=r.tokens if self.block_len > 1
+                          else r.tokens[:-1])
             r.state = "finished"
             r.finish_time = time.perf_counter()
             self._finish_ts.append(r.finish_time)
@@ -574,8 +584,7 @@ class ContinuousBatchingScheduler:
         pool = self.engine.pool
         avail = pool.free_pages - self._reserved_pages
         if avail < need:
-            avail += self.engine.reclaim_cache_pages(need - avail) \
-                if hasattr(self.engine, "reclaim_cache_pages") else 0
+            avail += self.engine.reclaim_cache_pages(need - avail)
         return avail >= need
 
     def _next_admit_index(self) -> int:
@@ -1017,10 +1026,7 @@ class ContinuousBatchingScheduler:
         from ..observability import instrument as obs
         with self._lock:
             r = self._migrating.pop(rid)
-            held = len(self.engine.pool.table(rid))
-            self._reserved_pages -= self._completion_pages(r) - held
-            self.engine.release(rid, token_ids=np.concatenate(
-                [r.prompt, np.asarray(r.tokens[:-1], np.int32)]))
+            self._release(r, cached=r.tokens[:-1])
             self.migrations_out += 1
             obs.serving_requests_counter().inc(event="migrated_out")
             return r
@@ -1038,13 +1044,7 @@ class ContinuousBatchingScheduler:
             r = self._prefilling.pop(rid, None)
             if r is None:
                 return False
-            if rid in self._begun:
-                self._begun.discard(rid)
-                held = len(self.engine.pool.table(rid))
-                self._reserved_pages -= self._completion_pages(r) - held
-                self.engine.release(rid)
-            else:
-                self._reserved_pages -= self._completion_pages(r)
+            self._drop_prefilling(r)
             return True
 
     def prepare_migration_in(self, rid, token_ids, prompt_len: int,
@@ -1056,7 +1056,7 @@ class ContinuousBatchingScheduler:
         the cached prefix) are reserved here, so the commit can never
         OOM a pool that said yes."""
         eng = self.engine
-        if not hasattr(eng, "begin_kv_import"):
+        if not eng.can_migrate:
             return False, "engine_unsupported"
         with self._lock:
             if self.draining:
@@ -1228,8 +1228,7 @@ class ContinuousBatchingScheduler:
                     "burn_rate": round(burn, 4),
                 },
             }
-            if hasattr(self.engine, "status"):
-                st["engine"] = self.engine.status()
+            st["engine"] = self.engine.status()
         from ..observability import anomaly
         st["last_anomaly"] = anomaly.last_anomaly()
         return st
@@ -1246,32 +1245,37 @@ class ContinuousBatchingScheduler:
 # static bucket-closure proof (device-free)
 # ---------------------------------------------------------------------------
 
-class _ShapeProbeEngine:
+class _ShapeProbeEngine(EngineContract):
     """Engine stand-in for :func:`simulate_decode_signatures`: real
     :class:`~.kv_pool.PagePool` bookkeeping and bucket tables, but
-    prefill/decode only record the shapes they were asked for. Must
-    mirror the real engine's interface the scheduler touches — in every
-    mode (classic bucketed, chunked/prefix-cache, disaggregated)."""
+    prefill/decode only record the shapes they were asked for. It is
+    the :class:`~.engine_core.EngineContract` and no more — in every
+    prefill mode (classic bucketed, chunked, disaggregated) and, with
+    ``block_len`` > 1, as a block engine whose every block takes one
+    denoising and one commit pass."""
 
     def __init__(self, decode_buckets, prefill_buckets, page_size,
                  num_pages, max_seq_len, prefill_chunk=None,
-                 disaggregated=False):
+                 disaggregated=False, block_len=1):
         from .kv_pool import PagePool
         self.decode_buckets = tuple(sorted(set(decode_buckets)))
         self.prefill_buckets = tuple(sorted(set(prefill_buckets)))
         self.prefill_chunk = int(prefill_chunk) if prefill_chunk else None
         self.disaggregated = bool(disaggregated)
+        self.block_len = int(block_len)
         self.pool = PagePool(num_pages, page_size, num_layers=1,
                              num_kv_heads=1, head_dim=1,
                              max_seq_len=max_seq_len)
         self.decode_signatures_used: set = set()
         self.prefill_signatures_used: set = set()
         self._chunk_pos: dict = {}
+        self._block: dict = {}      # seq_id -> the current block: are
+        #                             its rows "held", "passes" taken,
+        #                             the "prompt" tokens it begins with
 
     def prefill(self, seq_id, prompt_ids):
         n = int(np.asarray(prompt_ids).reshape(-1).shape[0])
-        from .engine import ServingEngine
-        sb = ServingEngine.prefill_bucket(self, n)
+        sb = smallest_bucket(self.prefill_buckets, n, "prompt tokens")
         self.pool.alloc(seq_id, n)
         if self.disaggregated:
             # prefill program on the prefill mesh + the KV-handoff
@@ -1286,12 +1290,20 @@ class _ShapeProbeEngine:
     # ---- chunked-mode surface the scheduler drives -----------------
     def prefill_begin(self, seq_id, prompt_ids):
         n = int(np.asarray(prompt_ids).reshape(-1).shape[0])
-        self.pool.alloc(seq_id, n)
+        bl = self.block_len
+        rest, n = n % bl, n // bl * bl      # whole blocks are prefilled
+        self.pool.alloc(seq_id, n or bl)
         self._chunk_pos[seq_id] = [0, n]
+        self._block[seq_id] = {"held": n == 0, "passes": 0,
+                               "prompt": rest}
         return 0
 
     def prefill_step(self, seq_id):
         pos, n = self._chunk_pos[seq_id]
+        first = 0 if self.block_len == 1 else None
+        if pos >= n:                # shorter than a block
+            del self._chunk_pos[seq_id]
+            return 0, True, first
         c = min(self.prefill_chunk, n - pos)
         self.prefill_signatures_used.add(
             ("chunk", self.prefill_chunk, self.pool.max_pages_per_seq))
@@ -1300,32 +1312,46 @@ class _ShapeProbeEngine:
         if pos < n:
             return c, False, None
         del self._chunk_pos[seq_id]
-        return c, True, 0
-
-    def reclaim_cache_pages(self, n):
-        return 0
-
-    def prefill_bucket(self, n):  # same lookup the real engine uses
-        from .engine import ServingEngine
-        return ServingEngine.prefill_bucket(self, n)
-
-    def decode_bucket(self, n):
-        from .engine import ServingEngine
-        return ServingEngine.decode_bucket(self, n)
+        return c, True, first
 
     def decode(self, seq_ids, bucket):
         self.decode_signatures_used.add(
             (int(bucket), self.pool.max_pages_per_seq))
-        return [0] * len(seq_ids)
+        if self.block_len == 1:
+            return [0] * len(seq_ids)
+        out = []
+        for sid in seq_ids:
+            st = self._block[sid]
+            st["held"], st["passes"] = True, st["passes"] + 1
+            if st["passes"] % 2:    # denoising pass: nothing comes out
+                out.append(([], [], []))
+            else:                   # commit: the block, and a new one
+                new = self.block_len - st["prompt"]
+                st["held"], st["prompt"] = False, 0
+                out.append(([0] * new, [0] * new, [1.0] * new))
+        return out
+
+    # ---- a block engine's three extra calls ------------------------
+    def starts_block(self, seq_id) -> bool:
+        return not self._block[seq_id]["held"]
+
+    def masked_positions(self, seq_ids) -> int:
+        return sum(self.block_len for s in seq_ids
+                   if not self._block[s]["passes"] % 2)
+
+    def note_emitted(self, emitted, dropped):
+        pass
 
     def release(self, seq_id, token_ids=None):
+        self._block.pop(seq_id, None)
         self.pool.free(seq_id)
 
 
 def simulate_decode_signatures(decode_buckets, prefill_buckets, page_size,
                                num_pages, max_seq_len, n_requests=200,
                                seed=0, arrival_p=0.35, prefill_chunk=None,
-                               disaggregated=False, cancel_p=0.0):
+                               disaggregated=False, cancel_p=0.0,
+                               block_len=1):
     """Replay the REAL scheduler over a randomized admission mix (ragged
     prompt lengths, random completion budgets, bursty arrivals) with a
     shape-probe engine. Returns ``(decode_sigs_used, prefill_sigs_used,
@@ -1334,7 +1360,8 @@ def simulate_decode_signatures(decode_buckets, prefill_buckets, page_size,
     request mix can retrace at serving time. ``prefill_chunk`` /
     ``disaggregated`` replay the chunked (prefix-cache) and
     disaggregated engine modes, whose prefill-side program sets differ
-    (one chunk signature; per-bucket prefill + scatter).
+    (one chunk signature; per-bucket prefill + scatter); ``block_len``
+    > 1 replays a block engine (chunked), through ``_block_tick``.
 
     ``cancel_p`` mixes randomized deadline-style cancellations into
     the replay: after each tick, with that probability, one live
@@ -1347,7 +1374,8 @@ def simulate_decode_signatures(decode_buckets, prefill_buckets, page_size,
     eng = _ShapeProbeEngine(decode_buckets, prefill_buckets, page_size,
                             num_pages, max_seq_len,
                             prefill_chunk=prefill_chunk,
-                            disaggregated=disaggregated)
+                            disaggregated=disaggregated,
+                            block_len=block_len)
     sched = ContinuousBatchingScheduler(eng)
     submitted = 0
     while submitted < n_requests or sched.pending:

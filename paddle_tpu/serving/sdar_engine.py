@@ -1,16 +1,18 @@
 """Block-diffusion serving engine: SDAR-MoE from the page pool.
 
-``SdarServingEngine`` presents the surface that
-:class:`~.scheduler.ContinuousBatchingScheduler` drives (as
-``ServingEngine`` and ``MoEServingEngine`` do), for a model whose step is
-not one token: it declares ``block_len`` (the scheduler's
-``tokens_per_step``), its prefill yields no token, and one ``decode``
-call is one *pass* over the current block of every running sequence,
-which returns the 0 to ``block_len`` tokens each of them finished.
+``SdarServingEngine`` is the SDAR adapter over the paged-engine core
+(:mod:`.engine_core`: the pool, the prefix cache, the AOT bucket set,
+``status()``, the chunked prefill's skeleton and ``release()`` are
+:class:`~.engine_core.PagedEngine`'s), for a model whose step is not one
+token. Of the :class:`~.engine_core.EngineContract` it declares
+``block_len`` (the scheduler then drives it through ``_block_tick``): its
+prefill yields no token, and one ``decode`` call is one *pass* over the
+current block of every running sequence, which returns the 0 to
+``block_len`` tokens each of them finished. This module holds the step
+functions (module globals: ``_build_programs()`` jits them as they
+stand), ``decode()`` and the blocks' host state.
 
-Two programs, on PR 28's scheme (the whole ``[L, P, ps, nkv, d]`` pool in
-the ``lax.scan`` carry, written at ``(layer, rows)``, the paged kernels
-indexed by layer, the pool donated and aliased):
+Two programs, the pool carried in place as :mod:`.engine_core` sets out:
 
 - :func:`sdar_chunk_prefill_fn`: one chunk (256) of the prompt's whole
   blocks through ``ragged_prefill_attention`` with ``block=block_len``
@@ -52,15 +54,15 @@ probability of its token) that the program read there (handed to the
 scheduler with the tokens: the record that the benchmark's reference
 replays, so that numbers are compared and not only choices).
 
-Spans as in ``ServingEngine``: ``engine.prefill_begin`` /
-``prefill_step`` / ``decode`` with ``engine.host_prep`` / ``dispatch`` /
+Spans: the core's ``engine.prefill_begin`` / ``prefill_step``, and
+``engine.decode``, each with ``engine.host_prep`` / ``dispatch`` /
 ``readback`` inside; ``engine.decode`` also carries ``commit`` (1 where
 every live sequence of the pass commits), ``n_commit``, ``pass`` (the
 pass index where all live sequences are at the same one, else -1) and
 ``unmasked``.
 
-Live migration is refused by name (no ``export_kv``/``begin_kv_import``,
-so the fleet answers ``engine_unsupported``; the scheduler's
+Live migration is refused by name (``can_migrate`` stays False, so the
+fleet answers ``engine_unsupported``; the scheduler's
 ``checkpoint_request`` and ``migratable_rids`` raise
 ``MigrationUnsupported``: a block in flight has no token-exact checkpoint
 yet); cancellation and eviction release a sequence at any pass, and only
@@ -70,7 +72,6 @@ page of 64 is 16 blocks, so a hit is exact).
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -83,9 +84,7 @@ from ..kernels.paged_attention import (paged_attention_decode,
                                        ragged_prefill_attention)
 from ..models import sdar
 from ..profiler.utils import RecordEvent
-from .engine import EngineShapeError, _write_rows
-from .kv_pool import PagePool
-from .prefix_cache import PrefixCache
+from .engine_core import EngineShapeError, PagedEngine, _write_rows
 
 __all__ = ["SdarServingEngine", "sdar_block_step_fn",
            "sdar_chunk_prefill_fn", "block_attention"]
@@ -236,12 +235,10 @@ class _Block:
         self.begun = False              # its rows are in the pool's length
 
 
-class SdarServingEngine:
+class SdarServingEngine(PagedEngine):
     """See the module docstring. ``params`` is the stacked layout of
     :func:`paddle_tpu.models.sdar.sdar_weight_shapes` (placed on the
     pool's device here); greedy only."""
-
-    prefill_yields_token = False
 
     def __init__(self, params, config: sdar.SdarMoeConfig, *, page_size=64,
                  num_pages=None, max_seq_len=None,
@@ -255,32 +252,21 @@ class SdarServingEngine:
                 f"a page ({page_size}) must hold whole blocks ({bl}) and a "
                 f"chunk ({prefill_chunk}) whole pages")
         max_seq_len = int(max_seq_len or cfg.max_position_embeddings)
-        if max_seq_len % bl or max_seq_len > cfg.max_position_embeddings:
-            raise ValueError(f"max_seq_len {max_seq_len}: whole blocks, "
-                             f"within the model's positions")
-        self.max_seq_len = max_seq_len
-        self.decode_buckets = tuple(sorted({int(b) for b in decode_buckets}))
-        self.prefill_chunk = int(prefill_chunk)
+        if max_seq_len % bl:
+            raise ValueError(f"max_seq_len {max_seq_len}: whole blocks")
         self.use_kernel = bool(use_kernel)
         self.threshold = cfg.confidence_threshold if threshold is None \
             else float(threshold)
-        self.compute_dtype = params["embed"].dtype
-        if num_pages is None:
-            num_pages = self.decode_buckets[-1] * (
-                -(-max_seq_len // page_size)) + 1
-        self.pool = PagePool(num_pages, page_size,
-                             num_layers=cfg.num_hidden_layers,
-                             num_kv_heads=cfg.num_key_value_heads,
-                             head_dim=cfg.head_dim,
-                             dtype=self.compute_dtype,
-                             max_seq_len=max_seq_len)
-        self.params = jax.device_put(
-            params, next(iter(self.pool.k_pages.devices())))
-        self.prefix_cache = PrefixCache(self.pool) if prefix_cache else None
+        super().__init__(
+            params, num_layers=cfg.num_hidden_layers,
+            num_kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
+            dtype=params["embed"].dtype,
+            max_positions=cfg.max_position_embeddings, page_size=page_size,
+            num_pages=num_pages, max_seq_len=max_seq_len,
+            decode_buckets=decode_buckets, prefill_chunk=prefill_chunk,
+            prefix_cache=prefix_cache)
         self._blocks: dict = {}         # seq_id -> _Block
-        self._chunk_state: dict = {}    # seq_id -> in-flight prefill
         self._pending_load: list = []   # chunk programs' counts, unread
-        self._in_flight = 0
         self.counters = {"passes_denoise": 0, "passes_commit": 0,
                          "passes_mixed": 0, "blocks_committed": 0,
                          "tokens_emitted": 0, "tokens_dropped": 0,
@@ -289,10 +275,6 @@ class SdarServingEngine:
             (cfg.num_hidden_layers, cfg.num_experts), np.int64)
         self.last_pass_load = None      # [L, E] of the last decode pass
         self.last_chunk_loads = []      # of the chunks last read back
-        self._decode_exe: dict = {}
-        self._chunk_exe = None
-        self._program_memory: dict = {"decode": {}}
-        self.compile_s = 0.0
         self._build_programs()
         if aot:
             self.compile_buckets()
@@ -317,68 +299,13 @@ class SdarServingEngine:
     def _state_width(self):
         return 2 * self.block_len + 2 + self.pool.max_pages_per_seq
 
-    def compile_buckets(self):
-        """AOT-compile the block program of every decode bucket and the
-        chunk program, so that serving never compiles."""
-        from ..observability.instrument import record_compile
-        t0 = time.perf_counter()
-        p = self.pool
-        S = jax.ShapeDtypeStruct
-        kp = S(p.k_pages.shape, p.k_pages.dtype)
-        avals = jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype),
-                                       self.params)
-        i32 = jnp.int32
-        for b in self.decode_buckets:
-            if b not in self._decode_exe:
-                self._decode_exe[b] = self._decode_jit.lower(
-                    avals, kp, kp, S((b, self._state_width), i32)).compile()
-        if self._chunk_exe is None:
-            C = self.prefill_chunk
-            self._chunk_exe = self._chunk_jit.lower(
-                avals, kp, kp, S((1, C), i32), S((), i32), S((), i32),
-                S((1, p.max_pages_per_seq), i32), S((C,), i32)).compile()
-
-        def sizes(exe):
-            m = exe.memory_analysis()
-            return {"temp_bytes": int(m.temp_size_in_bytes),
-                    "alias_bytes": int(m.alias_size_in_bytes)}
-        self._program_memory = {
-            "decode": {b: sizes(e) for b, e in
-                       sorted(self._decode_exe.items())},
-            "chunk": sizes(self._chunk_exe)}
-        self.compile_s += time.perf_counter() - t0
-        record_compile(time.perf_counter() - t0, what="serving_buckets")
-
-    def weight_bytes(self) -> int:
-        return int(sum(leaf.nbytes for leaf in
-                       jax.tree_util.tree_leaves(self.params)))
-
-    def reclaim_cache_pages(self, n_pages: int) -> int:
-        if self.prefix_cache is None:
-            return 0
-        return self.prefix_cache.reclaim(int(n_pages))
+    def _decode_avals(self, b):
+        return (jax.ShapeDtypeStruct((b, self._state_width), jnp.int32),)
 
     def status(self) -> dict:
-        st = {
-            "compute_dtype": str(np.dtype(self.compute_dtype)),
-            "weights_mb": round(self.weight_bytes() / 2 ** 20, 2),
-            "decode_buckets": list(self.decode_buckets),
-            "prefill_chunk": self.prefill_chunk,
-            "block_len": self.block_len,
-            "max_seq_len": self.max_seq_len,
-            "compile_s": round(self.compile_s, 3),
-            "aot_programs": len(self._decode_exe)
-            + (self._chunk_exe is not None),
-            "program_memory": dict(
-                self._program_memory,
-                pool_bytes=int(self.pool.k_pages.nbytes
-                               + self.pool.v_pages.nbytes)),
-            "pool": self.pool.stats(),
-            "passes": dict(self.counters),
-            "expert_load": self.expert_load.tolist(),
-        }
-        if self.prefix_cache is not None:
-            st["prefix_cache"] = self.prefix_cache.stats()
+        st = super().status()
+        st["passes"] = dict(self.counters)
+        st["expert_load"] = self.expert_load.tolist()
         if self.use_kernel:
             st["expert_product"] = self._expert_product()
         return st
@@ -402,14 +329,6 @@ class SdarServingEngine:
                 tile_visits(load, tm) * tm)
         return out
 
-    def decode_bucket(self, n_active: int) -> int:
-        for b in self.decode_buckets:
-            if n_active <= b:
-                return b
-        raise EngineShapeError(
-            f"{n_active} active sequences exceed the largest decode "
-            f"bucket {self.decode_buckets[-1]}")
-
     # ----------------------------------------------------------- prefill
     def prefill_begin(self, seq_id, prompt_ids) -> int:
         """Pages for the prompt's whole blocks (cached whole pages mapped
@@ -423,92 +342,45 @@ class SdarServingEngine:
             raise EngineShapeError(
                 f"prompt of {n} tokens leaves no room for a block within "
                 f"max_seq_len {self.max_seq_len}")
-        with RecordEvent("engine.prefill_begin", rid=seq_id,
-                         prompt_len=n) as ev:
-            cached_len = self._alloc_prompt(seq_id, prompt[:n_full])
-            ev.set(cached_len=cached_len)
+        cached_len = self._begin_prefill(seq_id, prompt[:n_full], n)
         block = _Block(n_full, prompt[n_full:], bl, self.cfg.mask_token_id)
         block.begun = n_full == 0       # its rows were allocated above
         self._blocks[seq_id] = block
-        self._chunk_state[seq_id] = {"prompt": prompt[:n_full],
-                                     "pos": cached_len, "n": n_full}
         return cached_len
 
     def _alloc_prompt(self, seq_id, whole) -> int:
-        n = int(whole.shape[0])
-        if not n:                       # shorter than a block: no prefill
+        """Whole pages only, as the core maps them: inside a block every
+        position's K/V depends on the block's other tokens, so a hit
+        that ends inside a page (the GPT engine's copy-on-write
+        boundary) would not be exact."""
+        if not whole.shape[0]:          # shorter than a block: no prefill
             self.pool.note_prefix_lookup(0)
             self.pool.alloc(seq_id, self.block_len)
             return 0
-        if self.prefix_cache is None:
-            self.pool.note_prefix_lookup(0)
-            with RecordEvent("pool.alloc"):
-                self.pool.alloc(seq_id, n)
-            return 0
-        cache = self.prefix_cache
-        with RecordEvent("prefix.match"):
-            # whole pages only: inside a block every position's K/V
-            # depends on the block's other tokens, so a hit that ends
-            # inside a page (the GPT engine's copy-on-write boundary)
-            # would not be exact
-            nodes, _boundary, _ = cache.match(whole)
-            pages = cache.map_into(seq_id, nodes, None)
-        cached_len = len(nodes) * self.pool.page_size
-        with RecordEvent("pool.alloc", cow=False):
-            try:
-                self.pool.alloc_prefixed(seq_id, n, pages, cached_len)
-            except Exception:
-                cache.release(seq_id)
-                raise
-        return cached_len
+        return super()._alloc_prompt(seq_id, whole)
 
     def prefill_step(self, seq_id):
-        """Run one chunk of an in-flight prefill: ``(tokens processed,
-        done, None)``. The last chunk reads back (the chunks' expert
-        counts), so that a finished prefill is a finished program."""
+        """One chunk, as the core runs it: ``(tokens processed, done,
+        None)``. The last chunk reads back the chunks' expert counts."""
         st = self._chunk_state[seq_id]
-        start, n = st["pos"], st["n"]
-        if start >= n:                  # nothing to prefill
+        if st["pos"] >= st["n"]:        # nothing to prefill
             del self._chunk_state[seq_id]
             return 0, True, None
-        clen = min(self.prefill_chunk, n - start)
-        final = start + clen >= n
-        with RecordEvent("engine.prefill_step", rid=seq_id, start=start,
-                         clen=clen, final=final, in_flight=self._in_flight):
-            C = self.prefill_chunk
-            with RecordEvent("engine.host_prep"):
-                ids = np.zeros((1, C), np.int32)
-                ids[0, :clen] = st["prompt"][start:start + clen]
-                rows = self.pool.chunk_rows(seq_id, start, C)
-                table = self.pool.table_array([seq_id])
-                fn = self._chunk_exe if self._chunk_exe is not None \
-                    else self._chunk_jit
-                args = (jnp.asarray(ids), jnp.asarray(np.int32(start)),
-                        jnp.asarray(np.int32(clen)), jnp.asarray(table),
-                        jnp.asarray(rows))
-            with RecordEvent("engine.dispatch"):
-                kp, vp, load = fn(self.params, self.pool.k_pages,
-                                  self.pool.v_pages, *args)
-                self.pool.bind(kp, vp)
-            self._in_flight += 1
-            self._pending_load.append(load)
-            self.counters["prefill_chunks"] += 1
-            st["pos"] = start + clen
-            if not final:
-                return clen, False, None
-            with RecordEvent("engine.readback", in_flight=self._in_flight):
-                self.last_chunk_loads = [
-                    np.asarray(load).reshape(self.expert_load.shape)
-                    for load in self._pending_load]
-            for load in self.last_chunk_loads:
-                self.expert_load += load
-            self._pending_load.clear()
-            self._in_flight = 0
-            del self._chunk_state[seq_id]
-            if self.prefix_cache is not None:
-                self.prefix_cache.insert(st["prompt"],
-                                         self.pool.table(seq_id))
-        return clen, True, None
+        return super().prefill_step(seq_id)
+
+    def _chunk_issued(self, load):
+        self._pending_load.append(load)
+        self.counters["prefill_chunks"] += 1
+
+    def _chunk_read(self, seq_id, load):
+        """Prefill yields no token: what is read is every pending
+        chunk's expert counts."""
+        self.last_chunk_loads = [
+            np.asarray(load).reshape(self.expert_load.shape)
+            for load in self._pending_load]
+        for load in self.last_chunk_loads:
+            self.expert_load += load
+        self._pending_load.clear()
 
     # ------------------------------------------------------------ decode
     def starts_block(self, seq_id) -> bool:
@@ -612,17 +484,5 @@ class SdarServingEngine:
         self.counters["tokens_emitted"] += emitted
         self.counters["tokens_dropped"] += dropped
 
-    def release(self, seq_id, token_ids=None):
-        """Free a sequence at any pass. ``token_ids`` (prompt and
-        generated tokens whose blocks were committed) publishes its whole
-        pages to the prefix cache first."""
+    def _forget(self, seq_id):
         self._blocks.pop(seq_id, None)
-        self._chunk_state.pop(seq_id, None)
-        if self.prefix_cache is not None:
-            if token_ids is not None and len(token_ids):
-                ids = np.asarray(token_ids, np.int32).reshape(-1)
-                valid = min(int(ids.shape[0]), self.pool.seq_len(seq_id))
-                self.prefix_cache.insert(ids[:valid],
-                                         self.pool.table(seq_id))
-            self.prefix_cache.release(seq_id)
-        self.pool.free(seq_id)

@@ -253,37 +253,6 @@ def test_serving_engine_autofuse_parity():
     assert "ragged_prefill" in rules
 
 
-def test_moe_engine_autofuse_matches_fused_engine():
-    from paddle_tpu.models import (ErnieMoeForPretraining, ErnieMoeModel,
-                                   ernie_moe_tiny_config)
-    from paddle_tpu.serving.moe_engine import MoEServingEngine
-
-    paddle.seed(0)
-    mcfg = ernie_moe_tiny_config(
-        num_hidden_layers=2, hidden_size=32, num_attention_heads=2,
-        intermediate_size=64, num_experts=4, capacity_factor=100.0,
-        max_position_embeddings=64)
-    mm = ErnieMoeForPretraining(ErnieMoeModel(mcfg))
-    mm.eval()
-    fused = MoEServingEngine(mm, mcfg, page_size=8, decode_buckets=(1,),
-                             aot=False, use_fused_moe=True,
-                             autofuse=False)
-    auto = MoEServingEngine(mm, mcfg, page_size=8, decode_buckets=(1,),
-                            aot=False, use_fused_moe=False, autofuse=True)
-    prompt = np.random.default_rng(1).integers(
-        0, mcfg.vocab_size, (11,)).astype(np.int32)
-    assert fused.prefill("s", prompt) == auto.prefill("s", prompt)
-    toks = ([], [])
-    for _ in range(3):
-        fused.pool.extend("s")
-        auto.pool.extend("s")
-        toks[0].append(fused.decode(["s"])[0])
-        toks[1].append(auto.decode(["s"])[0])
-    assert toks[0] == toks[1]
-    assert any(r["rule"] == "moe_gate_dispatch"
-               for r in rewrite.fired_records())
-
-
 # ---------------------------------------------------------------------------
 # bench anchor row
 # ---------------------------------------------------------------------------

@@ -175,3 +175,26 @@ def test_gate_aux_loss_cleared_in_eval():
     model.eval()
     model(ids)
     assert all(not g.has_loss for g in gates)
+
+
+def test_generator_is_greedy_causal_and_restores_the_mode():
+    """``ErnieMoeGenerator`` (the eager oracle; the engine it was the
+    oracle of is gone): a longer generation begins with the shorter one,
+    and a model in training mode is in training mode again afterwards."""
+    from paddle_tpu.models import ErnieMoeGenerator
+    paddle.seed(0)
+    cfg = ernie_moe_tiny_config(
+        num_hidden_layers=2, hidden_size=32, num_attention_heads=2,
+        intermediate_size=64, num_experts=4, capacity_factor=100.0,
+        max_position_embeddings=64)     # no-drop: the parity caveat
+    m = ErnieMoeForPretraining(ErnieMoeModel(cfg))
+    m.train()
+    gen = ErnieMoeGenerator(m)
+    ids = _data(cfg, B=2, S=9, seed=3)
+    three = gen(ids, max_new_tokens=3)
+    assert m.training
+    assert three.shape == (2, 3) and three.dtype == np.int64
+    assert (three >= 0).all() and (three < cfg.vocab_size).all()
+    m.eval()
+    np.testing.assert_array_equal(gen(ids, max_new_tokens=2), three[:, :2])
+    assert not m.training

@@ -23,6 +23,7 @@ Coverage:
 - a slow-marked chaos loop: kill -> migrate -> scale-in cycles under
   sustained load (plus a SIGSTOP straggler shed) with zero failures.
 """
+import collections
 import json
 import signal
 import socket
@@ -540,27 +541,41 @@ def test_fleet_live_migration_failover_and_drain_by_migrate(
         assert fleet.run(timeout=240)
 
         # ---- phase 1: live-migrate a mid-decode request
+        # The request is as long as the engine's max_seq_len allows and
+        # `migrate` is asked again as soon as it answers (a tick of the
+        # router only where the request is not dispatched yet, and now
+        # and then to see a result), so that it is caught on its first
+        # tokens. Every try has a prompt of its own: a retry of the same
+        # one would find its pages in the destination's cache and send a
+        # payload too small to stream in two chunks.
         mig_rid, rep = None, None
-        for _attempt in range(6):
-            rid = submit(prompts[4], 32)
+        n_new = cfg.max_position_embeddings - len(prompts[4])
+        refused = collections.Counter()     # why every `migrate` said no
+        for mig_prompt in [prompts[4]] + _shared_prompts(cfg, 5, rng):
+            rid = submit(mig_prompt, n_new)
             deadline = time.monotonic() + 90
             while rid not in fleet.results \
                     and time.monotonic() < deadline:
-                fleet.tick()
                 r2 = fleet.migrate(rid)
                 if r2.get("migrated"):
                     mig_rid, rep = rid, r2
                     break
-                time.sleep(0.005)
+                why = str(r2.get("reason") or r2.get("error"))
+                refused[why] += 1
+                if why != "not_running" or not refused[why] % 8:
+                    fleet.tick()
             if mig_rid is not None:
                 break
-            assert rid in fleet.results    # finished too fast; try again
-        assert mig_rid is not None, "could not catch a request mid-decode"
+            assert rid in fleet.results, dict(refused)  # too fast; again
+        assert mig_rid is not None, (
+            f"could not catch a request mid-decode: {dict(refused)}")
+        # the only refusals are the two benign races
+        assert set(refused) <= {"not_inflight", "not_running"}, refused
         assert fleet.run(timeout=240)
         assert rep["bytes"] > 0 and rep["chunks"] >= 2
         # warm destination: at least one full page was NOT resent
         assert rep["cached_len"] >= ps
-        assert rep["payload_tokens"] < len(prompts[4]) + 32
+        assert rep["payload_tokens"] < len(mig_prompt) + n_new
         res = fleet.results[mig_rid]
         assert res["state"] == "finished" and res["replica"] == rep["to"]
         summ = res["summary"]
@@ -620,7 +635,15 @@ def test_fleet_live_migration_failover_and_drain_by_migrate(
                         victim = rid_
                         break
                 time.sleep(0.005)
-            assert victim is not None
+            assert victim is not None, {
+                "status": {k: {f: (h.last_status or {}).get(f) for f in
+                               ("running", "prefilling", "queue_depth",
+                                "finished", "draining", "healthy")}
+                           for k, h in fleet.replicas.items()},
+                "queued": len(fleet._queue),
+                "inflight": {k: v.get("replica")
+                             for k, v in fleet._inflight.items()},
+                "done": len(fleet.results), "of": len(expected)}
             assert fleet.scale_in(victim, reason="test") == victim
             assert fleet.run(timeout=240)
             deadline = time.monotonic() + 120

@@ -421,3 +421,20 @@ def test_global_scatter_count_mismatch_names_expert():
     y = global_scatter(x, lc, lc)
     z = global_gather(y, lc, lc)
     np.testing.assert_allclose(_np(z), _np(x))
+
+
+# ---------------------------------------------------------------------------
+# the static cost model's price of the stage the kernels fuse
+# ---------------------------------------------------------------------------
+
+def test_predicted_fused_dispatch_row_beats_baseline():
+    """The bench acceptance bar: the fused dispatch+combine stage beats
+    the gather chain in the static cost model, the PTCS004 diagnostic
+    fires on the old path and is clean on the new — all carried in the
+    anchor row itself."""
+    from paddle_tpu.serving.predict import predicted_fused_dispatch_row
+    row = predicted_fused_dispatch_row()
+    assert row["predicted_speedup"] > 1.0, row
+    assert row["hbm_mb_fused"] < row["hbm_mb_unfused"]
+    assert row["ptcs004_fires_unfused"] is True
+    assert row["ptcs004_clean_fused"] is True

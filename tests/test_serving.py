@@ -265,42 +265,8 @@ def test_scheduler_rejects_oversized_and_eos():
     assert r.state == "finished" and r.tokens == [eos]
 
 
-def test_engine_shape_errors_and_aot_closure():
-    """The AOT bucket set is closed at init: unknown decode batches and
-    oversized prompts raise instead of recompiling; the randomized
-    admission-mix simulation stays inside the set."""
-    model, _ = _tiny_model(seed=6)
-    eng = ServingEngine(model, page_size=8, decode_buckets=(1, 2),
-                        aot=True)
-    assert set(eng._decode_exe) == {1, 2}
-    assert set(eng._prefill_exe) == set(eng.prefill_buckets)
-    with pytest.raises(EngineShapeError):
-        eng.decode_bucket(3)
-    with pytest.raises(EngineShapeError):
-        eng.prefill_bucket(10_000)
-    with pytest.raises(EngineShapeError):
-        eng.prefill("x", np.zeros(128, np.int32))  # no room to decode
-    used_d, used_p, ok_d, ok_p = simulate_decode_signatures(
-        eng.decode_buckets, eng.prefill_buckets, eng.pool.page_size,
-        eng.pool.num_pages, eng.max_seq_len, n_requests=120, seed=7)
-    assert used_d and used_d <= ok_d
-    assert used_p and used_p <= ok_p
-
-
-def test_engine_no_recompile_across_mix():
-    """Serving a shuffled request mix never grows the compiled-program
-    set beyond the AOT buckets (zero retraces at serving time)."""
-    model, cfg = _tiny_model(seed=7)
-    eng = ServingEngine(model, page_size=8, decode_buckets=(1, 2, 4),
-                        aot=True)
-    n_exe = len(eng._decode_exe) + len(eng._prefill_exe)
-    compile_s0 = eng.compile_s
-    sched = ContinuousBatchingScheduler(eng)
-    for i, p in enumerate(_prompts(cfg, (3, 21, 9, 14, 5, 40), seed=8)):
-        sched.submit(p, max_new_tokens=2 + i % 4)
-    sched.run()
-    assert len(eng._decode_exe) + len(eng._prefill_exe) == n_exe
-    assert eng.compile_s == compile_s0
+# shape errors, the AOT closure and "a mixed run compiles nothing" are
+# held for every engine in tests/test_engine_contract.py
 
 
 # ------------------------------------------------- engine from checkpoint
@@ -394,7 +360,7 @@ def test_check_program_serving_gate_clean():
         assert rep.clean, str(rep)
     assert {r.target_name for r in reports} == {
         "serving.decode_step", "serving.decode_buckets",
-        "serving.chunk_prefill", "serving.moe_decode_step"}
+        "serving.chunk_prefill", "serving.sdar_block_step"}
 
 
 # ------------------------------------------------------------- predict

@@ -423,76 +423,6 @@ def test_fleet_end_to_end_warm_start_federation_and_drain_retire(
     assert ev.get("fleet_scale") == 1 and ev.get("replica_retired") == 1
 
 
-def test_fleet_moe_checkpoint_warm_start_kill_under_load(
-        tmp_path, monkeypatch):
-    """MoE-checkpoint warm start (owed from PR 14): FleetRouter replicas
-    build their engine via ``MoEServingEngine.from_checkpoint``, and the
-    kill-under-load harness still holds — a SIGKILLed warm-started MoE
-    replica is replaced (itself warm-started from the same checkpoint)
-    with zero failed requests."""
-    from paddle_tpu.distributed.fleet.elastic.fault_injection import \
-        kill_replica
-    from paddle_tpu.models import (ErnieMoeForPretraining, ErnieMoeModel,
-                                   ernie_moe_tiny_config)
-    from paddle_tpu.serving.fleet import FleetRouter
-    _drain_env(monkeypatch, tmp_path)
-    cfg = ernie_moe_tiny_config(
-        num_hidden_layers=2, hidden_size=32, num_attention_heads=2,
-        intermediate_size=64, num_experts=4, capacity_factor=100.0,
-        max_position_embeddings=64)
-    paddle.seed(11)
-    ckpt = str(tmp_path / "ernie_moe.pdparams")
-    paddle.save(ErnieMoeForPretraining(ErnieMoeModel(cfg)).state_dict(),
-                ckpt)
-
-    fleet = FleetRouter(cfg, checkpoint=ckpt, n_replicas=2,
-                        model_kind="moe",
-                        engine_kwargs=dict(page_size=8,
-                                           decode_buckets=(1, 2, 4)),
-                        run_dir=str(tmp_path / "run"), seed=11,
-                        max_restarts=3)
-    rng = np.random.default_rng(3)
-    try:
-        fleet.start()
-        rids, killed = [], False
-        n_total = 8
-        deadline = time.monotonic() + 240
-        while len(fleet.results) < n_total:
-            assert time.monotonic() < deadline, (
-                f"stalled: {len(fleet.results)}/{n_total} done, "
-                f"outstanding={fleet.outstanding}")
-            if len(rids) < n_total:
-                p = rng.integers(0, cfg.vocab_size, (10,)).astype(np.int32)
-                rids.append(fleet.submit(p, max_new_tokens=4))
-            fleet.tick()
-            if not killed and len(fleet.results) >= 1 and fleet._inflight:
-                target = next(
-                    (rec["replica"] for rec in fleet._inflight.values()
-                     if rec.get("replica") is not None), None)
-                if target is not None:
-                    kill_replica(fleet, target)
-                    killed = True
-            time.sleep(0.01)
-        assert killed
-        states = {fleet.results[r]["state"] for r in rids}
-        assert states == {"finished"}          # zero failed requests
-        assert all(len(fleet.results[r]["tokens"]) == 4 for r in rids)
-        assert fleet.restarts >= 1
-        summary = fleet.shutdown()
-    finally:
-        fleet.shutdown(federate=False)
-    assert summary["fleet"]["restarts"] >= 1
-    # every replica start (initial pair + relaunch) was warm-started
-    events = []
-    for path in glob.glob(os.path.join(fleet.run_dir, "events.rank*.jsonl")):
-        with open(path) as f:
-            events += [json.loads(ln) for ln in f if ln.strip()]
-    starts = [e for e in events if e.get("event") == "replica_start"]
-    assert len(starts) >= 3                    # 2 initial + >=1 relaunch
-    assert all(e.get("warm_start") for e in starts)
-    assert all(e.get("engine") == "MoEServingEngine" for e in starts)
-
-
 def test_fleet_replica_sigkill_under_load_zero_failed_requests(
         tmp_path, monkeypatch):
     """ACCEPTANCE: SIGKILL a replica under sustained load. Goodput

@@ -271,7 +271,8 @@ def test_brownout_prefers_cache_hits_and_pauses_background():
     sched = _probe_sched()
     sched.max_concurrency = 1
     hits = types.SimpleNamespace(
-        match=lambda prompt: (None, None, 8 if prompt[0] == 7 else 0))
+        match=lambda prompt: (None, None, 8 if prompt[0] == 7 else 0),
+        reclaim=lambda n_pages: 0)
     sched.engine.prefix_cache = hits
     calls = []
     sched.background_hooks.append(lambda: calls.append(1))
@@ -299,7 +300,7 @@ def test_shedding_rejects_cache_misses_with_retry_hint():
     assert r.retry_after_s is not None
     # cache hits still get in: shedding protects goodput, not uptime
     sched.engine.prefix_cache = types.SimpleNamespace(
-        match=lambda prompt: (None, None, 8))
+        match=lambda prompt: (None, None, 8), reclaim=lambda n_pages: 0)
     r2 = sched.submit(np.zeros(8, np.int32), 4)
     assert r2.state == "queued"
 

@@ -96,8 +96,6 @@ _ANCHOR_MAP = {
     "serving_disagg": "serving_disagg_predicted",
     # the MoE serving engine row (ERNIE-MoE, fused Pallas dispatch)
     # anchors on the static cost model's MoE decode-program row
-    "serving_moe_tokens_per_sec": "serving_moe_predicted",
-    "serving_moe": "serving_moe_predicted",
     # the N-replica fleet row anchors on the fleet roofline model
     # (per-replica roofline x N minus router overhead)
     "serving_fleet_tokens_per_sec": "serving_fleet_predicted",

@@ -209,22 +209,19 @@ def lint_serving(world_size=None, hbm_budget_gb=None):
     # bucketed engine, the chunked/prefix-cache engine (whose prefill
     # side is ONE traced-offset chunk program), the disaggregated
     # engine (per-bucket prefill programs on the prefill mesh + scatter
-    # landings on the decode mesh), and the MoE engine (ERNIE-MoE
-    # dense/MoE stack, fused Pallas dispatch — classic prefill
-    # semantics, its own bucket/pool sizing). Each mode's allowed set
-    # must match what the real engine would AOT-compile, and every
-    # signature the real scheduler requests must fall inside it.
-    from paddle_tpu.models import (ErnieMoeForPretraining, ErnieMoeModel,
-                                   ernie_moe_tiny_config)
-    from paddle_tpu.serving import MoEServingEngine
-    mcfg = ernie_moe_tiny_config(num_hidden_layers=2, hidden_size=32,
-                                 num_attention_heads=2,
-                                 intermediate_size=64, num_experts=4,
-                                 max_position_embeddings=64)
-    mmodel = ErnieMoeForPretraining(ErnieMoeModel(mcfg))
-    mmodel.eval()
-    moe_eng = MoEServingEngine(mmodel, page_size=8,
-                               decode_buckets=(1, 2, 4), aot=False)
+    # landings on the decode mesh), and the block engine (SDAR-MoE:
+    # chunked prefill that yields no token, a decode step that is a pass
+    # over blocks and grows the pool a block at a time). Each mode's
+    # allowed set must match what the real engine would AOT-compile, and
+    # every signature the real scheduler requests must fall inside it.
+    from paddle_tpu.models.sdar import init_sdar_weights, sdar_moe_tiny_config
+    from paddle_tpu.serving import SdarServingEngine
+    from paddle_tpu.serving.sdar_engine import sdar_block_step_fn
+    scfg = sdar_moe_tiny_config()
+    sdar_eng = SdarServingEngine(
+        init_sdar_weights(scfg, 0), scfg, page_size=8, num_pages=64,
+        max_seq_len=128, decode_buckets=(1, 2, 4), prefill_chunk=16,
+        aot=False)
     chunk = eng.prefill_buckets[0]
     modes = {
         "classic": (dict(), eng),
@@ -236,71 +233,58 @@ def lint_serving(world_size=None, hbm_budget_gb=None):
                    ServingEngine(model, page_size=8,
                                  decode_buckets=(1, 2, 4),
                                  disaggregated=True, aot=False)),
-        # MoE: classic prefill/decode semantics over the MoE engine's
-        # own pool/bucket config — proves the scheduler can never ask
-        # the MoE decode program for an uncompiled shape either
-        "moe": (dict(), moe_eng),
+        "blocks": (dict(prefill_chunk=sdar_eng.prefill_chunk,
+                        block_len=sdar_eng.block_len), sdar_eng),
     }
+
+    def error(msg, op):
+        diags.append(Diagnostic("PTRC002", "recompile", "error", msg,
+                                op=f"serving.{op}"))
+
+    def replay(e, **sim_kw):
+        return simulate_decode_signatures(
+            e.decode_buckets,
+            # a chunked-only engine has no one-shot buckets
+            e.prefill_buckets if e.prefill_chunk is None
+            else (e.max_seq_len,),
+            e.pool.page_size, e.pool.num_pages, e.max_seq_len,
+            n_requests=200, seed=0, **sim_kw)
+
     for mode, (sim_kw, mode_eng) in modes.items():
-        used_d, used_p, ok_d, ok_p = simulate_decode_signatures(
-            mode_eng.decode_buckets, mode_eng.prefill_buckets,
-            mode_eng.pool.page_size, mode_eng.pool.num_pages,
-            mode_eng.max_seq_len, n_requests=200, seed=0, **sim_kw)
-        if ok_d != mode_eng.decode_signatures():
-            # the closure proof is only a proof if the probe's allowed
-            # set IS the set the real engine AOT-compiles
-            diags.append(Diagnostic(
-                "PTRC002", "recompile", "error",
-                f"[{mode}] shape-probe allowed set {sorted(ok_d)} "
-                f"drifted from the engine's AOT decode signatures "
-                f"{sorted(mode_eng.decode_signatures())}",
-                op="serving.decode"))
-        if ok_p != mode_eng.prefill_signatures():
-            diags.append(Diagnostic(
-                "PTRC002", "recompile", "error",
-                f"[{mode}] shape-probe allowed prefill set "
-                f"{sorted(ok_p, key=str)} drifted from the engine's "
-                f"AOT prefill signatures "
-                f"{sorted(mode_eng.prefill_signatures(), key=str)}",
-                op="serving.prefill"))
-        for used, ok, what in ((used_d, ok_d, "decode"),
-                               (used_p, ok_p, "prefill")):
-            escaped = sorted(used - ok, key=str)
-            if escaped:
-                diags.append(Diagnostic(
-                    "PTRC002", "recompile", "error",
-                    f"[{mode}] serving {what} requested shape(s) "
-                    f"{escaped} outside the AOT bucket set "
-                    f"{sorted(ok, key=str)} — every such shape "
-                    f"retraces at serving time; widen the bucket "
-                    f"config", op=f"serving.{what}"))
+        used_d, used_p, ok_d, ok_p = replay(mode_eng, **sim_kw)
+        # the closure proof is only a proof if the probe's allowed set
+        # IS the set the real engine AOT-compiles
+        for ok, real, what in (
+                (ok_d, mode_eng.decode_signatures(), "decode"),
+                (ok_p, mode_eng.prefill_signatures(), "prefill")):
+            if ok != real:
+                error(f"[{mode}] shape-probe allowed {what} set "
+                      f"{sorted(ok, key=str)} drifted from the engine's "
+                      f"AOT {what} signatures {sorted(real, key=str)}",
+                      what)
         # cancellation mix: the same replay with randomized mid-decode
         # deadline cancellations through the real scheduler's cancel()
         # path. Cancel is an EVICTION — it must introduce ZERO program
         # signatures outside the AOT set (never a recompile), and the
         # probe's allowed set must not move
-        cd, cp, okd_c, okp_c = simulate_decode_signatures(
-            mode_eng.decode_buckets, mode_eng.prefill_buckets,
-            mode_eng.pool.page_size, mode_eng.pool.num_pages,
-            mode_eng.max_seq_len, n_requests=200, seed=0,
-            cancel_p=0.15, **sim_kw)
+        cd, cp, okd_c, okp_c = replay(mode_eng, cancel_p=0.15, **sim_kw)
         if (okd_c, okp_c) != (ok_d, ok_p):
-            diags.append(Diagnostic(
-                "PTRC002", "recompile", "error",
-                f"[{mode}+cancel] probe allowed set changed under the "
-                f"cancellation mix — the cancel path must not alter "
-                f"what the engine compiles", op="serving.cancel"))
-        for used, ok, what in ((cd, ok_d, "decode"),
-                               (cp, ok_p, "prefill")):
+            error(f"[{mode}+cancel] probe allowed set changed under the "
+                  f"cancellation mix — the cancel path must not alter "
+                  f"what the engine compiles", "cancel")
+        for tag, used, ok, what in (
+                (mode, used_d, ok_d, "decode"),
+                (mode, used_p, ok_p, "prefill"),
+                (f"{mode}+cancel", cd, ok_d, "decode"),
+                (f"{mode}+cancel", cp, ok_p, "prefill")):
             escaped = sorted(used - ok, key=str)
             if escaped:
-                diags.append(Diagnostic(
-                    "PTRC002", "recompile", "error",
-                    f"[{mode}+cancel] mid-decode cancellations drove "
-                    f"{what} shape(s) {escaped} outside the AOT bucket "
-                    f"set {sorted(ok, key=str)} — cancel must be an "
-                    f"eviction, never a recompile",
-                    op=f"serving.{what}"))
+                error(f"[{tag}] serving {what} requested shape(s) "
+                      f"{escaped} outside the AOT bucket set "
+                      f"{sorted(ok, key=str)} — every such shape "
+                      f"retraces at serving time (cancel must be an "
+                      f"eviction, never a recompile); widen the bucket "
+                      f"config", what)
     rep = Report("serving.decode_buckets", diags)
     rep.emit()
     reports.append(rep)
@@ -330,32 +314,23 @@ def lint_serving(world_size=None, hbm_budget_gb=None):
         jax.ShapeDtypeStruct((C,), i32),
         name="serving.chunk_prefill"))
 
-    # the MoE decode program (fused Pallas dispatch inside) through the
-    # full pass suite: the fused path must lint clean — in particular
-    # the cost pass's PTCS004 fusion-opportunity diagnostic must NOT
-    # fire on it (a pallas_call IS the fused form)
-    from paddle_tpu.serving.moe_engine import moe_decode_step_fn
-    mpool = moe_eng.pool
-    mbucket = moe_eng.decode_buckets[-1]
-    mfn = _fuse(functools.partial(
-        moe_decode_step_fn, kinds=moe_eng.kinds,
-        eps=mcfg.layer_norm_eps, top_k=mcfg.top_k, temperature=0.0,
-        topk_sample=0, use_kernel=False, use_fused_moe=True),
-        label="serving.moe_decode_step")
+    # the block engine's pass program through the full pass suite (its
+    # reference paths, as above: every op modelable)
+    spool = sdar_eng.pool
+    sfn = _fuse(functools.partial(sdar_block_step_fn, cfg=scfg,
+                                  use_kernel=False),
+                label="serving.sdar_block_step")
 
-    def moe_decode(kp, vp, tokens, positions, table, lens):
-        a = [unwrap(t) for t in (kp, vp, tokens, positions, table, lens)]
-        return mfn(moe_eng.params, *a, None)
+    def block_step(kp, vp, state):
+        return sfn(sdar_eng.params, unwrap(kp), unwrap(vp), unwrap(state))
 
-    mkp = jax.ShapeDtypeStruct(mpool.k_pages.shape, mpool.k_pages.dtype)
+    skp = jax.ShapeDtypeStruct(spool.k_pages.shape, spool.k_pages.dtype)
     reports.append(ProgramAnalyzer(
         world_size=world_size, hbm_budget_gb=hbm_budget_gb).analyze(
-        moe_decode, mkp, mkp,
-        jax.ShapeDtypeStruct((mbucket,), i32),
-        jax.ShapeDtypeStruct((mbucket,), i32),
-        jax.ShapeDtypeStruct((mbucket, mpool.max_pages_per_seq), i32),
-        jax.ShapeDtypeStruct((mbucket,), i32),
-        name="serving.moe_decode_step"))
+        block_step, skp, skp,
+        jax.ShapeDtypeStruct(
+            (sdar_eng.decode_buckets[-1], sdar_eng._state_width), i32),
+        name="serving.sdar_block_step"))
     return reports
 
 
