@@ -21,8 +21,13 @@ module holds what GPT decides:
   outside the set raises instead of silently recompiling
   (``tools/check_program.py --model serving`` proves the scheduler never
   requests one);
-- ``decode()``: the host's part of a one-token tick (last tokens,
-  positions, page table, a sampling key);
+- ``decode()``: the host's part of a one-token tick. Everything the
+  program needs from the host crosses in ONE int32 array
+  (:func:`decode_packed_fn`: last token, length, the call counter and
+  the page-table row of every slot); the sampling key is the engine's
+  base key, resident on the decode device, folded with that counter
+  inside the program, so nothing runs eagerly on the device before the
+  launch;
 - the prefix cache's copy-on-write boundary page (``_alloc_prompt``) and
   live migration (``export_kv`` / ``begin_`` / ``commit_`` /
   ``abort_kv_import``).
@@ -62,8 +67,8 @@ from .engine_core import (EngineShapeError, PagedEngine, _write_rows,
                           smallest_bucket)
 
 __all__ = ["ServingEngine", "EngineShapeError", "decode_step_fn",
-           "prefill_fn", "chunk_prefill_fn", "prefill_kv_fn",
-           "scatter_kv_fn"]
+           "decode_packed_fn", "prefill_fn", "chunk_prefill_fn",
+           "prefill_kv_fn", "scatter_kv_fn"]
 
 
 # ---------------------------------------------------------------------------
@@ -177,6 +182,26 @@ def decode_step_fn(params, k_pages, v_pages, tokens, positions, page_table,
     logits = _mm("bsh,vh->bsv", h, wte, dt)[:, 0]
     nxt = sample_logits(logits, key, temperature, top_k).astype(jnp.int32)
     return k_pages, v_pages, nxt
+
+
+# columns of a decode tick's packed state ``int32[bucket, 3 + pages]``
+_TOKEN, _LEN, _CALL, _TABLE = 0, 1, 2, 3
+
+
+def decode_packed_fn(step, params, k_pages, v_pages, state, key):
+    """The decode program as the engine launches it: ``step`` is
+    :func:`decode_step_fn` with its static arguments bound, ``state``
+    ``int32[B, 3 + pages_per_seq]`` is all that crosses from the host in
+    a tick (a slot's last token, its ``seq_len``, the engine's call
+    counter as uint32 bits, its page-table row) and ``key`` the engine's
+    base key, which stays on the device. The position is ``seq_len - 1``
+    (an idle slot's is clamped to 0 by the step) and the tick's sampling
+    key ``fold_in(key, counter)``: the bits the host made eagerly
+    before."""
+    seq_lens = state[:, _LEN]
+    calls = jax.lax.bitcast_convert_type(state[0, _CALL], jnp.uint32)
+    return step(params, k_pages, v_pages, state[:, _TOKEN], seq_lens - 1,
+                state[:, _TABLE:], seq_lens, jax.random.fold_in(key, calls))
 
 
 def prefill_fn(params, k_pages, v_pages, ids, true_len, dest_rows, key, *,
@@ -444,6 +469,10 @@ class ServingEngine(PagedEngine):
             from ..analysis.rewrite import autofuse_enabled
             autofuse = autofuse_enabled()
         self.autofuse = bool(autofuse)
+        # the base key where the decode programs run: a resident
+        # argument, folded with the call counter inside the program
+        self._decode_key = jax.device_put(
+            self._key, next(iter(self.pool.k_pages.devices())))
         self._build_programs()
         if aot:
             self.compile_buckets()
@@ -485,9 +514,11 @@ class ServingEngine(PagedEngine):
         def flash(sb):
             return flash_attention_gate(sb, cfg.head_dim, self._use_flash)
         self._decode_jit = jax.jit(
-            _fuse(functools.partial(decode_step_fn,
-                                    use_kernel=self.use_kernel, **kw),
-                  "serving.decode_step"),
+            _fuse(functools.partial(
+                decode_packed_fn,
+                functools.partial(decode_step_fn,
+                                  use_kernel=self.use_kernel, **kw)),
+                "serving.decode_step"),
             donate_argnums=pools)
         self._prefill_jit = {
             sb: jax.jit(functools.partial(prefill_fn, use_flash=flash(sb),
@@ -537,16 +568,14 @@ class ServingEngine(PagedEngine):
         mode (no-op otherwise — default placement already matches)."""
         if not self.disaggregated:
             return jnp.asarray(x)
-        return jax.device_put(jnp.asarray(x), self._decode_device)
+        return jax.device_put(x, self._decode_device)
 
     def _key_aval(self):
         return self._aval(self._key.shape, self._key.dtype)
 
     def _decode_avals(self, b):
-        i32 = jnp.int32
-        return (self._aval((b,), i32), self._aval((b,), i32),
-                self._aval((b, self.pool.max_pages_per_seq), i32),
-                self._aval((b,), i32), self._key_aval())
+        return (self._aval((b, _TABLE + self.pool.max_pages_per_seq),
+                           jnp.int32), self._key_aval())
 
     def _chunk_extra_avals(self):
         return (self._key_aval(),)
@@ -808,20 +837,23 @@ class ServingEngine(PagedEngine):
             raise EngineShapeError(f"{n} sequences > bucket {bucket}")
         with RecordEvent("engine.decode", n=n, bucket=bucket,
                          in_flight=self._in_flight):
-            with RecordEvent("engine.host_prep"):
+            # h2d: host arrays sent in this call
+            with RecordEvent("engine.host_prep", h2d=1):
                 slots = list(seq_ids) + [None] * (bucket - n)
-                lens = self.pool.lens_array(slots)
-                table = self.pool.table_array(slots)
-                tokens = np.asarray(
-                    [self._last_token.get(sid, 0) for sid in slots],
+                self._calls += 1    # as _next_key(): one stream of keys
+                state = np.empty(
+                    (bucket, _TABLE + self.pool.max_pages_per_seq),
                     np.int32)
-                positions = np.maximum(lens - 1, 0).astype(np.int32)
-                args = [self._to_decode(x) for x in (
-                    tokens, positions, table, lens, self._next_key())]
+                state[:, _TOKEN] = [self._last_token.get(sid, 0)
+                                    for sid in slots]
+                state[:, _LEN] = self.pool.lens_array(slots)
+                state[:, _CALL] = np.uint32(self._calls).astype(np.int32)
+                state[:, _TABLE:] = self.pool.table_array(slots)
+                state = self._to_decode(state)
             with RecordEvent("engine.dispatch"):
                 kp, vp, nxt = self._decode_fn(bucket)(
                     self.params, self.pool.k_pages, self.pool.v_pages,
-                    *args)
+                    state, self._decode_key)
                 self.pool.bind(kp, vp)
             self._in_flight += 1
             with RecordEvent("engine.readback",

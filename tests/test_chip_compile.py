@@ -26,6 +26,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
 
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -179,9 +180,11 @@ def _serving_program(one_chip, what, pool_tokens):
     kw = dict(eps=cfg.layer_norm_epsilon, temperature=0.0, top_k=0,
               use_kernel=True, compute_dtype="bfloat16")
     if what == "decode":
-        fn = functools.partial(engine.decode_step_fn, **kw)
-        args = (sds((32,), I32), sds((32,), I32), sds((32, 32), I32),
-                sds((32,), I32))
+        # as the engine launches it: one packed int32 state, the base key
+        fn = functools.partial(
+            engine.decode_packed_fn,
+            functools.partial(engine.decode_step_fn, **kw))
+        args = (sds((32, 3 + 32), I32),)
     else:
         fn = functools.partial(engine.chunk_prefill_fn, **kw)
         args = (sds((1, 256), I32), sds((), I32), sds((), I32),
@@ -198,7 +201,16 @@ def test_serving_program_updates_pool_in_place(monkeypatch, one_chip,
     mem = exe.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes > 5 * _GIB
     assert mem.temp_size_in_bytes < _GIB
-    assert exe.as_text().count("tpu_custom_call") == 1
+    text = exe.as_text()
+    assert text.count("tpu_custom_call") == 1
+    # nothing of the pool's size is copied, cut, written back or filled
+    pool = re.escape("bf16[%s]" % ",".join(map(str, args[1].shape)))
+    pool_shaped = [ln for ln in text.splitlines() if re.search(
+        rf"= {pool}\S* (copy|dynamic-slice|dynamic-update-slice|broadcast)\(",
+        ln)]
+    assert pool_shaped == []
+    print(what, "arguments", mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes, "alias", mem.alias_size_in_bytes)
 
 
 def test_decode_program_compiles_with_49k_token_pool(monkeypatch, one_chip,

@@ -571,6 +571,10 @@ def test_train_step_checkpoint_roundtrip(tmp_path):
         step(x, y)
     mgr.wait()
     assert mgr.complete_steps() == [2, 4]   # interval-gated async saves
+    # steps 5 and 6 are what the resumed step must replay: no save of
+    # step 6, which would be the newest whenever it finished before the
+    # resume below (it raced it: one of PR 35's two whole runs failed here)
+    mgr.interval = 0
     loss_after_5 = float(step(x, y).numpy())
     loss_after_6 = float(step(x, y).numpy())
 
