@@ -69,7 +69,9 @@ def supported(q_shape, k_shape=None, v_shape=None, causal=False,
     lengths are handled by pad-to-block inside the wrapper (VERDICT r4
     weak #6), so the gate is about PROFIT, not correctness: sequences
     below half a block would be mostly padding and stay on XLA's fused
-    attention.
+    attention. q, k and v rows of one width only; and nothing here
+    counts layers, so a served model may hold fewer of them in the page
+    pool than it has.
     """
     if len(q_shape) != 4:
         return False

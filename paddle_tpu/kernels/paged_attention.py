@@ -119,9 +119,29 @@ def _layer_index(k_pages, layer):
     return jnp.asarray(0 if layer is None else layer, jnp.int32)
 
 
+def _check_widths(q, k_pages, v_pages):
+    """q, k and v rows are of one width here: the kernels' blocks, their
+    scores' lane reduce and their accumulators all take the pool's ``d``.
+    A caller whose score rows are narrower than its value rows (two score
+    heads of ``d / 2`` onto value rows of ``d``) lays its rows out at one
+    width itself: a pair of heads one head of the pool, and each query of
+    a pair a row of its own filled with zeros where the other's lanes are,
+    ``[q1 ; 0]`` and ``[0 ; q2]``."""
+    if not q.shape[-1] == k_pages.shape[-1] == v_pages.shape[-1] \
+            or k_pages.shape != v_pages.shape:
+        raise ValueError(
+            f"q rows of width {q.shape[-1]} against a pool of k "
+            f"{k_pages.shape} and v {v_pages.shape}: the paged kernels "
+            f"take q, k and v rows of one width (score rows narrower than "
+            f"the value rows go in as a pair of heads a pool row, with "
+            f"each query zero-filled to the pair's width)")
+
+
 def _pool_and_layer(k_pages, v_pages, layer):
     """The kernels' one view of a pool: rank 5 ``[L, P, ps, nkv, d]``
-    and the layer as a ``(1,)`` int32 for the scalar prefetch."""
+    and the layer as a ``(1,)`` int32 for the scalar prefetch. ``L`` is
+    the layers the pool holds, which a model may have more of (one full
+    attention layer of 32 writes the pool that eight layers read)."""
     layer = jnp.reshape(_layer_index(k_pages, layer), (1,))
     if k_pages.ndim == 4:
         k_pages, v_pages = k_pages[None], v_pages[None]
@@ -256,6 +276,7 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens,
     ``[B, num_heads, d]``.
     """
     B, nh, d = q.shape
+    _check_widths(q, k_pages, v_pages)
     k_pages, v_pages, layer = _pool_and_layer(k_pages, v_pages, layer)
     _, _, page_size, nkv, _ = k_pages.shape
     if nh % nkv:
@@ -308,6 +329,129 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens,
         )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), layer,
           q if grouped else q.swapaxes(1, 2), k_pages, v_pages)
     return (out if grouped else out.swapaxes(1, 2)).reshape(B, nh, d)
+
+
+def _decode_kernel_rows(pt_ref, sl_ref, ly_ref, q_ref, k_ref, v_ref, o_ref,
+                        m_scr, l_scr, acc_scr, *, page_size, scale):
+    """:func:`_decode_kernel_grouped` over a pool of **rows**: a page is
+    ``[page, nkv * d]``, a token's KV heads side by side on the lanes, so
+    a head's keys are a lane-aligned slice of a dense tile where the
+    ``[page, nkv, d]`` block hands the MXU a sublane gather (a head's row
+    out of every token's padded tile: at two heads of 640 that gather,
+    not the bytes, was the kernel's time on the chip). ``q`` and the
+    output are head-major, ``[nkv, g, d]`` a sequence."""
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    npg = pl.num_programs(1)
+    nkv, _, d = q_ref.shape[1:]
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    sl = sl_ref[b]
+
+    @pl.when(j * np.int32(page_size) < sl)
+    def _():
+        live = j * np.int32(page_size) + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1) < sl
+        # three passes over the heads, not one: every head's scores, then
+        # every head's softmax step, then every head's weighted values.
+        # The products of a pass do not wait for one another, so the MXUs
+        # work side by side (head by head, scores-softmax-values in a
+        # chain, the same kernel took 1.5 to 1.8 x as long on the chip)
+        scores = [jax.lax.dot_general(
+            q_ref[0, h], k_ref[0, :, h * d:(h + 1) * d],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            * jnp.float32(scale) for h in range(nkv)]   # [g, page_size]
+        weights = []
+        for h, s in enumerate(scores):
+            s = jnp.where(live, s, jnp.float32(_NEG_INF))
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            m_scr[h] = m_new
+            l_scr[h] = corr * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
+            weights.append((p, corr))
+        for h, (p, corr) in enumerate(weights):
+            v = v_ref[0, :, h * d:(h + 1) * d]          # [page_size, d]
+            acc_scr[h] = corr * acc_scr[h] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    @pl.when(j == npg - 1)
+    def _():
+        for h in range(nkv):
+            l = jnp.maximum(l_scr[h], jnp.float32(1e-30))
+            o_ref[0, h] = (acc_scr[h] / l).astype(o_ref.dtype)
+
+
+def paged_attention_decode_rows(q, k_rows, v_rows, page_table, seq_lens,
+                                scale=None, layer=0, name=None,
+                                use_kernel=True):
+    """:func:`paged_attention_decode` over a pool of rows.
+
+    ``k_rows``/``v_rows`` ``[num_layers, num_pages, page_size, nkv * d]``
+    (a token's KV heads side by side: what a model holds whose KV heads
+    are not a count the chip tiles without padding, ten of 128 say; a
+    dense ``[page, nkv * d]`` tile a page) read at ``layer``; ``q`` ``[B,
+    nkv, g, d]`` head-major, ``g`` query rows a KV head (a multiple of 16
+    on the chip: whole bf16 sublane tiles for the MXU; fill with zero
+    rows). ``page_table``, ``seq_lens`` and ``scale`` as there; ``name``
+    (static) is the call's name in the trace, for a program that reads
+    more than one cache through this kernel and a reader that has to
+    tell them apart. Returns ``[B, nkv, g, d]`` in **float32**, the
+    accumulator as it stands: the caller this body serves subtracts one
+    head's output from another's, and two nearly equal rows rounded to
+    the served type first lose what their difference keeps.
+    ``use_kernel=False`` is the XLA reference (the pool's rows cut into
+    heads, then :func:`paged_attention_reference`)."""
+    B, nkv, g, d = q.shape
+    L, _, page_size, width = k_rows.shape
+    if width != nkv * d or k_rows.shape != v_rows.shape:
+        raise ValueError(
+            f"q of {nkv} heads of {d} against pool rows of k {k_rows.shape} "
+            f"and v {v_rows.shape}: a row holds the KV heads side by side, "
+            f"q, k and v rows of one width a head")
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    if not use_kernel:
+        heads = lambda a: a.reshape(*a.shape[:3], nkv, d)
+        out = paged_attention_reference(
+            q.reshape(B, nkv * g, d).astype(jnp.float32), heads(k_rows),
+            heads(v_rows), page_table, seq_lens, scale=scale, layer=layer)
+        return out.reshape(q.shape)
+    interpret = _interpret()
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+    with x64_off(interpret):
+        q_block = pl.BlockSpec((1, nkv, g, d),
+                               lambda b, j, pt, sl, ly: (b, 0, 0, 0))
+        kv_block = pl.BlockSpec(
+            (None, 1, page_size, width),
+            lambda b, j, pt, sl, ly: (ly[0], pt[b, j], 0, 0))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, page_table.shape[1]),
+            in_specs=[q_block, kv_block, kv_block],
+            out_specs=q_block,
+            scratch_shapes=[
+                pltpu.VMEM((nkv, g, 1), jnp.float32),
+                pltpu.VMEM((nkv, g, 1), jnp.float32),
+                pltpu.VMEM((nkv, g, d), jnp.float32),
+            ],
+        )
+        return pl.pallas_call(
+            functools.partial(_decode_kernel_rows, page_size=page_size,
+                              scale=float(scale)),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+            compiler_params=_ARB2,
+            interpret=interpret,
+            name=name or "paged_attention_decode_rows",
+        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), layer,
+          q, k_rows, v_rows)
 
 
 def _prefill_kernel(pt_ref, off_ref, ly_ref, q_ref, k_ref, v_ref, o_ref,
@@ -399,6 +543,7 @@ def ragged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
     ``j`` iff ``j // block <= i // block``; 1 is the plain causal rule.
     """
     B, C, nh, d = q.shape
+    _check_widths(q, k_pages, v_pages)
     k_pages, v_pages, layer = _pool_and_layer(k_pages, v_pages, layer)
     _, _, ps, nkv, _ = k_pages.shape
     if nh % nkv:

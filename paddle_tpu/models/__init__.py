@@ -21,3 +21,14 @@ from .ernie import (  # noqa: F401
 from .sdar import (  # noqa: F401
     SdarMoeConfig, sdar_moe_tiny_config, init_sdar_weights,
 )
+
+# Phi-4-mini-flash is found on first use (see serving/__init__.py)
+_LAZY = ("Phi4FlashConfig", "phi4flash_tiny_config",
+         "init_phi4flash_weights")
+
+
+def __getattr__(name):
+    if name in _LAZY:
+        from . import phi4flash
+        return getattr(phi4flash, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -138,10 +138,25 @@ from .scheduler import (ContinuousBatchingScheduler,  # noqa: F401
 from .router import PrefixAffinityRouter, SLOAutoscaler  # noqa: F401
 from .fleet import FleetError, FleetRouter, ReplicaHandle  # noqa: F401
 
+# the hybrid engine (a state pool beside the page pool) is found on first
+# use: `import paddle_tpu` is 11-15 s of every run's set-up, and no run
+# that serves another model should pay for this one's modules
+_LAZY = {"Phi4FlashServingEngine": ".phi4flash_engine",
+         "StatePool": ".state_pool", "StatePoolFull": ".state_pool"}
+
+
+def __getattr__(name):
+    if name in _LAZY:
+        from importlib import import_module
+        return getattr(import_module(_LAZY[name], __name__), name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
 __all__ = [
     "PagePool", "PagePoolError", "PagePoolOOM",
     "EngineContract", "PagedEngine", "EngineShapeError",
-    "ServingEngine", "SdarServingEngine",
+    "ServingEngine", "SdarServingEngine", "Phi4FlashServingEngine",
+    "StatePool", "StatePoolFull",
     "PrefixCache", "ContinuousBatchingScheduler", "Request",
     "MigrationUnsupported",
     "simulate_decode_signatures", "make_shared_prefix_workload",

@@ -580,7 +580,7 @@ class ServingEngine(PagedEngine):
     def _chunk_extra_avals(self):
         return (self._key_aval(),)
 
-    def _chunk_extra_args(self):
+    def _chunk_extra_args(self, seq_id, final):
         return (self._next_key(),)
 
     def _compile_more(self, params_avals, kp):

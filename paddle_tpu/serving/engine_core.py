@@ -24,8 +24,10 @@ tables, so that ``_build_programs(); compile_buckets()`` re-makes every
 program from the module's functions as they stand. It gives the avals of
 a decode call at a bucket (``_decode_avals``) and what its chunk program
 takes after the common five arguments (``_chunk_extra_avals`` /
-``_chunk_extra_args``: GPT's sampling key), says what a prompt's last
-chunk reads back (``_chunk_read``: GPT the first token, SDAR the pending
+``_chunk_extra_args``: GPT's sampling key; a model with state beside
+the pages passes its donated state arrays there, gets them back in what
+the program returns after the pools and rebinds them in
+``_chunk_issued``), says what a prompt's last chunk reads back (``_chunk_read``: GPT the first token, SDAR the pending
 expert counts), forgets its per-sequence state on release (``_forget``),
 and owns ``decode()``: the host's part of a tick is model-shaped.
 ``_alloc_prompt`` here maps whole cached pages only, which is exact for
@@ -153,7 +155,8 @@ class PagedEngine(EngineContract):
 
     def __init__(self, params, *, num_layers, num_kv_heads, head_dim, dtype,
                  max_positions, page_size, num_pages, max_seq_len,
-                 decode_buckets, prefill_chunk, prefix_cache):
+                 decode_buckets, prefill_chunk, prefix_cache,
+                 flat_rows=False):
         max_seq_len = int(max_seq_len or max_positions)
         if max_seq_len > max_positions:
             raise ValueError(f"max_seq_len {max_seq_len} exceeds the "
@@ -176,7 +179,8 @@ class PagedEngine(EngineContract):
                 max_seq_len / page_size) + 1
         self.pool = PagePool(num_pages, page_size, num_layers=num_layers,
                              num_kv_heads=num_kv_heads, head_dim=head_dim,
-                             dtype=dtype, max_seq_len=max_seq_len)
+                             dtype=dtype, max_seq_len=max_seq_len,
+                             flat_rows=flat_rows)
         # the weights live with the pool, where the programs run: a
         # model built on another backend (host-side init) would
         # otherwise cross to the device again on every call
@@ -207,8 +211,15 @@ class PagedEngine(EngineContract):
         chunk_len, page_table, dest_rows)``."""
         return ()
 
-    def _chunk_extra_args(self) -> tuple:
+    def _chunk_extra_args(self, seq_id, final) -> tuple:
+        """What a chunk of ``seq_id`` (its last where ``final``) passes
+        after the common five: arrays an adapter keeps beside the pool
+        ride here (donated by its jit, rebound in :meth:`_chunk_issued`)."""
         return ()
+
+    def _chunk_attrs(self, final) -> dict:
+        """Further attributes of a chunk's ``engine.prefill_step`` span."""
+        return {}
 
     def _compile_more(self, params_avals, kp):
         """The adapter's other serving-time programs."""
@@ -355,7 +366,8 @@ class PagedEngine(EngineContract):
         clen = min(C, n - start)
         final = start + clen >= n
         with RecordEvent("engine.prefill_step", rid=seq_id, start=start,
-                         clen=clen, final=final, in_flight=self._in_flight):
+                         clen=clen, final=final, in_flight=self._in_flight,
+                         **self._chunk_attrs(final)):
             with RecordEvent("engine.host_prep"):
                 ids = np.zeros((1, C), np.int32)
                 ids[0, :clen] = st["prompt"][start:start + clen]
@@ -365,7 +377,8 @@ class PagedEngine(EngineContract):
                     else self._chunk_jit
                 args = (jnp.asarray(ids), jnp.asarray(np.int32(start)),
                         jnp.asarray(np.int32(clen)), jnp.asarray(table),
-                        jnp.asarray(rows)) + self._chunk_extra_args()
+                        jnp.asarray(rows)) \
+                    + self._chunk_extra_args(seq_id, final)
             with RecordEvent("engine.dispatch"):
                 kp, vp, out = fn(self.params, self.pool.k_pages,
                                  self.pool.v_pages, *args)

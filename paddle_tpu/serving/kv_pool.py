@@ -6,7 +6,12 @@ num_kv_heads, head_dim]`` — and the host-side bookkeeping that maps
 sequences onto them: a free list and one page table (list of page ids)
 per live sequence. Live HBM therefore tracks *actual tokens* (rounded up
 to the page), not ``max_position_embeddings`` — the vLLM/"Ragged Paged
-Attention" scheme.
+Attention" scheme. One table serves all ``num_layers`` of the pool, and
+those are the layers whose cache grows with the sequence, which a model
+may have fewer of than it has layers: the hybrid engine
+(:mod:`.phi4flash_engine`) builds the pool with ``num_layers=1`` for a
+model of 32, whose window and state-space layers keep a slot of constant
+size in the :class:`~.state_pool.StatePool` beside it.
 
 Page 0 is the reserved **sink** page: padding page-table entries and
 padded prefill rows scatter into it, so every gather/scatter index the
@@ -65,7 +70,8 @@ class PagePool:
     SINK = 0  # reserved padding/garbage page, never allocated
 
     def __init__(self, num_pages, page_size, num_layers, num_kv_heads,
-                 head_dim, dtype="float32", max_seq_len=None):
+                 head_dim, dtype="float32", max_seq_len=None,
+                 flat_rows=False):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (one is the sink)")
         if page_size < 1:
@@ -81,8 +87,12 @@ class PagePool:
         # page-table operand is static, only the batch bucket varies
         self.max_pages_per_seq = max(
             1, math.ceil(self.max_seq_len / self.page_size))
-        shape = (self.num_layers, self.num_pages, self.page_size,
-                 self.num_kv_heads, self.head_dim)
+        # flat_rows: a token's KV heads side by side, ``[L, P, ps, nkv *
+        # d]`` (``paged_attention_decode_rows``): for a head count the
+        # chip would pad as a second-minor axis (ten heads to sixteen)
+        shape = (self.num_layers, self.num_pages, self.page_size) + (
+            (self.num_kv_heads * self.head_dim,) if flat_rows
+            else (self.num_kv_heads, self.head_dim))
         self.k_pages = jnp.zeros(shape, dtype=dtype)
         self.v_pages = jnp.zeros(shape, dtype=dtype)
         # internal lock: every bookkeeping mutator/reader below runs

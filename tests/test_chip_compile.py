@@ -34,7 +34,8 @@ from jax.sharding import SingleDeviceSharding
 
 import paddle_tpu  # noqa: F401  (x64 on, as every user of the kernels has it)
 from paddle_tpu.kernels import (flash_attention, grouped_matmul, int8_matmul,
-                                moe_dispatch, paged_attention)
+                                moe_dispatch, paged_attention,
+                                selective_scan)
 
 
 @pytest.fixture(scope="module")
@@ -272,6 +273,94 @@ def test_sdar_program_in_place_and_no_expert_slice(monkeypatch, one_chip,
                and ln.lstrip().startswith("%grouped_ragged-dot")
                for ln in text.splitlines()) == 2
     assert "ragged-dot-metadata" not in text and " ragged-dot(" not in text
+
+
+def _phi4_program(one_chip, what, slots=128):
+    """The hybrid engine's programs at the benchmark's sizes: 128 slots,
+    a page pool of 393,216 tokens (one layer, pages of 128 rows of 1,280),
+    a table of 48 pages, chunks of 256."""
+    from paddle_tpu.models import phi4flash
+    from paddle_tpu.serving import phi4flash_engine
+    cfg = phi4flash.Phi4FlashConfig()
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        sds, phi4flash.phi4flash_weight_shapes(cfg),
+        is_leaf=lambda x: isinstance(x, tuple))
+    pool = sds((1, 3073, 128, 1280))
+    ring = sds((8, (slots + 1) * 4, 128, 1280))
+    state = (sds((9, slots + 1, 16, 5120), F32), sds((9, slots + 1, 3, 5120)),
+             ring, ring)
+    carried = 2 * 2 * math.prod(pool.shape) + sum(
+        math.prod(a.shape) * a.dtype.itemsize for a in state)
+    if what.startswith("decode"):
+        fn = functools.partial(phi4flash_engine.phi4flash_decode_fn, cfg=cfg)
+        args = (params, pool, pool) + state \
+            + (sds((int(what[6:]), 3 + 48), I32),)
+        donate = (1, 2, 3, 4, 5, 6)
+    else:
+        fn = functools.partial(phi4flash_engine.phi4flash_chunk_fn, cfg=cfg)
+        args = (params, pool, pool, sds((1, 256), I32), sds((), I32),
+                sds((), I32), sds((1, 48), I32), sds((256,), I32)) + state \
+            + (sds((3,), I32),)
+        donate = (1, 2, 8, 9, 10, 11)
+    return fn, args, donate, carried
+
+
+@pytest.mark.parametrize("what,temp_gib", [("decode128", 0.25),
+                                           ("chunk", 0.1), ("decode1", 0.1)])
+def test_phi4flash_program_updates_pool_and_state_in_place(
+        monkeypatch, one_chip, no_compile_cache, what, temp_gib):
+    """Whole, for the described v5e: the weights (7.18 GiB), the page
+    pool and the four state arrays fit beside the temporaries; every
+    carried array is aliased; the kernels are the decode kernel over rows
+    under the engine's names (a window layer's rings in the loop; the
+    full layer and the cross layers' loop) and, in the chunk program, the
+    scan (the pairs' loop, the memory layer), the full layer's attention
+    with a position a row, and the cross-decoder's kernel."""
+    fn, args, donate, carried = _phi4_program(one_chip, what)
+    monkeypatch.setattr(selective_scan, "_interpret", lambda: False)
+    exe = _compiled(monkeypatch, paged_attention, fn, *args, donate=donate)
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= carried > 4.7 * _GIB
+    assert 11.9 * _GIB < mem.argument_size_in_bytes < 12.0 * _GIB
+    assert mem.temp_size_in_bytes < temp_gib * _GIB
+    text = exe.as_text()
+    calls = [ln.lstrip().split(" ", 1)[0] for ln in text.splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == (4 if what == "chunk" else 3)
+    named = lambda name: sum(c.startswith("%" + name) for c in calls)
+    if what == "chunk":
+        assert named("selective_scan_chunk") == 2
+        assert named("shared_kv_attention_chunk") == 1
+        assert named("shared_kv_attention_last") == 1
+    else:
+        assert named("window_attention_decode") == 1
+        assert named("shared_kv_attention_decode") == 2
+
+
+@pytest.mark.parametrize("ps", [64, 128])
+def test_paged_decode_over_rows(monkeypatch, one_chip, no_compile_cache, ps):
+    """The decode kernel over a pool of rows at the hybrid engine's
+    widths: ten heads of 128 side by side, sixteen query rows a head."""
+    def decode(q, kr, vr, table, lens, layer):
+        return paged_attention.paged_attention_decode_rows(
+            q, kr, vr, table, lens, scale=0.125, layer=layer)
+    assert _compile(monkeypatch, paged_attention, one_chip, decode,
+                    ((128, 10, 16, 128), BF16), ((8, 1032, ps, 1280), BF16),
+                    ((8, 1032, ps, 1280), BF16), ((128, 512 // ps), I32),
+                    ((128,), I32), ((), I32)) == 1
+
+
+def test_selective_scan_chunk(monkeypatch, one_chip, no_compile_cache):
+    """The chunk's recurrence at the published widths: 256 positions,
+    5,120 channels, 16 states."""
+    assert _compile(
+        monkeypatch, selective_scan, one_chip,
+        selective_scan.selective_scan_chunk,
+        ((256, 5120), BF16), ((256, 5120), F32), ((16, 5120), F32),
+        ((256, 16), F32), ((256, 16), F32), ((16, 5120), F32)) == 1
 
 
 @pytest.mark.parametrize("m", [32, 1024, 2048])

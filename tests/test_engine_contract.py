@@ -3,9 +3,10 @@
 ``serving/engine_core.py`` states once what the scheduler may rely on
 (``EngineContract``) and what every paged engine does (``PagedEngine``).
 Each case below runs for the GPT engine as the cells deploy it (chunked,
-prefix cache), for SDAR's block engine, and, where the one-shot prefill
-has something of its own to say, for the GPT engine without a chunk
-program. Tiny configs, the kernels' reference paths: the contract is the
+prefix cache), for SDAR's block engine, for the hybrid Phi-4-flash engine
+(a state pool beside the pages, no prefix cache: the cases about cached
+pages leave it out), and, where the one-shot prefill has something of its
+own to say, for the GPT engine without a chunk program. Tiny configs, the kernels' reference paths: the contract is the
 host's.
 """
 import collections
@@ -15,15 +16,17 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.models import sdar
+from paddle_tpu.models import phi4flash, sdar
 from paddle_tpu.serving import (ContinuousBatchingScheduler, EngineContract,
                                 EngineShapeError, PagedEngine,
                                 SdarServingEngine, ServingEngine,
                                 simulate_decode_signatures)
-from paddle_tpu.serving import engine as gpt_engine, sdar_engine
+from paddle_tpu.serving import (engine as gpt_engine, phi4flash_engine,
+                                sdar_engine)
 from paddle_tpu.serving.scheduler import _ShapeProbeEngine
 
 SDAR_CFG = sdar.sdar_moe_tiny_config()
+PHI4_CFG = phi4flash.phi4flash_tiny_config()
 MAX_LEN = 128
 COMMON_KEYS = {"decode_buckets", "prefill_chunk", "block_len", "pool",
                "compute_dtype", "weights_mb", "max_seq_len", "compile_s",
@@ -45,7 +48,12 @@ def sdar_weights():
 
 
 @pytest.fixture(scope="module")
-def build(gpt_model, sdar_weights):
+def phi4_weights():
+    return phi4flash.init_phi4flash_weights(PHI4_CFG, 17)
+
+
+@pytest.fixture(scope="module")
+def build(gpt_model, sdar_weights, phi4_weights):
     """``build(kind, **overrides)``: a new engine of that kind."""
     model, cfg = gpt_model
 
@@ -60,6 +68,12 @@ def build(gpt_model, sdar_weights):
             base.update(num_pages=64, max_seq_len=MAX_LEN)
             base.update(kw)
             return SdarServingEngine(sdar_weights, SDAR_CFG, **base)
+        if kind == "phi4":
+            base.update(num_pages=64, max_seq_len=MAX_LEN,
+                        prefix_cache=False)
+            base.update(kw)
+            return phi4flash_engine.Phi4FlashServingEngine(
+                phi4_weights, PHI4_CFG, **base)
         base.update(autofuse=False)
         base.update(kw)
         return ServingEngine(model, cfg, **base)
@@ -95,6 +109,8 @@ def _emptied(eng):
     assert eng.pool.live_sequences == 0
     assert eng.pool.pages_in_use == 0
     assert not eng._chunk_state
+    if isinstance(eng, phi4flash_engine.Phi4FlashServingEngine):
+        assert eng.state.slots_in_use == 0
     return eng
 
 
@@ -103,8 +119,9 @@ def _n_programs(eng):
         + len(getattr(eng, "_prefill_exe", ()))
 
 
-ALL = ["gpt", "gpt-oneshot", "sdar"]
-CHUNKED = ["gpt", "sdar"]
+ALL = ["gpt", "gpt-oneshot", "sdar", "phi4"]
+CHUNKED = ["gpt", "sdar"]           # chunked, with the prefix cache
+PAGED = CHUNKED + ["phi4"]          # chunked, with or without it
 
 
 # ------------------------------------------------------- shapes refused
@@ -191,15 +208,15 @@ jax.monitoring.register_event_duration_secs_listener(
     lambda event, *_a, **_k: _EVENTS.update([event.rsplit("/", 1)[-1]]))
 
 
-@pytest.mark.parametrize("kind", CHUNKED)
+@pytest.mark.parametrize("kind", PAGED)
 def test_construction_lowers_and_compiles_the_bucket_set_and_no_more(
         build, kind):
     """With the cells' options (a chunk, the prefix cache, the kernels,
     auto-fusion as the environment has it): construction lowers and
     compiles nothing, and ``compile_buckets()`` one program a decode
     bucket, the chunk program and GPT's page-copy program."""
-    cells = dict(use_kernel=True) if kind == "sdar" else \
-        dict(use_kernel=True, autofuse=None)
+    cells = dict(use_kernel=True, autofuse=None) if kind == "gpt" else \
+        dict(use_kernel=True)
     build(kind, **cells)                # the eager ops' own programs
     _EVENTS.clear()
     eng = build(kind, **cells)
@@ -227,8 +244,10 @@ class _Recorded:
             name, jitted, log, reads
 
     def lower(self, *avals):
-        key = (self.name, avals[3].shape[0] if self.name == "decode"
-               else None)
+        # the tick's packed int32 state: the first integer array after
+        # the pools (the hybrid engine's state arrays come before it)
+        key = (self.name, next(a for a in avals[3:] if a.dtype == np.int32)
+               .shape[0] if self.name == "decode" else None)
         self.log.append(key)
         exe = self.jitted.lower(*avals).compile()
         rec = self
@@ -244,7 +263,7 @@ class _Recorded:
         return Lowered()
 
 
-@pytest.mark.parametrize("kind", CHUNKED)
+@pytest.mark.parametrize("kind", PAGED)
 def test_compile_buckets_keeps_its_order_and_reads_memory_once(build, kind):
     """Decode buckets ascending, then the chunk program, then GPT's
     page-copy program; the memory of every pool-carrying program is read
@@ -267,12 +286,17 @@ def test_compile_buckets_keeps_its_order_and_reads_memory_once(build, kind):
 
 # --------------------------------------------------------------- status
 
-@pytest.mark.parametrize("kind", CHUNKED)
+@pytest.mark.parametrize("kind", PAGED)
 def test_status_carries_the_common_keys_and_every_programs_memory(
         compiled, build, kind):
     eng = compiled(kind)
     st = eng.status()
-    assert COMMON_KEYS | {"prefix_cache"} <= set(st)
+    assert COMMON_KEYS <= set(st)
+    assert ("prefix_cache" in st) == (kind in CHUNKED)
+    if kind == "phi4":
+        assert {"state", "cache_bytes_per_token",
+                "state_bytes_per_slot"} <= set(st)
+        assert st["program_memory"]["state_bytes"] == eng.state.nbytes
     assert st["block_len"] == eng.block_len
     assert st["decode_buckets"] == list(eng.decode_buckets)
     assert st["aot_programs"] >= len(eng.decode_buckets) + 1
@@ -310,7 +334,7 @@ def test_release_publishes_whole_pages_that_the_next_prefill_hits(
     _emptied(eng)
 
 
-@pytest.mark.parametrize("kind", CHUNKED)
+@pytest.mark.parametrize("kind", PAGED)
 def test_release_without_a_cache_frees_every_page(build, kind):
     eng = build(kind, prefix_cache=False)
     assert eng.prefix_cache is None and eng.reclaim_cache_pages(4) == 0
@@ -321,6 +345,7 @@ def test_release_without_a_cache_frees_every_page(build, kind):
     eng.release(1)
     assert eng.pool.pages_in_use == 0 and not eng._chunk_state
     assert eng.pool.free_pages == eng.pool.num_pages - 1
+    _emptied(eng)
 
 
 @pytest.mark.parametrize("kind", CHUNKED)
@@ -342,7 +367,7 @@ def test_reclaim_cache_pages_returns_pages_under_pressure(compiled, kind):
     _emptied(eng)
 
 
-@pytest.mark.parametrize("kind", CHUNKED)
+@pytest.mark.parametrize("kind", PAGED)
 def test_a_request_cancelled_mid_prefill_leaves_no_state_and_no_page(
         compiled, kind):
     eng = compiled(kind)
@@ -395,7 +420,7 @@ def test_scheduler_reads_every_declared_attribute(build, kind):
 
 # ----------------------------------- programs follow their module's names
 
-@pytest.mark.parametrize("kind", CHUNKED)
+@pytest.mark.parametrize("kind", PAGED)
 def test_rebuilt_programs_are_made_from_the_modules_functions(
         monkeypatch, build, kind):
     """``_build_programs()`` re-makes the jitted programs from the step
@@ -404,7 +429,9 @@ def test_rebuilt_programs_are_made_from_the_modules_functions(
     module, names = {
         "gpt": (gpt_engine, ("decode_step_fn", "chunk_prefill_fn")),
         "sdar": (sdar_engine, ("sdar_block_step_fn",
-                               "sdar_chunk_prefill_fn"))}[kind]
+                               "sdar_chunk_prefill_fn")),
+        "phi4": (phi4flash_engine, ("phi4flash_decode_fn",
+                                    "phi4flash_chunk_fn"))}[kind]
     eng = build(kind, decode_buckets=(1,))
     traced = []
 
