@@ -36,6 +36,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -45,6 +46,10 @@ MIN_BLOCK = 128
 MAX_BLOCK = 512
 _LANE = 128
 _NEG_INF = -1e30
+# names of the forward's residuals that a checkpoint policy may save
+# (``models/gpt.py``'s ``remat="dots"``)
+FLASH_OUT = "flash_attention_out"
+FLASH_LSE = "flash_attention_lse"
 
 
 def _pick_block(s_len):
@@ -398,8 +403,22 @@ def _flash(q, k, v, causal, scale, g, kv_len, q_offset):
 
 
 def _flash_fwd(q, k, v, causal, scale, g, kv_len, q_offset):
+    """The output ``o`` and the row statistics ``lse`` (float32) are
+    named ``FLASH_OUT`` and ``FLASH_LSE``: only the forward kernel makes
+    them, so a ``jax.checkpoint`` whose policy saves the names hands them
+    to the backward kernels instead of running this kernel again. q, k, v
+    are left unnamed: the caller recomputes them cheaply (a slice, a
+    transpose, a pad). Outside such a checkpoint a name is the identity.
+
+    ``o`` is named as its bits: the caller's forward reads ``o`` too, and
+    a float residual that the forward reads gets a ``reduce_precision``
+    from ``jax.checkpoint``, which the chip's compiler keeps as a pass
+    over ``o`` and a copy. The bitcasts cost nothing."""
     o, lse = _fwd(q, k, v, causal, scale, g, kv_len, q_offset)
-    return o, (q, k, v, o, lse)
+    bits = jnp.dtype(f"int{8 * o.dtype.itemsize}")
+    o = jax.lax.bitcast_convert_type(checkpoint_name(
+        jax.lax.bitcast_convert_type(o, bits), FLASH_OUT), o.dtype)
+    return o, (q, k, v, o, checkpoint_name(lse, FLASH_LSE))
 
 
 _flash.defvjp(_flash_fwd, _bwd)

@@ -39,6 +39,7 @@ from ..nn import functional as F
 from ..nn import initializer as I
 from ..framework.tensor import Tensor, Parameter
 from ..framework import random as random_mod
+from ..kernels.flash_attention import FLASH_LSE, FLASH_OUT
 from ..ops._dispatch import apply, unwrap
 from ..profiler.utils import RecordEvent
 
@@ -436,6 +437,51 @@ _STACK_SPECS = {
 }
 
 
+# remat="dots": a block's backward reads every matmul's output and the flash
+# kernel's output and row statistics (named in kernels/flash_attention.py)
+# from the forward, and recomputes only the elementwise and norm glue: the
+# forward kernel runs once a layer, as without a checkpoint
+_DOTS_POLICY = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names(FLASH_OUT, FLASH_LSE))
+
+
+def _kernel_calls(jaxpr, names):
+    """{name: calls} of the ``pallas_call``s in ``jaxpr`` named in
+    ``names``, each times the trip counts of the scans around it; of a
+    ``cond``'s branches the one that calls the most."""
+    from ..analysis.passes.cost import _pallas_call_name, _sub_jaxprs
+    counts = dict.fromkeys(names, 0)
+    for eqn in jaxpr.eqns:
+        prim = eqn.primitive.name
+        if prim == "pallas_call":          # its params hold the kernel body
+            name = _pallas_call_name(eqn)
+            if name in counts:
+                counts[name] += 1
+            continue
+        subs = [_kernel_calls(j, names) for j in _sub_jaxprs(eqn.params)]
+        if not subs:
+            continue
+        times = eqn.params["length"] if prim == "scan" else 1
+        join = max if prim == "cond" else sum
+        for n in names:
+            counts[n] += times * join(c[n] for c in subs)
+    return counts
+
+
+def flash_fwd_per_layer(jaxpr):
+    """Forward flash-kernel calls a layer in a train step's jaxpr: the
+    calls named ``flash_attention_fwd`` over those named
+    ``flash_attention_bwd_dq``, which a layer's backward calls once. 1
+    where the backward reads the kernel's saved output, 2 where it runs
+    the kernel again (1F1B re-derives a stage from its input: one more);
+    0 where the block calls no kernel."""
+    c = _kernel_calls(getattr(jaxpr, "jaxpr", jaxpr),
+                      ("flash_attention_fwd", "flash_attention_bwd_dq"))
+    fwd, bwd = c["flash_attention_fwd"], c["flash_attention_bwd_dq"]
+    return fwd / bwd if bwd else 0
+
+
 def gpt_stacked_param_shapes(config: GPTConfig):
     """Shapes of the stacked train-step pytree — the single source of
     truth shared by the buffer path (asserted) and the abstract
@@ -524,6 +570,9 @@ class GPTHybridTrainStep:
         }
         self._compiled = None
         self._t = 0
+        # forward flash-kernel calls a layer in the step's program, read
+        # from its jaxpr at the first call (flash_fwd_per_layer)
+        self.flash_fwd_per_layer = None
         # opt-in static lint at first call (analysis pkg); the compiled
         # schedule itself is SPMD-by-construction — the lint covers the
         # eager model the stacked params came from
@@ -697,13 +746,12 @@ class GPTHybridTrainStep:
             blk = lambda p, xx: gpt_block(p, xx, eps, mp_axis="mp",
                                           use_flash=use_flash)
             if remat == "dots":
-                # selective remat: save matmul outputs, recompute only the
+                # selective remat: save matmul outputs and the flash
+                # kernel's output and row statistics, recompute only the
                 # elementwise/norm glue — trades a little memory for much
                 # less recompute than full per-block checkpointing
-                blk = jax.checkpoint(
-                    blk, prevent_cse=False,
-                    policy=jax.checkpoint_policies
-                    .dots_with_no_batch_dims_saveable)
+                blk = jax.checkpoint(blk, prevent_cse=False,
+                                     policy=_DOTS_POLICY)
             elif remat:
                 # prevent_cse=False: inside lax.scan the loop structure
                 # already prevents the unwanted CSE; the default True makes
@@ -952,10 +1000,8 @@ class GPTHybridTrainStep:
             # stage's backward fits; see tools/mem_probe.py for measured
             # numbers per schedule × n_micro × remat.
             if remat == "dots":
-                blk = jax.checkpoint(
-                    blk, prevent_cse=False,
-                    policy=jax.checkpoint_policies
-                    .dots_with_no_batch_dims_saveable)
+                blk = jax.checkpoint(blk, prevent_cse=False,
+                                     policy=_DOTS_POLICY)
             elif remat:
                 blk = jax.checkpoint(blk, prevent_cse=False)
 
@@ -1126,8 +1172,10 @@ class GPTHybridTrainStep:
     def __call__(self, input_ids, labels):
         with RecordEvent("train.step", "Operator",
                          annotation=jax.profiler.StepTraceAnnotation,
-                         step_num=self._t + 1):
-            return self._step(input_ids, labels)
+                         step_num=self._t + 1) as span:
+            loss = self._step(input_ids, labels)
+            span.set(flash_fwd_per_layer=self.flash_fwd_per_layer)
+            return loss
 
     def _step(self, input_ids, labels):
         import time as _time
@@ -1173,6 +1221,11 @@ class GPTHybridTrainStep:
             lr_val = lr_src
         lr = jnp.asarray(lr_val, jnp.float32)
         t = jnp.asarray(self._t, jnp.float32)
+        if first_call:
+            # the trace the call below reuses (jit caches it)
+            self.flash_fwd_per_layer = flash_fwd_per_layer(
+                self._compiled.trace(self.params, self.opt_state, ids, labs,
+                                     lr, t).jaxpr)
         # the compiled call until it returns: the host's time to hand the
         # step to the device, not the step's device time
         with RecordEvent("GPTHybridTrainStep.step", "Operator"):

@@ -16,7 +16,9 @@ own ``_interpret`` gate — the program has no option for it.
 Beside the kernels: the two serving programs that carry the KV pool
 through their layer loop, whole and at the benchmark's size, for what
 only the compiler can say of them — that the pool is aliased and the
-temporaries are not a second pool (three compiles of a few seconds).
+temporaries are not a second pool (three compiles of a few seconds);
+and the benchmark's two train steps, whole, for how many kernels they
+hold (about ten seconds each).
 """
 import os
 
@@ -104,6 +106,37 @@ def test_flash_fwd_bwd(monkeypatch, one_chip, no_compile_cache):
     assert _compile(monkeypatch, flash_attention, one_chip,
                     jax.grad(loss, argnums=(0, 1, 2)),
                     _FLASH, _FLASH, _FLASH) == 3
+
+
+# The benchmark's two train steps, whole, under remat="dots": the backward
+# reads the flash forward's saved output and row statistics, so a program
+# holds three kernels, not four (the forward again inside the backward
+# loop). The 345M step's temporaries hold that output, lane-padded, for
+# 24 layers (12.66 GiB before, 13.81 with it; B14 no longer fits).
+@pytest.mark.parametrize("config,dp,mp,batch,seq,temp_gib", [
+    ("gpt_345m_config", 1, 1, 12, 1024, 14.5),
+    ("gpt_1p3b_config", 2, 2, 8, 2048, 9.0)])
+def test_train_step_runs_the_flash_forward_once(
+        monkeypatch, topo, no_compile_cache, config, dp, mp, batch, seq,
+        temp_gib):
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.models import gpt
+    monkeypatch.setattr(mesh_mod, "_global_mesh", mesh_mod._global_mesh)
+    monkeypatch.setattr(mesh_mod, "_hcg", mesh_mod._hcg)
+    hcg = mesh_mod.HybridCommunicateGroup(
+        dp_degree=dp, mp_degree=mp,
+        mesh=mesh_mod.build_mesh(dp=dp, mp=mp, devices=topo.devices))
+    step = gpt.GPTHybridTrainStep.abstract(
+        getattr(gpt, config)(), hcg, remat="dots", compute_dtype="bfloat16",
+        use_flash=True)
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    with jax.default_matmul_precision("default"):
+        exe = step.lower_step(batch, seq).compile()
+    mem = exe.memory_analysis()
+    assert exe.as_text().count("tpu_custom_call") == 3
+    assert mem.temp_size_in_bytes < temp_gib * _GIB
+    print(config, "arguments", mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes)
 
 
 @pytest.mark.parametrize("nh,d", [(8, 128), (16, 64)])

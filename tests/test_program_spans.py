@@ -374,6 +374,8 @@ def test_train_step_spans(traced):
         kids = [s.name for s in traced.spans if s.parent_id == step.span_id]
         assert kids == ["GPTHybridTrainStep.step", "train.account",
                         "train.mem_sample"]
+        # the CPU's gate keeps XLA's attention: no forward kernel a layer
+        assert step.attrs["flash_fwd_per_layer"] == 0
 
 
 # ---- (h) the Paddle-style profiler on the widened record -------------------
