@@ -22,13 +22,15 @@ from .sdar import (  # noqa: F401
     SdarMoeConfig, sdar_moe_tiny_config, init_sdar_weights,
 )
 
-# Phi-4-mini-flash is found on first use (see serving/__init__.py)
-_LAZY = ("Phi4FlashConfig", "phi4flash_tiny_config",
-         "init_phi4flash_weights")
+# Phi-4-mini-flash and GLM-5.2 are found on first use (see
+# serving/__init__.py)
+_LAZY = {"Phi4FlashConfig": "phi4flash", "phi4flash_tiny_config": "phi4flash",
+         "init_phi4flash_weights": "phi4flash", "Glm5Config": "glm5",
+         "glm5_tiny_config": "glm5", "init_glm5_weights": "glm5"}
 
 
 def __getattr__(name):
     if name in _LAZY:
-        from . import phi4flash
-        return getattr(phi4flash, name)
+        from importlib import import_module
+        return getattr(import_module("." + _LAZY[name], __name__), name)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

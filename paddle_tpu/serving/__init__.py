@@ -138,11 +138,12 @@ from .scheduler import (ContinuousBatchingScheduler,  # noqa: F401
 from .router import PrefixAffinityRouter, SLOAutoscaler  # noqa: F401
 from .fleet import FleetError, FleetRouter, ReplicaHandle  # noqa: F401
 
-# the hybrid engine (a state pool beside the page pool) is found on first
-# use: `import paddle_tpu` is 11-15 s of every run's set-up, and no run
-# that serves another model should pay for this one's modules
+# the hybrid and the sparse latent engines are found on first use:
+# `import paddle_tpu` is 11-15 s of every run's set-up, and no run that
+# serves another model should pay for these modules
 _LAZY = {"Phi4FlashServingEngine": ".phi4flash_engine",
-         "StatePool": ".state_pool", "StatePoolFull": ".state_pool"}
+         "StatePool": ".state_pool", "StatePoolFull": ".state_pool",
+         "Glm5ServingEngine": ".glm5_engine"}
 
 
 def __getattr__(name):
@@ -156,6 +157,7 @@ __all__ = [
     "PagePool", "PagePoolError", "PagePoolOOM",
     "EngineContract", "PagedEngine", "EngineShapeError",
     "ServingEngine", "SdarServingEngine", "Phi4FlashServingEngine",
+    "Glm5ServingEngine",
     "StatePool", "StatePoolFull",
     "PrefixCache", "ContinuousBatchingScheduler", "Request",
     "MigrationUnsupported",

@@ -156,7 +156,7 @@ class PagedEngine(EngineContract):
     def __init__(self, params, *, num_layers, num_kv_heads, head_dim, dtype,
                  max_positions, page_size, num_pages, max_seq_len,
                  decode_buckets, prefill_chunk, prefix_cache,
-                 flat_rows=False):
+                 flat_rows=False, rows=None):
         max_seq_len = int(max_seq_len or max_positions)
         if max_seq_len > max_positions:
             raise ValueError(f"max_seq_len {max_seq_len} exceeds the "
@@ -180,7 +180,7 @@ class PagedEngine(EngineContract):
         self.pool = PagePool(num_pages, page_size, num_layers=num_layers,
                              num_kv_heads=num_kv_heads, head_dim=head_dim,
                              dtype=dtype, max_seq_len=max_seq_len,
-                             flat_rows=flat_rows)
+                             flat_rows=flat_rows, rows=rows)
         # the weights live with the pool, where the programs run: a
         # model built on another backend (host-side init) would
         # otherwise cross to the device again on every call
@@ -233,20 +233,21 @@ class PagedEngine(EngineContract):
         t0 = time.perf_counter()
         p = self.pool
         kp = self._aval(p.k_pages.shape, p.k_pages.dtype)
+        vp = self._aval(p.v_pages.shape, p.v_pages.dtype)
         params_avals = jax.tree_util.tree_map(
             lambda a: self._aval(a.shape, a.dtype), self.params)
         i32 = jnp.int32
         for b in self.decode_buckets:
             if b not in self._decode_exe:
                 self._decode_exe[b] = self._decode_jit.lower(
-                    params_avals, kp, kp, *self._decode_avals(b)).compile()
+                    params_avals, kp, vp, *self._decode_avals(b)).compile()
         if self.prefill_chunk is not None and self._chunk_exe is None:
             # ONE chunk program: offset and length ride as traced
             # scalars, so every chunk of every prompt (and every
             # cached-prefix suffix) reuses the same executable
             C, S = self.prefill_chunk, jax.ShapeDtypeStruct
             self._chunk_exe = self._chunk_jit.lower(
-                params_avals, kp, kp, S((1, C), i32), S((), i32),
+                params_avals, kp, vp, S((1, C), i32), S((), i32),
                 S((), i32), S((1, p.max_pages_per_seq), i32), S((C,), i32),
                 *self._chunk_extra_avals()).compile()
         self._compile_more(params_avals, kp)

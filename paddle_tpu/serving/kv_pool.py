@@ -2,7 +2,9 @@
 
 The serving engine's memory manager. The pool owns two device arrays —
 ``k_pages``/``v_pages`` ``[num_layers, num_pages, page_size,
-num_kv_heads, head_dim]`` — and the host-side bookkeeping that maps
+num_kv_heads, head_dim]``, or given ``rows`` two of their own depth, row
+shape and type (a latent pool's packed latent rows, and its index keys,
+also named ``aux_pages``) — and the host-side bookkeeping that maps
 sequences onto them: a free list and one page table (list of page ids)
 per live sequence. Live HBM therefore tracks *actual tokens* (rounded up
 to the page), not ``max_position_embeddings`` — the vLLM/"Ragged Paged
@@ -71,7 +73,7 @@ class PagePool:
 
     def __init__(self, num_pages, page_size, num_layers, num_kv_heads,
                  head_dim, dtype="float32", max_seq_len=None,
-                 flat_rows=False):
+                 flat_rows=False, rows=None):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (one is the sink)")
         if page_size < 1:
@@ -93,8 +95,18 @@ class PagePool:
         shape = (self.num_layers, self.num_pages, self.page_size) + (
             (self.num_kv_heads * self.head_dim,) if flat_rows
             else (self.num_kv_heads, self.head_dim))
-        self.k_pages = jnp.zeros(shape, dtype=dtype)
-        self.v_pages = jnp.zeros(shape, dtype=dtype)
+        if rows is None:
+            self.k_pages = jnp.zeros(shape, dtype=dtype)
+            self.v_pages = jnp.zeros(shape, dtype=dtype)
+        else:
+            # rows ((depth, row shape, dtype), (depth, row shape, dtype)):
+            # two arrays under the same table in place of K and V, each of
+            # its own depth, row shape and type (a latent pool: its packed
+            # latent rows, and the index keys as ``aux_pages``)
+            self.k_pages, self.v_pages = (
+                jnp.zeros((depth, self.num_pages, self.page_size)
+                          + tuple(row), dtype=kind)
+                for depth, row, kind in rows)
         # internal lock: every bookkeeping mutator/reader below runs
         # under it (witness-named for the runtime lock witness)
         self._mu = lockwitness.named_rlock("serving.page_pool")
@@ -163,7 +175,6 @@ class PagePool:
         waste = sum((self.page_size - n % self.page_size)
                     % self.page_size for n in self._lens.values())
         util = ((cap - waste) / cap) if cap else 1.0
-        itemsize = jnp.zeros((), self.k_pages.dtype).dtype.itemsize
         return {
             "num_pages": self.num_pages,
             "page_size": self.page_size,
@@ -174,7 +185,7 @@ class PagePool:
             "capacity_tokens": (self.num_pages - 1) * self.page_size,
             "utilization": round(util, 4),
             "internal_fragmentation": round(1.0 - util, 4),
-            "pool_bytes": 2 * int(np.prod(self.k_pages.shape)) * itemsize,
+            "pool_bytes": int(self.k_pages.nbytes + self.v_pages.nbytes),
             "pages_shared": self.pages_shared,
             "tokens_reused": self._tokens_reused,
             # raw counts next to the rate so a FLEET can aggregate hit
@@ -412,6 +423,13 @@ class PagePool:
         pages = self._tables[seq_id]
         return np.asarray([pages[t // ps] * ps + (t % ps)
                            for t in range(start, stop)], dtype=np.int32)
+
+    @property
+    def aux_pages(self):
+        """The second array, by a name that does not say values: where
+        ``rows`` gave the pool arrays of its own, ``v_pages`` holds no
+        values."""
+        return self.v_pages
 
     @_locked
     def bind(self, k_pages, v_pages):

@@ -37,7 +37,7 @@ from jax.sharding import SingleDeviceSharding
 import paddle_tpu  # noqa: F401  (x64 on, as every user of the kernels has it)
 from paddle_tpu.kernels import (flash_attention, grouped_matmul, int8_matmul,
                                 moe_dispatch, paged_attention,
-                                selective_scan)
+                                selective_scan, sparse_mla)
 
 
 @pytest.fixture(scope="module")
@@ -371,6 +371,72 @@ def test_phi4flash_program_updates_pool_and_state_in_place(
     else:
         assert named("window_attention_decode") == 1
         assert named("shared_kv_attention_decode") == 2
+
+
+def _glm5_program(one_chip, what):
+    """The sparse latent engine's programs at the benchmark's sizes: the
+    five served layers at the published widths (routers in float32), a
+    latent pool of 524,800 tokens (pages of 512 rows of 384 32-bit words,
+    each a row of 768 bfloat16 packed) and two index-key arrays of 128
+    beside it, a table of 132 pages, 16 slots, chunks of 2,048."""
+    from paddle_tpu.models import glm5
+    from paddle_tpu.serving import glm5_engine
+    cfg = glm5.Glm5Config(vocab_size=19360)
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    leaf = lambda n, s: sds(s, F32 if n in glm5.FLOAT32_LEAVES else BF16)
+    params = {"embed": sds((19360, 6144)), "norm": sds((6144,)),
+              "head": sds((6144, 19360)),
+              "layers": [{n: leaf(n, s) for n, s in
+                          glm5.glm5_layer_shapes(cfg, l).items()}
+                         for l in range(5)]}
+    lat = sds((5, 1025, 512, 1, 384), jnp.uint32)
+    idx = sds((2, 1025, 512, 128))
+    carried = 4 * math.prod(lat.shape) + 2 * math.prod(idx.shape)
+    if what == "decode16":
+        fn = functools.partial(glm5_engine.glm5_decode_fn, cfg=cfg)
+        args = (params, lat, idx, sds((16, 2 + 132), I32))
+    else:
+        fn = functools.partial(glm5_engine.glm5_chunk_fn, cfg=cfg)
+        args = (params, lat, idx, sds((1, 2048), I32), sds((), I32),
+                sds((), I32), sds((1, 132), I32), sds((2048,), I32))
+    return fn, args, carried
+
+
+@pytest.mark.parametrize("what,temp_gib", [("decode16", 0.1),
+                                           ("chunk", 2.4)])
+def test_glm5_program_updates_its_pools_in_place(
+        monkeypatch, one_chip, no_compile_cache, what, temp_gib):
+    """Whole, for the described v5e: the weights (7.24 GiB) and the
+    latent and index pools (4.00 GiB) beside the temporaries (the chunk's
+    are its index scores over the table's 67,584 positions, the passes of
+    the selection and its mask); both pools aliased, and no copy of the
+    latent pool (the row writes are a kernel's: XLA's scatter would lay
+    the pool out in tiles of 8 rows and back); the kernels under the
+    names the benchmark reads: the index score twice (the layers with an
+    indexer), the row writes and the attention over the selected rows
+    five times each, the held experts' grouped product twice in each of
+    four expert layers."""
+    fn, args, carried = _glm5_program(one_chip, what)
+    monkeypatch.setattr(grouped_matmul, "_interpret", lambda: False)
+    exe = _compiled(monkeypatch, sparse_mla, fn, *args, donate=(1, 2))
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= carried > 3.9 * _GIB
+    assert 11.2 * _GIB < mem.argument_size_in_bytes < 11.3 * _GIB
+    assert mem.temp_size_in_bytes < temp_gib * _GIB
+    text = exe.as_text()
+    assert not [ln for ln in text.splitlines() if " copy(" in ln
+                and "u32[5,1025,512,1,384]" in ln]
+    calls = [ln.lstrip().split(" ", 1)[0] for ln in text.splitlines()
+             if "tpu_custom_call" in ln]
+    kind = "decode" if what == "decode16" else "prefill"
+    named = lambda name: sum(c.startswith("%" + name) for c in calls)
+    assert named(f"mla_indexer_{kind}") == 2
+    assert named(f"sparse_mla_{kind}") == 5
+    assert named("latent_row_write") == 5
+    assert named("grouped_ragged-dot") == 8
+    assert len(calls) == 20
 
 
 @pytest.mark.parametrize("ps", [64, 128])

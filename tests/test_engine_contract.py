@@ -5,8 +5,10 @@
 Each case below runs for the GPT engine as the cells deploy it (chunked,
 prefix cache), for SDAR's block engine, for the hybrid Phi-4-flash engine
 (a state pool beside the pages, no prefix cache: the cases about cached
-pages leave it out), and, where the one-shot prefill has something of its
-own to say, for the GPT engine without a chunk program. Tiny configs, the kernels' reference paths: the contract is the
+pages leave it out), for the sparse latent GLM-5.2 engine (a latent page
+row and index keys under one table, no prefix cache), and, where the
+one-shot prefill has something of its own to say, for the GPT engine
+without a chunk program. Tiny configs, the kernels' reference paths: the contract is the
 host's.
 """
 import collections
@@ -16,17 +18,18 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.models import phi4flash, sdar
+from paddle_tpu.models import glm5, phi4flash, sdar
 from paddle_tpu.serving import (ContinuousBatchingScheduler, EngineContract,
                                 EngineShapeError, PagedEngine,
                                 SdarServingEngine, ServingEngine,
                                 simulate_decode_signatures)
-from paddle_tpu.serving import (engine as gpt_engine, phi4flash_engine,
-                                sdar_engine)
+from paddle_tpu.serving import (engine as gpt_engine, glm5_engine,
+                                phi4flash_engine, sdar_engine)
 from paddle_tpu.serving.scheduler import _ShapeProbeEngine
 
 SDAR_CFG = sdar.sdar_moe_tiny_config()
 PHI4_CFG = phi4flash.phi4flash_tiny_config()
+GLM5_CFG = glm5.glm5_tiny_config()
 MAX_LEN = 128
 COMMON_KEYS = {"decode_buckets", "prefill_chunk", "block_len", "pool",
                "compute_dtype", "weights_mb", "max_seq_len", "compile_s",
@@ -53,7 +56,12 @@ def phi4_weights():
 
 
 @pytest.fixture(scope="module")
-def build(gpt_model, sdar_weights, phi4_weights):
+def glm5_weights():
+    return glm5.init_glm5_weights(GLM5_CFG, 17)
+
+
+@pytest.fixture(scope="module")
+def build(gpt_model, sdar_weights, phi4_weights, glm5_weights):
     """``build(kind, **overrides)``: a new engine of that kind."""
     model, cfg = gpt_model
 
@@ -74,6 +82,12 @@ def build(gpt_model, sdar_weights, phi4_weights):
             base.update(kw)
             return phi4flash_engine.Phi4FlashServingEngine(
                 phi4_weights, PHI4_CFG, **base)
+        if kind == "glm5":
+            base.update(num_pages=64, max_seq_len=MAX_LEN,
+                        prefix_cache=False)
+            base.update(kw)
+            return glm5_engine.Glm5ServingEngine(glm5_weights, GLM5_CFG,
+                                                 **base)
         base.update(autofuse=False)
         base.update(kw)
         return ServingEngine(model, cfg, **base)
@@ -119,9 +133,9 @@ def _n_programs(eng):
         + len(getattr(eng, "_prefill_exe", ()))
 
 
-ALL = ["gpt", "gpt-oneshot", "sdar", "phi4"]
+ALL = ["gpt", "gpt-oneshot", "sdar", "phi4", "glm5"]
 CHUNKED = ["gpt", "sdar"]           # chunked, with the prefix cache
-PAGED = CHUNKED + ["phi4"]          # chunked, with or without it
+PAGED = CHUNKED + ["phi4", "glm5"]  # chunked, with or without it
 
 
 # ------------------------------------------------------- shapes refused
@@ -301,7 +315,13 @@ def test_status_carries_the_common_keys_and_every_programs_memory(
     assert st["decode_buckets"] == list(eng.decode_buckets)
     assert st["aot_programs"] >= len(eng.decode_buckets) + 1
     mem = st["program_memory"]
-    assert mem["pool_bytes"] == 2 * eng.pool.k_pages.nbytes
+    assert mem["pool_bytes"] == eng.pool.k_pages.nbytes \
+        + eng.pool.v_pages.nbytes
+    if kind == "glm5":
+        # a latent row of every layer, an index key of the indexers'
+        assert st["cache_bytes_per_token"] == 4 * (
+            5 * GLM5_CFG.row_width + 2 * GLM5_CFG.index_head_dim)
+        assert eng.pool.v_pages.shape[0] == 2
     assert sorted(mem["decode"]) == list(eng.decode_buckets)
     for sizes in list(mem["decode"].values()) + [mem["chunk"]]:
         assert set(sizes) == {"temp_bytes", "alias_bytes"}
@@ -431,7 +451,8 @@ def test_rebuilt_programs_are_made_from_the_modules_functions(
         "sdar": (sdar_engine, ("sdar_block_step_fn",
                                "sdar_chunk_prefill_fn")),
         "phi4": (phi4flash_engine, ("phi4flash_decode_fn",
-                                    "phi4flash_chunk_fn"))}[kind]
+                                    "phi4flash_chunk_fn")),
+        "glm5": (glm5_engine, ("glm5_decode_fn", "glm5_chunk_fn"))}[kind]
     eng = build(kind, decode_buckets=(1,))
     traced = []
 
