@@ -128,18 +128,9 @@ def _ln(x, w, b, eps):
     return (x - mu) * jax.lax.rsqrt(var + eps) * w + b
 
 
-def gpt_block(p, x, eps, mp_axis=None, use_flash=False, return_kv=False):
-    """One pre-LN decoder block. Pure jax.
-
-    p: dict of (possibly mp-sliced) tensors:
-      ln1_w/ln1_b [H], wqkv [H,3,nh,d], bqkv [3,nh,d], wo [nh,d,H], bo [H],
-      ln2_w/ln2_b [H], w1 [H,F], b1 [F], w2 [F,H], b2 [H]
-    x: [B, S, H]. When `mp_axis` is set (inside shard_map) the head dim of
-    wqkv/bqkv/wo and the F dim of w1/b1/w2 are local slices and the row-parallel
-    outputs are psum'ed over the axis — the hand-rolled Megatron pattern the
-    GSPMD path gets from sharding propagation instead. With `use_flash` the
-    attention core runs the Pallas FlashAttention kernel (TPU only).
-    """
+def _attention(p, x, eps, use_flash):
+    """A block's attention half up to its row-parallel output: ``(o, k,
+    v)``, ``o`` [B, S, H] before any sum over the mp axis."""
     h = _ln(x, p["ln1_w"], p["ln1_b"], eps)
     qkv = jnp.einsum("bsh,hknd->bsknd", h, p["wqkv"]) + p["bqkv"]
     q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # [B,S,nh,d]
@@ -154,13 +145,33 @@ def gpt_block(p, x, eps, mp_axis=None, use_flash=False, return_kv=False):
         logits = jnp.where(causal, logits, jnp.asarray(-1e30, logits.dtype))
         probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(x.dtype)
         attn = jnp.einsum("bnst,btnd->bsnd", probs, v)
-    o = jnp.einsum("bsnd,ndh->bsh", attn, p["wo"])
+    return jnp.einsum("bsnd,ndh->bsh", attn, p["wo"]), k, v
+
+
+def _mlp(p, x, eps):
+    """A block's MLP half up to its row-parallel output."""
+    h = _ln(x, p["ln2_w"], p["ln2_b"], eps)
+    u = jax.nn.gelu(h @ p["w1"] + p["b1"], approximate=True)
+    return u @ p["w2"]
+
+
+def gpt_block(p, x, eps, mp_axis=None, use_flash=False, return_kv=False):
+    """One pre-LN decoder block. Pure jax.
+
+    p: dict of (possibly mp-sliced) tensors:
+      ln1_w/ln1_b [H], wqkv [H,3,nh,d], bqkv [3,nh,d], wo [nh,d,H], bo [H],
+      ln2_w/ln2_b [H], w1 [H,F], b1 [F], w2 [F,H], b2 [H]
+    x: [B, S, H]. When `mp_axis` is set (inside shard_map) the head dim of
+    wqkv/bqkv/wo and the F dim of w1/b1/w2 are local slices and the row-parallel
+    outputs are psum'ed over the axis — the hand-rolled Megatron pattern the
+    GSPMD path gets from sharding propagation instead. With `use_flash` the
+    attention core runs the Pallas FlashAttention kernel (TPU only).
+    """
+    o, k, v = _attention(p, x, eps, use_flash)
     if mp_axis is not None:
         o = jax.lax.psum(o, mp_axis)
     x = x + o + p["bo"]
-    h = _ln(x, p["ln2_w"], p["ln2_b"], eps)
-    u = jax.nn.gelu(h @ p["w1"] + p["b1"], approximate=True)
-    m = u @ p["w2"]
+    m = _mlp(p, x, eps)
     if mp_axis is not None:
         m = jax.lax.psum(m, mp_axis)
     out = x + m + p["b2"]
@@ -482,6 +493,127 @@ def flash_fwd_per_layer(jaxpr):
     return fwd / bwd if bwd else 0
 
 
+def _sums_hidden_over_mp(jaxpr):
+    """Whether ``jaxpr`` itself, outside any loop or branch of its own,
+    sums a ``[B, S, H]`` activation over ``"mp"``: a ``psum``, or the
+    ``ppermute`` of :func:`exchange_sum`."""
+    from ..analysis.passes.cost import _sub_jaxprs
+    for eqn in jaxpr.eqns:
+        prim = eqn.primitive.name
+        if prim in ("psum", "ppermute"):
+            axes = eqn.params.get("axes", eqn.params.get("axis_name"))
+            axes = axes if isinstance(axes, tuple) else (axes,)
+            if "mp" in axes and eqn.invars[0].aval.ndim == 3:
+                return True
+        elif prim not in ("scan", "while", "cond") and any(
+                _sums_hidden_over_mp(j) for j in _sub_jaxprs(eqn.params)):
+            return True
+    return False
+
+
+def mp_streams(jaxpr):
+    """Independent streams a layer runs in a train step's jaxpr: the
+    carries of its layer scans, the scans whose own body sums an
+    activation over ``"mp"`` (forward, and the backward's transposed
+    scan). 2 where :func:`scan_blocks` splits the batch, 1 where it does
+    not."""
+    from ..analysis.passes.cost import _sub_jaxprs
+    counts = []
+
+    def walk(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "scan" and _sums_hidden_over_mp(
+                    eqn.params["jaxpr"].jaxpr):
+                counts.append(eqn.params["num_carry"])
+            for j in _sub_jaxprs(eqn.params):
+                walk(j)
+
+    walk(getattr(jaxpr, "jaxpr", jaxpr))
+    return max(counts, default=1)
+
+
+def exchange_sum(x, axis):
+    """``psum(x, axis)`` over an axis of two devices, as one exchange:
+    each adds the other's ``x`` to its own (``a + b == b + a``, so both
+    hold the same sum, in ``x``'s dtype). XLA runs the ``ppermute`` as an
+    asynchronous ``collective-permute-start``/``-done`` pair, with other
+    work between them; a shard_map ``psum`` is one synchronous
+    ``all-reduce``, beside which nothing runs."""
+    return x + jax.lax.ppermute(x, axis, [(0, 1), (1, 0)])
+
+
+def gpt_block_pair(p, a, b, eps, use_flash, mp_sum):
+    """:func:`gpt_block` over ``"mp"`` on two halves of a batch, ``a``
+    and ``b``, whose row-parallel sums (by ``mp_sum``) each run beside
+    the other half's compute: a's attention sum beside b's attention, b's
+    beside a's MLP, a's MLP sum beside b's MLP. Each optimization barrier
+    holds a sum's result back until the other half's work has been
+    produced, so the scheduler puts that work between the sum's start
+    and its end; b's MLP sum has nothing left beside it."""
+    barrier = jax.lax.optimization_barrier
+    oa = _attention(p, a, eps, use_flash)[0]
+    sa = mp_sum(oa, "mp")
+    ob = _attention(p, b, eps, use_flash)[0]
+    sa, ob = barrier((sa, ob))
+    a = a + sa + p["bo"]
+    sb = mp_sum(ob, "mp")
+    ma = _mlp(p, a, eps)
+    sb, ma = barrier((sb, ma))
+    b = b + sb + p["bo"]
+    sma = mp_sum(ma, "mp")
+    mb = _mlp(p, b, eps)
+    sma, mb = barrier((sma, mb))
+    return a + sma + p["b2"], b + mp_sum(mb, "mp") + p["b2"]
+
+
+def train_layer(eps, use_flash, remat, streams=1):
+    """The layer the train step's layer scans run, inside the shard_map:
+    :func:`gpt_block` over ``"mp"`` on one stream, or
+    :func:`gpt_block_pair` on two (its sums the exchange where the axis
+    holds two devices, else ``psum``).
+
+    ``remat="dots"`` is selective remat: it saves matmul outputs and the
+    flash kernel's output and row statistics and recomputes only the
+    elementwise/norm glue, a little memory for much less recompute than
+    per-block checkpointing (``remat=True``). prevent_cse=False: inside
+    lax.scan the loop structure already prevents the unwanted CSE; the
+    default True makes XLA run the whole forward twice (loss value +
+    residuals), measured +19% step time on v5e."""
+    if streams == 1:
+        layer = lambda p, x: gpt_block(p, x, eps, mp_axis="mp",
+                                       use_flash=use_flash)
+    else:
+        mp_sum = exchange_sum if jax.lax.axis_size("mp") == 2 \
+            else jax.lax.psum
+        layer = lambda p, a, b: gpt_block_pair(p, a, b, eps, use_flash,
+                                               mp_sum)
+    if remat == "dots":
+        return jax.checkpoint(layer, prevent_cse=False, policy=_DOTS_POLICY)
+    if remat:
+        return jax.checkpoint(layer, prevent_cse=False)
+    return layer
+
+
+def scan_blocks(blocks, x, eps, use_flash, remat):
+    """Apply the stacked ``blocks`` to ``x`` [B, S, H] in one
+    ``lax.scan`` of :func:`train_layer` inside the train step's
+    shard_map.
+
+    Where the ``"mp"`` axis holds more than one device and B is even, the
+    scan carries ``x`` as two halves of the batch that share no value,
+    and each layer runs :func:`gpt_block_pair` on them, so that one
+    half's row-parallel sums are in flight while the other half
+    computes. Otherwise the scan is one stream, as it always was."""
+    b = x.shape[0]
+    if jax.lax.axis_size("mp") == 1 or b % 2:
+        blk = train_layer(eps, use_flash, remat)
+        return jax.lax.scan(lambda h, p: (blk(p, h), None), x, blocks)[0]
+    pair = train_layer(eps, use_flash, remat, streams=2)
+    halves, _ = jax.lax.scan(lambda hs, p: (pair(p, *hs), None),
+                             (x[:b // 2], x[b // 2:]), blocks)
+    return jnp.concatenate(halves)
+
+
 def gpt_stacked_param_shapes(config: GPTConfig):
     """Shapes of the stacked train-step pytree — the single source of
     truth shared by the buffer path (asserted) and the abstract
@@ -570,9 +702,11 @@ class GPTHybridTrainStep:
         }
         self._compiled = None
         self._t = 0
-        # forward flash-kernel calls a layer in the step's program, read
-        # from its jaxpr at the first call (flash_fwd_per_layer)
+        # forward flash-kernel calls a layer and the streams a layer runs
+        # in the step's program, read from its jaxpr at the first call
+        # (flash_fwd_per_layer, mp_streams)
         self.flash_fwd_per_layer = None
+        self.mp_streams = None
         # opt-in static lint at first call (analysis pkg); the compiled
         # schedule itself is SPMD-by-construction — the lint covers the
         # eager model the stacked params came from
@@ -743,28 +877,11 @@ class GPTHybridTrainStep:
         def stage_prog(blocks_local, wte_local, lnf_w, lnf_b, xs, labs):
             stage = jax.lax.axis_index("pp")
 
-            blk = lambda p, xx: gpt_block(p, xx, eps, mp_axis="mp",
-                                          use_flash=use_flash)
-            if remat == "dots":
-                # selective remat: save matmul outputs and the flash
-                # kernel's output and row statistics, recompute only the
-                # elementwise/norm glue — trades a little memory for much
-                # less recompute than full per-block checkpointing
-                blk = jax.checkpoint(blk, prevent_cse=False,
-                                     policy=_DOTS_POLICY)
-            elif remat:
-                # prevent_cse=False: inside lax.scan the loop structure
-                # already prevents the unwanted CSE; the default True makes
-                # XLA run the whole forward twice (loss value + residuals),
-                # measured +19% step time on v5e
-                blk = jax.checkpoint(blk, prevent_cse=False)
-
             def apply_blocks(x, chunk=None):
                 bl = blocks_local if chunk is None else \
                     {k: v.reshape((vpp, -1) + v.shape[1:])[chunk]
                      for k, v in blocks_local.items()}
-                out, _ = jax.lax.scan(lambda h, p: (blk(p, h), None), x, bl)
-                return out
+                return scan_blocks(bl, x, eps, use_flash, remat)
 
             def head(x, lab):
                 x = _ln(x, lnf_w, lnf_b, eps).astype(wte_local.dtype)
@@ -989,8 +1106,6 @@ class GPTHybridTrainStep:
 
         def stage_prog(blocks_local, wte_local, lnf_w, lnf_b, xs, labs):
             stage = jax.lax.axis_index("pp")
-            blk = lambda p, xx: gpt_block(p, xx, eps, mp_axis="mp",
-                                          use_flash=use_flash)
             # Remat here trades FLOPs for WITHIN-tick memory: each tick's
             # vjp re-derives a whole stage sub-stack, so layers_per_stage
             # blocks' residuals are live at once — per-block checkpointing
@@ -999,15 +1114,8 @@ class GPTHybridTrainStep:
             # live in the O(pp) ring.) At 13B scale this decides whether a
             # stage's backward fits; see tools/mem_probe.py for measured
             # numbers per schedule × n_micro × remat.
-            if remat == "dots":
-                blk = jax.checkpoint(blk, prevent_cse=False,
-                                     policy=_DOTS_POLICY)
-            elif remat:
-                blk = jax.checkpoint(blk, prevent_cse=False)
-
             def block_apply(bl, x):
-                out, _ = jax.lax.scan(lambda h_, p: (blk(p, h_), None), x, bl)
-                return out
+                return scan_blocks(bl, x, eps, use_flash, remat)
 
             def block_apply_chunk(bl, x, c):
                 # [vpp*chunk_len, ...] -> this stage's chunk c sub-stack
@@ -1174,7 +1282,8 @@ class GPTHybridTrainStep:
                          annotation=jax.profiler.StepTraceAnnotation,
                          step_num=self._t + 1) as span:
             loss = self._step(input_ids, labels)
-            span.set(flash_fwd_per_layer=self.flash_fwd_per_layer)
+            span.set(flash_fwd_per_layer=self.flash_fwd_per_layer,
+                     mp_streams=self.mp_streams)
             return loss
 
     def _step(self, input_ids, labels):
@@ -1223,9 +1332,10 @@ class GPTHybridTrainStep:
         t = jnp.asarray(self._t, jnp.float32)
         if first_call:
             # the trace the call below reuses (jit caches it)
-            self.flash_fwd_per_layer = flash_fwd_per_layer(
-                self._compiled.trace(self.params, self.opt_state, ids, labs,
-                                     lr, t).jaxpr)
+            jaxpr = self._compiled.trace(self.params, self.opt_state, ids,
+                                         labs, lr, t).jaxpr
+            self.flash_fwd_per_layer = flash_fwd_per_layer(jaxpr)
+            self.mp_streams = mp_streams(jaxpr)
         # the compiled call until it returns: the host's time to hand the
         # step to the device, not the step's device time
         with RecordEvent("GPTHybridTrainStep.step", "Operator"):

@@ -108,17 +108,42 @@ def test_flash_fwd_bwd(monkeypatch, one_chip, no_compile_cache):
                     _FLASH, _FLASH, _FLASH) == 3
 
 
+def _covered_sums(text):
+    """How many ``collective-permute`` pairs of the scheduled program have
+    a matmul or a kernel between their start and their done."""
+    covered = 0
+    for comp in re.split(r"\n(?=\S.*\{\n)", text):
+        open_, work = {}, False
+        for line in comp.split("\n"):
+            start = re.search(r"%(\S+) = .* collective-permute-start\(", line)
+            done = re.search(r"collective-permute-done\(%(\S+?)\)", line)
+            if start:
+                open_[start.group(1)] = False
+            elif done:
+                covered += open_.pop(done.group(1))
+            elif "flash_attention" in line or "dot_general" in line:
+                open_ = dict.fromkeys(open_, True)
+    return covered
+
+
 # The benchmark's two train steps, whole, under remat="dots": the backward
-# reads the flash forward's saved output and row statistics, so a program
-# holds three kernels, not four (the forward again inside the backward
+# reads the flash forward's saved output and row statistics, so a layer
+# calls three kernels, not four (the forward again inside the backward
 # loop). The 345M step's temporaries hold that output, lane-padded, for
-# 24 layers (12.66 GiB before, 13.81 with it; B14 no longer fits).
-@pytest.mark.parametrize("config,dp,mp,batch,seq,temp_gib", [
-    ("gpt_345m_config", 1, 1, 12, 1024, 14.5),
-    ("gpt_1p3b_config", 2, 2, 8, 2048, 9.0)])
+# 24 layers (12.66 GiB before, 13.81 with it; B14 no longer fits). On
+# dp2·mp2 the layer scan runs two half-batch streams (gpt.scan_blocks):
+# each calls the three kernels, and its five mp sums a layer (two forward,
+# three backward) are collective-permute pairs, of which the scheduler
+# covers seven of the ten with the other half's matmuls or kernels. It
+# does so only while memory is not tight: at 9.37 GiB of temporaries, and
+# none of the backward's six with about 1% of the device's memory less.
+@pytest.mark.parametrize(
+    "config,dp,mp,batch,seq,temp_gib,streams,permutes,covered", [
+        ("gpt_345m_config", 1, 1, 12, 1024, 14.5, 1, 0, 0),
+        ("gpt_1p3b_config", 2, 2, 8, 2048, 9.5, 2, 10, 7)])
 def test_train_step_runs_the_flash_forward_once(
         monkeypatch, topo, no_compile_cache, config, dp, mp, batch, seq,
-        temp_gib):
+        temp_gib, streams, permutes, covered):
     from paddle_tpu.distributed import mesh as mesh_mod
     from paddle_tpu.models import gpt
     monkeypatch.setattr(mesh_mod, "_global_mesh", mesh_mod._global_mesh)
@@ -133,7 +158,10 @@ def test_train_step_runs_the_flash_forward_once(
     with jax.default_matmul_precision("default"):
         exe = step.lower_step(batch, seq).compile()
     mem = exe.memory_analysis()
-    assert exe.as_text().count("tpu_custom_call") == 3
+    text = exe.as_text()
+    assert text.count("tpu_custom_call") == 3 * streams
+    assert text.count(" collective-permute-start(") == permutes
+    assert _covered_sums(text) >= covered
     assert mem.temp_size_in_bytes < temp_gib * _GIB
     print(config, "arguments", mem.argument_size_in_bytes, "temporaries",
           mem.temp_size_in_bytes)
