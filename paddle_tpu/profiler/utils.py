@@ -26,9 +26,14 @@ Records form a tree per thread: ``parent_id`` is the span that was open
 on the same thread when this one began (0 at the top). The buffer is
 bounded: past ``MAX_SPANS`` the oldest records go and
 :func:`dropped_spans` counts them.
+
+:func:`record_gc_pauses` adds the garbage collector's pauses to the
+record as ``gc`` spans (generation, collected), under whatever span was
+open when the collection began.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import threading
 import time
@@ -38,7 +43,7 @@ from typing import NamedTuple
 
 from jax.profiler import TraceAnnotation
 
-MAX_SPANS = 1 << 16
+MAX_SPANS = 1 << 17
 
 
 class Span(NamedTuple):
@@ -53,13 +58,13 @@ class Span(NamedTuple):
     attrs: dict
 
 
-_lock = threading.Lock()
+_lock = threading.RLock()     # a gc span can end inside a locked read
 _host_events: deque = deque(maxlen=MAX_SPANS)
 _dropped = 0
 _counter_samples: list = []      # (name, ts_ns, value) -> "ph":"C" events
 _collecting = False
 _span_ids = itertools.count(1)
-_open = threading.local()        # .stack: ids of the spans open on a thread
+_open = threading.local()   # .stack: ids of the spans open on a thread; .tid
 
 
 def _set_collecting(flag: bool):
@@ -124,7 +129,7 @@ class RecordEvent(ContextDecorator):
     def begin(self):
         tracing = TraceAnnotation.is_enabled()
         if not (tracing or _collecting):
-            return
+            return self
         if tracing:
             self._note = self._annotation(self.name, **self.attrs)
             self._note.__enter__()
@@ -132,10 +137,20 @@ class RecordEvent(ContextDecorator):
             stack = _open.stack
         except AttributeError:
             stack = _open.stack = []
+            _open.tid = threading.get_ident()
         self._parent_id = stack[-1] if stack else 0
         self._span_id = next(_span_ids)
         stack.append(self._span_id)
         self._begin_ns = time.perf_counter_ns()
+        return self
+
+    @property
+    def kept(self) -> bool:
+        """Whether this span is being recorded (between ``begin()`` and
+        ``end()`` while somebody is looking): work done only for the
+        record, such as waiting for the device apart from the copy that
+        follows, runs only then."""
+        return self._begin_ns is not None
 
     def set(self, **attrs):
         """Attributes known only after ``begin()`` (a count, a result)."""
@@ -145,34 +160,63 @@ class RecordEvent(ContextDecorator):
         if self._note is not None:
             self._note.set_metadata(**attrs)
 
-    def end(self):
+    def end(self, *_exc):
         global _dropped
         if self._begin_ns is None:
             return
         end_ns = time.perf_counter_ns()
+        # off the stack before anything allocates: a collection that
+        # begins after end_ns must not be recorded inside this span
+        try:
+            stack, tid = _open.stack, _open.tid
+        except AttributeError:      # ended on a thread that began none
+            stack, tid = (), threading.get_ident()
+        if stack and stack[-1] == self._span_id:
+            stack.pop()
+        elif self._span_id in stack:
+            # and whatever was opened inside this span and never ended
+            del stack[stack.index(self._span_id):]
         if self._note is not None:
             self._note.__exit__(None, None, None)
             self._note = None
-        stack = getattr(_open, "stack", ())     # () if ended on another thread
-        if self._span_id in stack:
-            # and whatever was opened inside this span and never ended
-            del stack[stack.index(self._span_id):]
-        span = Span(self.name, threading.get_ident(), self._begin_ns,
-                    end_ns, self.event_type, self._span_id,
-                    self._parent_id, self.attrs)
+        # tuple.__new__: the record without a Python-level constructor,
+        # which would be charged to the parent's time between children
+        span = tuple.__new__(Span, (
+            self.name, tid, self._begin_ns, end_ns,
+            self.event_type, self._span_id, self._parent_id, self.attrs))
         self._begin_ns = None
         with _lock:
             if len(_host_events) == _host_events.maxlen:
                 _dropped += 1
             _host_events.append(span)
 
-    def __enter__(self):
-        self.begin()
-        return self
+    # one frame each way: the recorder's own time sits between a span's
+    # children, where no span names it
+    __enter__ = begin
+    __exit__ = end
 
-    def __exit__(self, *exc):
-        self.end()
-        return False
+
+_gc_event = None       # the collection in progress (one at a time)
+
+
+def _gc_span(phase, info):
+    global _gc_event
+    if phase == "start":
+        if TraceAnnotation.is_enabled() or _collecting:
+            _gc_event = RecordEvent("gc", generation=info["generation"])
+            _gc_event.begin()
+    elif _gc_event is not None:
+        _gc_event.set(collected=info["collected"])
+        _gc_event.end()
+        _gc_event = None
+
+
+def record_gc_pauses():
+    """Record every garbage collection as a ``gc`` span, kept like any
+    other span (only while somebody is looking). Idempotent: the
+    callback is installed once per process."""
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
 
 
 def load_profiler_result(filename: str):

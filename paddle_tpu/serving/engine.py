@@ -45,8 +45,10 @@ flight recorder, and the anomaly monitor under ``path="serving"`` (see
 finished :class:`~.scheduler.Request`. Under a device trace the chunked
 prefill and the decode are ``RecordEvent`` spans (``engine.prefill_begin``
 / ``prefill_step`` / ``decode``) with ``engine.host_prep`` /
-``dispatch`` / ``readback`` inside; ``in_flight`` on them counts the
-programs dispatched since the engine's last readback.
+``dispatch`` / ``readback`` inside, the readback split into
+``engine.device_wait`` (ends when the program's result is ready) and
+``engine.d2h`` (the copy and what follows it); ``in_flight`` on them
+counts the programs dispatched since the engine's last readback.
 """
 from __future__ import annotations
 
@@ -64,7 +66,7 @@ from ..kernels.paged_attention import (paged_attention_decode,
                                        ragged_prefill_attention)
 from ..profiler.utils import RecordEvent
 from .engine_core import (EngineShapeError, PagedEngine, _write_rows,
-                          smallest_bucket)
+                          smallest_bucket, wait_for_device)
 
 __all__ = ["ServingEngine", "EngineShapeError", "decode_step_fn",
            "decode_packed_fn", "prefill_fn", "chunk_prefill_fn",
@@ -837,8 +839,7 @@ class ServingEngine(PagedEngine):
             raise EngineShapeError(f"{n} sequences > bucket {bucket}")
         with RecordEvent("engine.decode", n=n, bucket=bucket,
                          in_flight=self._in_flight):
-            # h2d: host arrays sent in this call
-            with RecordEvent("engine.host_prep", h2d=1):
+            with RecordEvent("engine.host_prep"):
                 slots = list(seq_ids) + [None] * (bucket - n)
                 self._calls += 1    # as _next_key(): one stream of keys
                 state = np.empty(
@@ -858,9 +859,11 @@ class ServingEngine(PagedEngine):
             self._in_flight += 1
             with RecordEvent("engine.readback",
                              in_flight=self._in_flight):
-                out = [int(t) for t in np.asarray(nxt)[:n]]
-                for sid, t in zip(seq_ids, out):
-                    self._last_token[sid] = t
+                wait_for_device(nxt)
+                with RecordEvent("engine.d2h"):
+                    out = [int(t) for t in np.asarray(nxt)[:n]]
+                    for sid, t in zip(seq_ids, out):
+                        self._last_token[sid] = t
             self._in_flight = 0
         return out
 

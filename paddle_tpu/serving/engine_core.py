@@ -59,7 +59,7 @@ from .kv_pool import PagePool
 from .prefix_cache import PrefixCache
 
 __all__ = ["EngineShapeError", "EngineContract", "PagedEngine",
-           "smallest_bucket"]
+           "smallest_bucket", "wait_for_device"]
 
 
 class EngineShapeError(RuntimeError):
@@ -75,6 +75,17 @@ def _write_rows(pages, layer, rows, new):
     L, np_, ps, nkv, d = pages.shape
     return pages.reshape(L, np_ * ps, nkv, d).at[layer, rows].set(
         new.astype(pages.dtype)).reshape(pages.shape)
+
+
+def wait_for_device(out):
+    """The device-ready stamp of a readback: an ``engine.device_wait``
+    span that ends when ``out`` (what the program returned) is ready.
+    The explicit wait runs only while the span is kept; otherwise the
+    copy to the host that follows (``engine.d2h``) waits, as it always
+    has."""
+    with RecordEvent("engine.device_wait") as ev:
+        if ev.kept:
+            jax.block_until_ready(out)
 
 
 def smallest_bucket(buckets, n: int, what: str) -> int:
@@ -350,8 +361,9 @@ class PagedEngine(EngineContract):
         program returned after the pools, not read back."""
 
     def _chunk_read(self, seq_id, out):
-        """Read back a prompt's last chunk (inside ``engine.readback``):
-        the first token, or None where prefill yields none."""
+        """Read back a prompt's last chunk (inside ``engine.readback``'s
+        ``engine.d2h``, once the device is done): the first token, or
+        None where prefill yields none."""
         raise NotImplementedError
 
     def prefill_step(self, seq_id):
@@ -390,14 +402,17 @@ class PagedEngine(EngineContract):
             if not final:
                 return clen, False, None
             with RecordEvent("engine.readback", in_flight=self._in_flight):
-                tok = self._chunk_read(seq_id, out)
+                wait_for_device(out)
+                with RecordEvent("engine.d2h"):
+                    tok = self._chunk_read(seq_id, out)
             self._in_flight = 0
             del self._chunk_state[seq_id]
             if self.prefix_cache is not None:
                 # content now exists: publish the prompt's full pages so
                 # queued same-prefix requests hit them
-                self.prefix_cache.insert(st["prompt"],
-                                         self.pool.table(seq_id))
+                with RecordEvent("engine.unpack"):
+                    self.prefix_cache.insert(st["prompt"],
+                                             self.pool.table(seq_id))
         return clen, True, tok
 
     # ----------------------------------------------------------- release

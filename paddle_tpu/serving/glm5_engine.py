@@ -63,14 +63,17 @@ whose selection history nothing records, and no test covers it yet.
 Spans: the core's ``engine.prefill_begin`` and ``engine.prefill_step``
 (with ``index_keys``, the keys the chunk's queries score in a layer with
 an indexer, and ``selected``, the rows their attention reads a layer);
-``engine.decode`` with ``engine.host_prep`` / ``dispatch`` /
-``readback`` inside and the attributes ``live_ctx`` (sum of the live
-lengths), ``selected`` (sum of ``min(len, index_topk)``: the rows a
-layer's attention reads) and ``cache_bytes`` (the bytes of the pages in
-use); ``experts.held`` (program ``decode`` or ``chunk``, ``tokens`` the
-real rows, ``assigned`` and ``active`` a list over the expert layers:
-the assignments to held experts and how many held experts got one), one
-a program, recorded when its counts are read.
+``sched.batch`` (the live lengths and the counts below, worked out
+before ``engine.decode`` opens: the decode tick's time outside the
+``engine.*`` spans, as it was before it had a span), then ``engine.decode`` with
+``engine.host_prep`` / ``dispatch`` / ``readback`` / ``unpack`` inside
+and the attributes ``live_ctx`` (sum of the live lengths), ``selected``
+(sum of ``min(len, index_topk)``: the rows a layer's attention reads)
+and ``cache_bytes`` (the bytes of the pages in use); ``experts.held``
+(program ``decode`` or ``chunk``, ``tokens`` the real rows, ``assigned``
+and ``active`` a list over the expert layers: the assignments to held
+experts and how many held experts got one), one a program, recorded when
+its counts are read.
 """
 from __future__ import annotations
 
@@ -85,7 +88,7 @@ from ..kernels.sparse_mla import (index_scores, masked_mla_chunk,
                                   write_rows)
 from ..models import glm5 as M
 from ..profiler.utils import RecordEvent
-from .engine_core import EngineShapeError, PagedEngine
+from .engine_core import EngineShapeError, PagedEngine, wait_for_device
 
 __all__ = ["Glm5ServingEngine", "glm5_decode_fn", "glm5_chunk_fn"]
 
@@ -376,16 +379,17 @@ class Glm5ServingEngine(PagedEngine):
         bucket = self.decode_bucket(n) if bucket is None else bucket
         if n > bucket:
             raise EngineShapeError(f"{n} sequences > bucket {bucket}")
-        rows = list(seq_ids) + [None] * (bucket - n)
-        lens = self.pool.lens_array(rows)
-        k = self.cfg.index_topk
-        with RecordEvent(
-                "engine.decode", n=n, bucket=bucket,
-                in_flight=self._in_flight, live_ctx=int(lens.sum()),
-                selected=int(np.minimum(lens, k).sum()),
+        with RecordEvent("sched.batch"):
+            rows = list(seq_ids) + [None] * (bucket - n)
+            lens = self.pool.lens_array(rows)
+            attrs = dict(
+                n=n, bucket=bucket, in_flight=self._in_flight,
+                live_ctx=int(lens.sum()),
+                selected=int(np.minimum(lens, self.cfg.index_topk).sum()),
                 cache_bytes=self.pool.pages_in_use * self.pool.page_size
-                * self.cache_bytes_per_token):
-            with RecordEvent("engine.host_prep", h2d=1):
+                * self.cache_bytes_per_token)
+        with RecordEvent("engine.decode", **attrs):
+            with RecordEvent("engine.host_prep"):
                 state = np.empty(
                     (bucket, _TABLE + self.pool.max_pages_per_seq), np.int32)
                 state[:, _TOKEN] = [self._last_token.get(s, 0) for s in rows]
@@ -399,18 +403,21 @@ class Glm5ServingEngine(PagedEngine):
                 self.pool.bind(out[0], out[1])
             self._in_flight += 1
             with RecordEvent("engine.readback", in_flight=self._in_flight):
-                head = np.asarray(out[2])
-                nxt = [int(t) for t in head[:n]]
-                if self.keep_logits:
-                    self.last_logits = np.asarray(out[4])[:n]
+                wait_for_device(out[2])
+                with RecordEvent("engine.d2h"):
+                    head = np.asarray(out[2])
+                    nxt = [int(t) for t in head[:n]]
+                    if self.keep_logits:
+                        self.last_logits = np.asarray(out[4])[:n]
             self._in_flight = 0
-            self._pending_loads.append((head, n, "decode"))
-            self._read_loads()
-            self.counters["decode_ticks"] += 1
-            for sid, t in zip(seq_ids, nxt):
-                self._last_token[sid] = t
-            if self.selection_every:
-                self._log_selections(seq_ids, lens, out[3])
+            with RecordEvent("engine.unpack"):
+                self._pending_loads.append((head, n, "decode"))
+                self._read_loads()
+                self.counters["decode_ticks"] += 1
+                for sid, t in zip(seq_ids, nxt):
+                    self._last_token[sid] = t
+                if self.selection_every:
+                    self._log_selections(seq_ids, lens, out[3])
         return nxt
 
     def _log_selections(self, seq_ids, lens, probe):

@@ -71,11 +71,14 @@ resets the slot.
 
 Spans: the core's ``engine.prefill_begin`` (with ``state.alloc`` under
 it) and ``engine.prefill_step`` (``cross`` = 1 where the chunk ran the
-cross-decoder), ``state.free`` in ``release``, and ``engine.decode`` with
-``engine.host_prep`` / ``dispatch`` / ``readback`` inside and the
-attributes ``live_ctx`` (sum of the live lengths), ``window_rows`` (sum of
-``min(len, window)``), ``state_slots`` (slots in use) and ``cache_bytes``
-(the bytes of the pages and of the slots in use).
+cross-decoder), ``state.free`` in ``release``, ``sched.batch`` (the
+live lengths and the counts below, worked out before ``engine.decode``
+opens: the decode tick's time outside the ``engine.*`` spans, as it was
+before it had a span), and ``engine.decode`` with ``engine.host_prep`` / ``dispatch`` /
+``readback`` / ``unpack`` inside and the attributes ``live_ctx`` (sum of
+the live lengths), ``window_rows`` (sum of ``min(len, window)``),
+``state_slots`` (slots in use) and ``cache_bytes`` (the bytes of the
+pages and of the slots in use).
 """
 from __future__ import annotations
 
@@ -88,7 +91,7 @@ import numpy as np
 from ..kernels.paged_attention import paged_attention_decode_rows
 from ..models import phi4flash as M
 from ..profiler.utils import RecordEvent
-from .engine_core import EngineShapeError, PagedEngine
+from .engine_core import EngineShapeError, PagedEngine, wait_for_device
 from .state_pool import StatePool
 
 __all__ = ["Phi4FlashServingEngine", "phi4flash_decode_fn",
@@ -463,18 +466,20 @@ class Phi4FlashServingEngine(PagedEngine):
         bucket = self.decode_bucket(n) if bucket is None else bucket
         if n > bucket:
             raise EngineShapeError(f"{n} sequences > bucket {bucket}")
-        rows = list(seq_ids) + [None] * (bucket - n)
-        lens = self.pool.lens_array(rows)
-        window = self.cfg.sliding_window
-        with RecordEvent(
-                "engine.decode", n=n, bucket=bucket,
-                in_flight=self._in_flight, live_ctx=int(lens.sum()),
+        with RecordEvent("sched.batch"):
+            rows = list(seq_ids) + [None] * (bucket - n)
+            lens = self.pool.lens_array(rows)
+            window = self.cfg.sliding_window
+            attrs = dict(
+                n=n, bucket=bucket, in_flight=self._in_flight,
+                live_ctx=int(lens.sum()),
                 window_rows=int(np.minimum(lens, window).sum()),
                 state_slots=self.state.slots_in_use,
                 cache_bytes=self.pool.pages_in_use * self.pool.page_size
                 * self.cache_bytes_per_token
-                + self.state.slots_in_use * self.state.bytes_per_slot):
-            with RecordEvent("engine.host_prep", h2d=1):
+                + self.state.slots_in_use * self.state.bytes_per_slot)
+        with RecordEvent("engine.decode", **attrs):
+            with RecordEvent("engine.host_prep"):
                 state = np.empty(
                     (bucket, _TABLE + self.pool.max_pages_per_seq), np.int32)
                 state[:, _TOKEN] = [self._last_token.get(s, 0) for s in rows]
@@ -490,13 +495,16 @@ class Phi4FlashServingEngine(PagedEngine):
                 self.state.bind(*out[2:6])
             self._in_flight += 1
             with RecordEvent("engine.readback", in_flight=self._in_flight):
-                nxt = [int(t) for t in np.asarray(out[6])[:n]]
-                if self.keep_logits:
-                    self.last_logits = np.asarray(out[7])[:n]
+                wait_for_device(out[6])
+                with RecordEvent("engine.d2h"):
+                    nxt = [int(t) for t in np.asarray(out[6])[:n]]
+                    if self.keep_logits:
+                        self.last_logits = np.asarray(out[7])[:n]
             self._in_flight = 0
-            self.counters["decode_ticks"] += 1
-            for sid, t in zip(seq_ids, nxt):
-                self._last_token[sid] = t
+            with RecordEvent("engine.unpack"):
+                self.counters["decode_ticks"] += 1
+                for sid, t in zip(seq_ids, nxt):
+                    self._last_token[sid] = t
         return nxt
 
     def _forget(self, seq_id):

@@ -48,8 +48,9 @@ Telemetry — aggregate AND request-scoped:
 - while a device trace is being taken (or a ``Profiler`` records) every
   tick is a ``sched.step`` :class:`~paddle_tpu.profiler.RecordEvent`
   with one child per phase (``sched.expire`` / ``evict`` / ``admit`` /
-  ``prefill_tick`` / ``hooks`` / ``decode_tick`` / ``account``) and the
-  engine's spans under those; off, each is one predicate;
+  ``prefill_tick`` / ``hooks`` / ``prepare`` / ``decode_tick`` /
+  ``account``) and the engine's spans under those, and ``submit()`` is
+  a ``sched.submit``; off, each is one predicate;
 - :meth:`ContinuousBatchingScheduler.serve_http` exposes ``/metrics``,
   ``/healthz`` (flips unhealthy after an engine failure), and
   ``/status`` (queue/pool/SLO snapshot) on a stdlib HTTP thread.
@@ -65,8 +66,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from ..observability import lockwitness
-from ..profiler.utils import RecordEvent
+from ..observability import instrument as obs, lockwitness
+from ..profiler.utils import RecordEvent, record_gc_pauses
 from .engine_core import EngineContract, smallest_bucket
 
 __all__ = ["Request", "ContinuousBatchingScheduler",
@@ -201,6 +202,7 @@ class ContinuousBatchingScheduler:
     def __init__(self, engine, max_queue: int = 1024, slo=None,
                  max_retained: int = 4096, prefill_token_budget=None):
         from ..observability.slo import SLOConfig, SLOTracker
+        record_gc_pauses()      # a collection inside a tick is a span of it
         self.engine = engine
         self.buckets = tuple(engine.decode_buckets)
         self.max_concurrency = self.buckets[-1]
@@ -303,57 +305,57 @@ class ContinuousBatchingScheduler:
         drain rate and rejected with reason ``retry_after`` plus a
         machine-readable ``retry_after_s`` hint; in shedding mode all
         cache-miss traffic is refused the same way (reason ``shed``)."""
-        from ..observability import instrument as obs
         from ..observability.reqtrace import RequestTrace
-        prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
-        with self._lock:
-            r = Request(next(self._rid) if rid is None else int(rid),
-                        prompt, int(max_new_tokens), eos_id=eos_id,
-                        router_wait_s=float(router_wait_s))
-            r.deadline_s = float(deadline_s) \
-                if deadline_s is not None and deadline_s > 0 \
-                else self.default_deadline_s
-            r.trace = RequestTrace(r.rid, r.submit_time)
-            pool = self.engine.pool
-            total = prompt.shape[0] + r.max_new_tokens
-            # max_new >= 1: prefill always emits one token, so total >=
-            # n+1 and the engine's prompt-room check can never fire at
-            # admission
-            reason = None
-            if self.draining:
-                reason = "draining"
-            elif r.max_new_tokens < 1:
-                reason = "max_new<1"
-            elif total > pool.max_seq_len:
-                reason = "too_long"
-            elif len(self._queue) >= self.max_queue:
-                reason = "retry_after"
-                r.retry_after_s = self._retry_after_estimate()
-            elif pool.pages_needed(total) > pool.num_pages - 1:
-                reason = "pool_too_small"
-            elif self.mode == "shedding" \
-                    and not self._cache_hit_tokens(prompt):
-                # shedding: only traffic the prefix cache makes cheap
-                # still gets in — everything else backs off
-                reason = "shed"
-                r.retry_after_s = self._retry_after_estimate()
-            if reason is not None:
-                r.state = "rejected"
-                r.reject_reason = reason
-                r.trace.span("rejected", r.submit_time,
-                             time.perf_counter(), reason=reason)
-                self.rejected.append(r)
-                del self.rejected[:-self.max_retained]
-                obs.serving_requests_counter().inc(event="rejected",
-                                                   reason=reason)
-                if self.slo is not None:
-                    self.slo.observe_request(r.summary())
-                self._log_request(r)
+        with RecordEvent("sched.submit"):
+            prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
+            with self._lock:
+                r = Request(next(self._rid) if rid is None else int(rid),
+                            prompt, int(max_new_tokens), eos_id=eos_id,
+                            router_wait_s=float(router_wait_s))
+                r.deadline_s = float(deadline_s) \
+                    if deadline_s is not None and deadline_s > 0 \
+                    else self.default_deadline_s
+                r.trace = RequestTrace(r.rid, r.submit_time)
+                pool = self.engine.pool
+                total = prompt.shape[0] + r.max_new_tokens
+                # max_new >= 1: prefill always emits one token, so total >=
+                # n+1 and the engine's prompt-room check can never fire at
+                # admission
+                reason = None
+                if self.draining:
+                    reason = "draining"
+                elif r.max_new_tokens < 1:
+                    reason = "max_new<1"
+                elif total > pool.max_seq_len:
+                    reason = "too_long"
+                elif len(self._queue) >= self.max_queue:
+                    reason = "retry_after"
+                    r.retry_after_s = self._retry_after_estimate()
+                elif pool.pages_needed(total) > pool.num_pages - 1:
+                    reason = "pool_too_small"
+                elif self.mode == "shedding" \
+                        and not self._cache_hit_tokens(prompt):
+                    # shedding: only traffic the prefix cache makes cheap
+                    # still gets in — everything else backs off
+                    reason = "shed"
+                    r.retry_after_s = self._retry_after_estimate()
+                if reason is not None:
+                    r.state = "rejected"
+                    r.reject_reason = reason
+                    r.trace.span("rejected", r.submit_time,
+                                 time.perf_counter(), reason=reason)
+                    self.rejected.append(r)
+                    del self.rejected[:-self.max_retained]
+                    obs.serving_requests_counter().inc(event="rejected",
+                                                       reason=reason)
+                    if self.slo is not None:
+                        self.slo.observe_request(r.summary())
+                    self._log_request(r)
+                    return r
+                self._queue.append(r)
+                obs.serving_requests_counter().inc(event="submitted")
+                obs.serving_queue_depth_gauge().set(float(len(self._queue)))
                 return r
-            self._queue.append(r)
-            obs.serving_requests_counter().inc(event="submitted")
-            obs.serving_queue_depth_gauge().set(float(len(self._queue)))
-            return r
 
     @property
     def pending(self) -> int:
@@ -430,7 +432,6 @@ class ContinuousBatchingScheduler:
                 and burn < 0.5 * self.brownout_burn:
             self.mode = "healthy"
         if self.mode != prev:
-            from ..observability import instrument as obs
             from ..observability.runlog import get_run_logger
             self.mode_transitions += 1
             obs.serving_overload_mode_gauge().set(float(
@@ -449,7 +450,6 @@ class ContinuousBatchingScheduler:
         stamp the ``deadline_exceeded`` terminal state. Cancel is an
         EVICTION, never a recompile: no new program shapes — the
         closure replay's cancellation mix proves it."""
-        from ..observability import instrument as obs
         if phase == "prefilling":
             self._drop_prefilling(r)
         elif phase == "running":
@@ -553,7 +553,6 @@ class ContinuousBatchingScheduler:
                 pass  # telemetry must never take the serving loop down
 
     def _evict_finished(self) -> int:
-        from ..observability import instrument as obs
         done = [rid for rid, r in self._running.items() if r.done]
         for rid in done:
             r = self._running.pop(rid)
@@ -611,7 +610,6 @@ class ContinuousBatchingScheduler:
         request to the prefill phase — page allocation AND the prefix-
         cache match happen at its first chunk (so a same-prefix request
         earlier in the queue has published its pages by then)."""
-        from ..observability import instrument as obs
         while self._queue and (len(self._running) + len(self._prefilling)
                                + len(self._migrating_in)
                                < self.max_concurrency):
@@ -639,7 +637,6 @@ class ContinuousBatchingScheduler:
         prefilling requests, one chunk at a time — the decode step that
         follows is stalled by at most ``prefill_token_budget`` tokens
         of prefill work (chunk-granular), never a whole long prompt."""
-        from ..observability import instrument as obs
         eng = self.engine
         budget = self.prefill_token_budget
         spent = 0
@@ -706,7 +703,6 @@ class ContinuousBatchingScheduler:
         return spent
 
     def _admit(self):
-        from ..observability import instrument as obs
         if self.chunked:
             return self._admit_chunked()
         pool = self.engine.pool
@@ -780,7 +776,6 @@ class ContinuousBatchingScheduler:
         under ``step()``'s ``sched.step`` (kept only while a trace is
         being taken): what is left of the step outside them is its own
         time."""
-        from ..observability import instrument as obs
         with RecordEvent("sched.expire"):
             now = time.perf_counter()
             self._update_mode(now)
@@ -804,19 +799,25 @@ class ContinuousBatchingScheduler:
                         hook()
                     except Exception:
                         pass  # background work must never take the loop down
-        obs.serving_queue_depth_gauge().set(float(len(self._queue)))
-        obs.serving_kv_pages_gauge().set(
-            float(self.engine.pool.pages_in_use))
-        # admission may have finished short requests (max_new=1)
-        active = [r for r in self._running.values() if not r.done]
-        if not active:
-            return bool(self._queue or self._prefilling or self._running)
-        t0 = time.perf_counter()
-        # ONE bucket-selection implementation: the engine's (raises
-        # EngineShapeError on overflow, same as every other shape gate)
-        bucket = self.engine.decode_bucket(len(active))
+        with RecordEvent("sched.prepare"):
+            obs.serving_queue_depth_gauge().set(float(len(self._queue)))
+            obs.serving_kv_pages_gauge().set(
+                float(self.engine.pool.pages_in_use))
+            # admission may have finished short requests (max_new=1)
+            active = [r for r in self._running.values() if not r.done]
+            if not active:
+                return bool(self._queue or self._prefilling
+                            or self._running)
+            t0 = time.perf_counter()
+            # ONE bucket-selection implementation: the engine's (raises
+            # EngineShapeError on overflow, same as every other shape gate)
+            bucket = self.engine.decode_bucket(len(active))
+            # a block tick's sched.decode_tick carries it: counted here,
+            # so that the phase span opens on the work it names
+            masked = self.engine.masked_positions([r.rid for r in active]) \
+                if self.block_len > 1 else None
         if self.block_len > 1:
-            return self._block_tick(active, bucket, t0)
+            return self._block_tick(active, bucket, t0, masked)
         with RecordEvent("sched.decode_tick", n_active=len(active),
                          bucket=bucket):
             pool = self.engine.pool
@@ -859,7 +860,7 @@ class ContinuousBatchingScheduler:
         # the same way train steps do
         obs.record_train_step(dt, tokens=tokens, path="serving")
 
-    def _block_tick(self, active, bucket, t0) -> bool:
+    def _block_tick(self, active, bucket, t0, masked) -> bool:
         """The decode phase where a step is one pass over each running
         sequence's block: the pool grows by a block where a sequence
         starts one, the pass yields 0 to ``block_len`` tokens for it (a
@@ -867,12 +868,10 @@ class ContinuousBatchingScheduler:
         ``max_new_tokens`` exactly: what its last block computed past
         that is dropped. Time to first token is the first block; the
         per-token samples are a block's time over its tokens."""
-        from ..observability import instrument as obs
         eng, pool, bl = self.engine, self.engine.pool, self.block_len
         rids = [r.rid for r in active]
         with RecordEvent("sched.decode_tick", n_active=len(active),
-                         bucket=bucket, block=bl,
-                         masked=eng.masked_positions(rids)):
+                         bucket=bucket, block=bl, masked=masked):
             grown = 0
             with RecordEvent("pool.extend") as ev:
                 for r in active:
@@ -1023,7 +1022,6 @@ class ContinuousBatchingScheduler:
         prefix stays warm here for future same-prefix traffic) and drop
         the request WITHOUT a terminal record — the destination now
         owns its lifecycle and will report it."""
-        from ..observability import instrument as obs
         with self._lock:
             r = self._migrating.pop(rid)
             self._release(r, cached=r.tokens[:-1])
@@ -1088,7 +1086,6 @@ class ContinuousBatchingScheduler:
         cached_len)`` or ``(False, reason)`` (on failure the staged
         reservation and cache pins are dropped; the source aborts and
         stays authoritative)."""
-        from ..observability import instrument as obs
         from ..observability.reqtrace import RequestTrace
         eng = self.engine
         rid = int(meta["rid"])

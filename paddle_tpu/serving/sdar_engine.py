@@ -54,12 +54,15 @@ probability of its token) that the program read there (handed to the
 scheduler with the tokens: the record that the benchmark's reference
 replays, so that numbers are compared and not only choices).
 
-Spans: the core's ``engine.prefill_begin`` / ``prefill_step``, and
-``engine.decode``, each with ``engine.host_prep`` / ``dispatch`` /
-``readback`` inside; ``engine.decode`` also carries ``commit`` (1 where
-every live sequence of the pass commits), ``n_commit``, ``pass`` (the
-pass index where all live sequences are at the same one, else -1) and
-``unmasked``.
+Spans: the core's ``engine.prefill_begin`` / ``prefill_step``,
+``sched.batch`` (the batch's blocks and the counts below, worked out
+before ``engine.decode`` opens: the decode tick's time outside the
+``engine.*`` spans, as it was before it had a span), and ``engine.decode``, each with
+``engine.host_prep`` / ``dispatch`` / ``readback`` inside (and
+``unpack``: the pass's result applied to the blocks); ``engine.decode``
+also carries ``commit`` (1 where every live sequence of the pass
+commits), ``n_commit``, ``pass`` (the pass index where all live
+sequences are at the same one, else -1) and ``unmasked``.
 
 Live migration is refused by name (``can_migrate`` stays False, so the
 fleet answers ``engine_unsupported``; the scheduler's
@@ -84,7 +87,8 @@ from ..kernels.paged_attention import (paged_attention_decode,
                                        ragged_prefill_attention)
 from ..models import sdar
 from ..profiler.utils import RecordEvent
-from .engine_core import EngineShapeError, PagedEngine, _write_rows
+from .engine_core import (EngineShapeError, PagedEngine, _write_rows,
+                          wait_for_device)
 
 __all__ = ["SdarServingEngine", "sdar_block_step_fn",
            "sdar_chunk_prefill_fn", "block_attention"]
@@ -407,13 +411,14 @@ class SdarServingEngine(PagedEngine):
         bucket = self.decode_bucket(n) if bucket is None else bucket
         if n > bucket:
             raise EngineShapeError(f"{n} sequences > bucket {bucket}")
-        blocks = [self._blocks[s] for s in seq_ids]
-        commits = [not b.masked.any() for b in blocks]
-        at = {b.n_pass for b in blocks}
-        with RecordEvent("engine.decode", n=n, bucket=bucket,
-                         in_flight=self._in_flight,
-                         commit=int(all(commits)), n_commit=sum(commits),
-                         **{"pass": at.pop() if len(at) == 1 else -1}) as ev:
+        with RecordEvent("sched.batch"):
+            blocks = [self._blocks[s] for s in seq_ids]
+            commits = [not b.masked.any() for b in blocks]
+            at = {b.n_pass for b in blocks}
+            attrs = {"n": n, "bucket": bucket, "in_flight": self._in_flight,
+                     "commit": int(all(commits)), "n_commit": sum(commits),
+                     "pass": at.pop() if len(at) == 1 else -1}
+        with RecordEvent("engine.decode", **attrs) as ev:
             with RecordEvent("engine.host_prep"):
                 state = np.zeros((bucket, self._state_width), np.int32)
                 held = self.pool.lens_array(seq_ids)
@@ -438,30 +443,34 @@ class SdarServingEngine(PagedEngine):
             self._in_flight += 1
             with RecordEvent("engine.readback",
                              in_flight=self._in_flight):
-                out = np.asarray(out)
+                wait_for_device(out)
+                with RecordEvent("engine.d2h"):
+                    out = np.asarray(out)
             self._in_flight = 0
-            after = out[:bucket * bl].reshape(bucket, bl)
-            picked = out[bucket * bl:2 * bucket * bl].reshape(bucket, bl) > 0
-            conf = out[2 * bucket * bl:3 * bucket * bl].view(
-                np.float32).reshape(bucket, bl)
-            load = out[3 * bucket * bl:].reshape(self.expert_load.shape)
-            self.expert_load += load
-            self.last_pass_load = load
-            ev.set(unmasked=int(picked[:n].sum()))
-            results = [
-                self._advance(b, commit, after[i], picked[i], conf[i])
-                for i, (b, commit) in enumerate(zip(blocks, commits))]
-            c = self.counters
-            kind = "passes_commit" if all(commits) else \
-                "passes_mixed" if any(commits) else "passes_denoise"
-            c[kind] += 1
-            c["positions_computed"] += n * bl
-            for sid, commit in zip(seq_ids, commits):
-                if commit:
-                    c["blocks_committed"] += 1
-                    self._blocks[sid] = _Block(
-                        self._blocks[sid].start + bl, (), bl,
-                        self.cfg.mask_token_id)
+            with RecordEvent("engine.unpack"):
+                after = out[:bucket * bl].reshape(bucket, bl)
+                picked = out[bucket * bl:2 * bucket * bl].reshape(
+                    bucket, bl) > 0
+                conf = out[2 * bucket * bl:3 * bucket * bl].view(
+                    np.float32).reshape(bucket, bl)
+                load = out[3 * bucket * bl:].reshape(self.expert_load.shape)
+                self.expert_load += load
+                self.last_pass_load = load
+                ev.set(unmasked=int(picked[:n].sum()))
+                results = [
+                    self._advance(b, commit, after[i], picked[i], conf[i])
+                    for i, (b, commit) in enumerate(zip(blocks, commits))]
+                c = self.counters
+                kind = "passes_commit" if all(commits) else \
+                    "passes_mixed" if any(commits) else "passes_denoise"
+                c[kind] += 1
+                c["positions_computed"] += n * bl
+                for sid, commit in zip(seq_ids, commits):
+                    if commit:
+                        c["blocks_committed"] += 1
+                        self._blocks[sid] = _Block(
+                            self._blocks[sid].start + bl, (), bl,
+                            self.cfg.mask_token_id)
         return results
 
     @staticmethod

@@ -41,6 +41,7 @@ SCHED_TREE = {
     "sched.expire": "sched.step", "sched.evict": "sched.step",
     "sched.admit": "sched.step", "sched.prefill_tick": "sched.step",
     "sched.hooks": "sched.step", "sched.decode_tick": "sched.step",
+    "sched.prepare": "sched.step",
     "engine.prefill_begin": "sched.prefill_tick",
     "prefix.match": "engine.prefill_begin",
     "pool.alloc": "engine.prefill_begin",
@@ -49,7 +50,9 @@ SCHED_TREE = {
     "engine.decode": "sched.decode_tick",
 }
 # names with more than one possible parent
-ENGINE_PHASES = {"engine.host_prep", "engine.dispatch", "engine.readback"}
+ENGINE_PHASES = {"engine.host_prep", "engine.dispatch", "engine.readback",
+                 "engine.unpack"}
+READBACK_PHASES = {"engine.device_wait", "engine.d2h"}
 TRAIN_TREE = {"train.step": None, "GPTHybridTrainStep.step": "train.step",
               "train.account": "train.step",
               "train.mem_sample": "train.step"}
@@ -157,8 +160,8 @@ def test_every_phase_is_recorded_under_its_parent(traced):
     spans = traced.spans
     by_id = {s.span_id: s for s in spans}
     names = {s.name for s in spans}
-    assert names >= set(SCHED_TREE) | ENGINE_PHASES | set(TRAIN_TREE) \
-        | {"sched.account"}
+    assert names >= set(SCHED_TREE) | ENGINE_PHASES | READBACK_PHASES \
+        | set(TRAIN_TREE) | {"sched.account", "sched.submit"}
     for s in spans:
         if s.name == "test.traced":
             assert s.parent_id == 0
@@ -168,7 +171,11 @@ def test_every_phase_is_recorded_under_its_parent(traced):
         assert parent.start_ns <= s.start_ns <= s.end_ns <= parent.end_ns, \
             (s, parent)
         want = {**SCHED_TREE, **TRAIN_TREE}.get(s.name)
-        if s.name in ENGINE_PHASES:
+        if s.name == "gc":          # a collection, under whatever was open
+            continue
+        if s.name in READBACK_PHASES:
+            assert parent.name == "engine.readback"
+        elif s.name in ENGINE_PHASES:
             assert parent.name in ("engine.decode", "engine.prefill_step")
         elif s.name == "sched.account":
             assert parent.name in ("sched.step", "sched.prefill_tick")
